@@ -20,12 +20,7 @@ __all__ = ["Generator", "seed", "default_generator", "get_rng_state", "set_rng_s
 
 
 def _tracing() -> bool:
-    try:
-        from jax._src import core as _core
-
-        return not _core.trace_state_clean()
-    except Exception:
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 class Generator:

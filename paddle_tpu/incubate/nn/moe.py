@@ -107,7 +107,7 @@ def _make_ragged_ep_ffn(activation: str, top_k: int, n_experts: int,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from paddle_tpu.framework.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     act_api = getattr(F, activation)

@@ -1,6 +1,6 @@
 """paddle_tpu.models — reference model families (flagship: Llama).
 
-Coverage of the BASELINE.md configs: Llama (TP/PP/CP hybrid trainers),
+Coverage of the bench.py training configs: Llama (TP/PP/CP hybrid trainers),
 GPT (fused-qkv causal LM), BERT (MLM pretraining), diffusion UNet
 (SD-style), plus vision CNNs in paddle_tpu.vision.models.
 """

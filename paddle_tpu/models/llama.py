@@ -56,7 +56,7 @@ TINY_CONFIG = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
                           num_hidden_layers=2, num_attention_heads=4,
                           num_key_value_heads=2, max_position_embeddings=128)
 
-LLAMA_7B_CONFIG = LlamaConfig()  # Llama-2-7B dims (BASELINE.md north star)
+LLAMA_7B_CONFIG = LlamaConfig()  # Llama-2-7B dims (chip_smoke.py's width)
 
 
 def _rope_tables(seq_len: int, head_dim: int, theta: float, dtype, offset=0):

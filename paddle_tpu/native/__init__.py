@@ -9,6 +9,7 @@ shared library (no pip/pybind dependency; bindings are ctypes).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,20 +21,30 @@ _SRC_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
 _BUILD_DIR = os.path.join(_SRC_DIR, "build")
 _SOURCES = ["tcp_store.cpp", "shm_queue.cpp"]
-_SONAME = "libpaddle_tpu_rt.so"
 
 
 def _build_lib() -> str:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    so_path = os.path.join(_BUILD_DIR, _SONAME)
+    """Build (once per source content) and return the shared library.
+    The file name carries a hash of the sources, so a binary built from
+    other sources — left in a copied tree, whatever its mtime — is never
+    picked up."""
     srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
-    newest_src = max(os.path.getmtime(s) for s in srcs)
-    if os.path.exists(so_path) and os.path.getmtime(so_path) >= newest_src:
+    digest = hashlib.sha256()
+    for src in srcs:
+        with open(src, "rb") as f:
+            digest.update(f.read())
+    so_path = os.path.join(
+        _BUILD_DIR, f"libpaddle_tpu_rt.{digest.hexdigest()[:16]}.so")
+    if os.path.exists(so_path):
         return so_path
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    # per-process temporary name: concurrent first builds (cluster worker
+    # processes) must not write through each other before the rename
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     cmd = ["g++", "-O2", "-fPIC", "-shared", "-std=c++17", "-pthread",
-           *srcs, "-lrt", "-o", so_path + ".tmp"]
+           *srcs, "-lrt", "-o", tmp]
     subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(so_path + ".tmp", so_path)
+    os.replace(tmp, so_path)
     return so_path
 
 

@@ -852,15 +852,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True):
     """python/paddle/nn/functional/flash_attention.py:scaled_dot_product_attention
     analog. Layout (batch, seq, heads, head_dim)."""
-    use_flash = (flags.use_fused_attention and attn_mask is None
-                 and dropout_p == 0.0
-                 and key.shape[1] >= flags.flash_attention_min_seq)
-    if use_flash:
-        try:
-            from paddle_tpu.ops.pallas import flash_attention as fa
+    if (flags.use_fused_attention and attn_mask is None and dropout_p == 0.0
+            and key.shape[1] >= flags.flash_attention_min_seq):
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        if fa.supported(query.shape, key.shape, is_causal):
             return fa.flash_attention_op(query, key, value, causal=is_causal)
-        except ValueError:
-            pass  # shape not kernel-eligible (ragged seq, sq!=sk causal)
     dk = rnd.split_key() if (dropout_p > 0.0 and training) else None
     return _sdpa_ref(query, key, value, attn_mask=attn_mask, dropout_key=dk,
                      dropout_p=dropout_p if training else 0.0, causal=is_causal)

@@ -39,7 +39,8 @@ from paddle_tpu.obs.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, metrics,
 )
 from paddle_tpu.obs.cost import (  # noqa: F401
-    clear_cost_cache, device_peak_flops, dispatch_cost, mfu, site_costs,
+    clear_cost_cache, device_peak_flops, dispatch_cost, mfu,
+    program_census, site_costs,
 )
 from paddle_tpu.obs.device import (  # noqa: F401
     DeviceTraceSession, device_trace_enabled,
@@ -55,7 +56,7 @@ __all__ = [
     "Span", "Tracer", "tracer", "span", "obs_enabled", "set_span_hook",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "metrics",
     "dispatch_cost", "site_costs", "clear_cost_cache",
-    "device_peak_flops", "mfu",
+    "device_peak_flops", "mfu", "program_census",
     "DeviceTraceSession", "device_trace_enabled",
     "ObsExporter", "resolve_export_port",
     "FlightRecorder", "flight_recorder", "record_crash",

@@ -20,11 +20,12 @@ only (documented in README).
 
 from __future__ import annotations
 
+import re
 import threading
 from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["dispatch_cost", "site_costs", "clear_cost_cache",
-           "device_peak_flops", "mfu"]
+           "program_census", "device_peak_flops", "mfu"]
 
 _CACHE: Dict[Tuple, Optional[dict]] = {}
 _BY_SITE: Dict[str, dict] = {}      # latest successful analysis per site
@@ -42,6 +43,33 @@ def _sig(args, kwargs) -> Tuple:
         return x
     flat, _ = jax.tree_util.tree_flatten((args, kwargs))
     return tuple(leaf(x) for x in flat)
+
+
+# the path element before /pallas_call is the call's name=, inside any
+# jvp(...)/transpose(...) wrapping; a call without a name has none
+_KERNEL_RE = re.compile(
+    r'op_name="[^"]*/(?:\w+\()*([\w.]+)\)*/pallas_call')
+_COLLECTIVE_RE = re.compile(
+    r"\b(all-reduce|all-gather|reduce-scatter|collective-permute|"
+    r"all-to-all)(?:-start)?\(")
+
+
+def program_census(compiled) -> dict:
+    """What a compiled program contains, read off its HLO text: Pallas
+    kernels (``tpu_custom_call``s, counted by the ``name=`` of their
+    ``pallas_call``) and collectives by kind. In interpret mode (off the
+    TPU) a kernel is ordinary HLO, so the kernel count is 0 there."""
+    kernels: Dict[str, int] = {}
+    collectives: Dict[str, int] = {}
+    for line in compiled.as_text().splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _KERNEL_RE.search(line)
+            name = m.group(1) if m else "unnamed"
+            kernels[name] = kernels.get(name, 0) + 1
+        m = _COLLECTIVE_RE.search(line)
+        if m:
+            collectives[m.group(1)] = collectives.get(m.group(1), 0) + 1
+    return {"kernels": kernels, "collectives": collectives}
 
 
 def dispatch_cost(site: str, jitted, args=(), kwargs=None,
@@ -104,6 +132,8 @@ def dispatch_cost(site: str, jitted, args=(), kwargs=None,
         elif "argument_bytes" in out or "output_bytes" in out:
             out["bytes_per_dispatch"] = (out.get("argument_bytes", 0)
                                          + out.get("output_bytes", 0))
+        if out:
+            out.update(program_census(compiled))
         if out and int(num_devices) > 1:
             out["num_devices"] = int(num_devices)
             if "flops" in out:
@@ -132,32 +162,42 @@ def clear_cost_cache() -> None:
         _BY_SITE.clear()
 
 
-def device_peak_flops() -> float:
-    """bf16 peak FLOP/s of device 0 (the BASELINE.md MFU denominators;
-    CPU gets a nominal 1 TF so MFU stays a defined, comparable ratio on
-    the harness)."""
+# bf16 peak FLOP/s of one chip, keyed by jax's ``device_kind`` (Google
+# Cloud TPU documentation: v5e 197, v5p 459, v4 275 TFLOP/s). The one
+# peaks table: bench.py reads it through ``device_peak_flops``.
+DEVICE_PEAK_FLOPS = {
+    "TPU v5 lite": 197e12,
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v4": 275e12,
+}
+
+
+def device_peak_flops() -> Optional[float]:
+    """bf16 peak FLOP/s of device 0. None off the TPU: a utilisation is
+    a device metric and the CPU harness reports none. A TPU kind that
+    is not in the table is an error, never a default."""
     import jax
-    try:
-        kind = str(jax.devices()[0].device_kind).lower()
-        platform = jax.devices()[0].platform
-    except Exception:
-        return 1e12
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if platform == "tpu":
-        return 197e12
-    return 1e12
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    kind = str(dev.device_kind)
+    if kind not in DEVICE_PEAK_FLOPS:
+        raise KeyError(f"no peak FLOP/s on record for device_kind "
+                       f"{kind!r}; add it to obs.cost.DEVICE_PEAK_FLOPS "
+                       f"with its source")
+    return DEVICE_PEAK_FLOPS[kind]
 
 
 def mfu(flops: float, seconds: float,
-        peak: Optional[float] = None) -> float:
+        peak: Optional[float] = None) -> Optional[float]:
     """Model-FLOPs-utilisation fraction for ``flops`` of work done in
-    ``seconds`` of wall time."""
+    ``seconds`` of wall time; None where there is no device peak (off
+    the TPU)."""
+    if peak is None:
+        peak = device_peak_flops()
+    if peak is None:
+        return None
     if seconds <= 0 or flops <= 0:
         return 0.0
-    return flops / seconds / (peak if peak is not None
-                              else device_peak_flops())
+    return flops / seconds / peak

@@ -38,11 +38,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas import _routing
+
 __all__ = ["supported", "decode_attention"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(q, kc) -> bool:
@@ -146,6 +144,7 @@ def decode_attention(q, kc, vc, pos, block_l: int = 256,
             pltpu.VMEM((rep, 128), jnp.float32),
             pltpu.VMEM((rep, D), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
+        name="decode_attention",
     )(*args)
     return out.reshape(B, H, D)

@@ -23,19 +23,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.framework.jax_compat import (
-    pallas_tpu_compiler_params as _compiler_params,
-)
+from paddle_tpu.ops.pallas import _routing
 
-__all__ = ["flash_attention_op", "flash_attention_fn"]
+__all__ = ["supported", "flash_attention_op", "flash_attention_fn"]
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 _NEG_INF = -1e30
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -150,9 +144,10 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
                        pl.BlockSpec((1, sq, 1), lambda b: (b, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
                        jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32)],
-            compiler_params=_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             interpret=interpret,
+            name="flash_fwd",
         )(q, k, v)
     grid = (bh, nq, nk)
 
@@ -181,9 +176,10 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse
 
@@ -317,9 +313,10 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k, interpret):
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
@@ -347,9 +344,10 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -394,16 +392,28 @@ def _auto_blocks(sq: int, sk: int):
     so short sequences run single-block (no online-softmax recurrence at
     all); longer sequences tile at <=512 (measured fastest at S>=2048).
     block_k is additionally capped at 1024 so the K/V tiles stay inside
-    VMEM for skewed shapes (short query, very long KV)."""
+    VMEM for skewed shapes (short query, very long KV). None = no
+    TPU-friendly tiling."""
     if sq * sk <= 1024 * 1024 and sk <= 1024:
         return sq, sk
     bq, bk = _divisor_block(sq, 512), _divisor_block(sk, 512)
     if bq % 8 or bk % 8:
-        # sublane-unfriendly tiling (odd seq len) — refuse so the routing
-        # layer falls back to XLA sdpa instead of a degenerate grid
-        raise ValueError(f"flash_attention: no TPU-friendly block tiling "
-                         f"for seq ({sq},{sk})")
+        return None   # sublane-unfriendly tiling (odd seq len)
     return bq, bk
+
+
+def supported(q_shape, k_shape, causal: bool) -> bool:
+    """Routing predicate (nn.functional): (B, S, H, D) shapes the kernel
+    takes as they are. Ragged sequence lengths, un-repeated KV heads and
+    causal queries with no visible key belong to the XLA sdpa path."""
+    if _routing.auto_partitioned():
+        return False
+    if len(q_shape) != 4 or len(k_shape) != 4:
+        return False
+    sq, sk = q_shape[1], k_shape[1]
+    if k_shape[2] != q_shape[2] or (causal and sq > sk):
+        return False
+    return _auto_blocks(sq, sk) is not None
 
 
 def flash_attention_fn(q, k, v, causal: bool = False, scale=None,
@@ -417,7 +427,11 @@ def flash_attention_fn(q, k, v, causal: bool = False, scale=None,
     b, sq, h, d = q.shape
     sk = k.shape[1]
     if block_q is None or block_k is None:
-        abq, abk = _auto_blocks(sq, sk)
+        auto = _auto_blocks(sq, sk)
+        if auto is None:
+            raise ValueError(f"flash_attention: no TPU-friendly block "
+                             f"tiling for seq ({sq},{sk})")
+        abq, abk = auto
     block_q = min(block_q, sq) if block_q else abq
     block_k = min(block_k, sk) if block_k else abk
     if sq % block_q or sk % block_k:
@@ -437,7 +451,7 @@ def flash_attention_fn(q, k, v, causal: bool = False, scale=None,
 
     qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
     ob = _flash(qb, kb, vb, scale, bool(causal), block_q, block_k,
-                _use_interpret())
+                _routing.use_interpret())
     return jnp.swapaxes(ob.reshape(b, h, sq, d), 1, 2)
 
 

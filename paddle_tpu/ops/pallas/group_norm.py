@@ -29,11 +29,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import _routing
+
 __all__ = ["supported", "gn_fwd", "gn_bwd"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _padded_elems(cg: int, spatial) -> int:
@@ -73,13 +71,15 @@ def _layout_for(x_shape, groups: int):
         return "native4d"
     # interpret mode has no lane-tiling constraint on 'flat'; everything
     # else routes identically so CPU tests exercise the TPU decisions
-    if (hw % 128 == 0 or _use_interpret()) and cg * hw * 4 <= budget:
+    if ((hw % 128 == 0 or _routing.use_interpret())
+            and cg * hw * 4 <= budget):
         return "flat"
     return None
 
 
 def supported(x_shape, groups: int) -> bool:
-    return _layout_for(x_shape, groups) is not None
+    return (not _routing.auto_partitioned()
+            and _layout_for(x_shape, groups) is not None)
 
 
 def _silu_fwd(y):
@@ -156,7 +156,7 @@ def gn_fwd(x, w, b, groups: int, eps: float, act=None):
             jax.ShapeDtypeStruct((B * groups, 1) + ones, jnp.float32),
             jax.ShapeDtypeStruct((B * groups, 1) + ones, jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
     )(xb, w.reshape((groups, cg) + ones), b.reshape((groups, cg) + ones))
     return out.reshape(x.shape), mean, rstd
 
@@ -216,7 +216,7 @@ def gn_bwd(x, w, b, mean, rstd, g, groups: int, act=None):
             jax.ShapeDtypeStruct((B * groups, cg) + ones, jnp.float32),
             jax.ShapeDtypeStruct((B * groups, cg) + ones, jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
     )(xb, w.reshape((groups, cg) + ones), b.reshape((groups, cg) + ones),
       mean, rstd, gb)
     # per-(b,g) channel partials -> (C,) by summing the batch axis

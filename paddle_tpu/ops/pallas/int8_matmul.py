@@ -26,11 +26,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas import _routing
+
 __all__ = ["supported", "int8_matmul"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def supported(x, w) -> bool:
@@ -84,5 +82,6 @@ def int8_matmul(x, w, scale, block_n: int = 1024):
         ],
         out_specs=pl.BlockSpec((B, bn), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((B, N), x.dtype),
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
+        name="int8_matmul",
     )(x, w, scale.astype(jnp.float32).reshape(1, N))

@@ -27,30 +27,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas import _routing
+
 __all__ = ["supported", "rms_fwd", "rms_bwd"]
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+# Mosaic's default scoped-VMEM limit on v5e. A row block holds its io
+# blocks double-buffered (the pipeline) plus the kernel's (rows, h) f32
+# temporaries; the temporaries counts below are the smallest that kept
+# every AOT compile for v5e inside the limit over h 1024..16384, bf16 and
+# f32 (the fixed 256-row block was refused from h=4096 on).
+_VMEM_BUDGET = 16 * 1024 * 1024
+_FWD_TEMPS = 2
+_BWD_TEMPS = 5
 
 
-def _row_block(rows: int) -> int:
+def _row_block(rows: int, h: int, io_bytes: int, n_temps: int) -> int:
+    """Largest sublane-aligned divisor of ``rows`` (<= 256) whose blocks
+    fit the VMEM budget; ``io_bytes`` is the summed itemsize of the
+    (rows, h) operands and results. 0 = no block fits."""
+    per_row = h * (2 * io_bytes + 4 * n_temps)
     for cand in (256, 128, 64, 32, 16, 8):
-        if rows % cand == 0:
+        if rows % cand == 0 and cand * per_row <= _VMEM_BUDGET:
             return cand
     return 0
 
 
 def supported(x_shape, w_shape) -> bool:
+    if _routing.auto_partitioned():
+        return False
     if len(x_shape) < 2 or len(w_shape) != 1 or x_shape[-1] != w_shape[0]:
         return False
     h = x_shape[-1]
     rows = 1
     for d in x_shape[:-1]:
         rows *= d
-    if _use_interpret():
-        return _row_block(rows) > 0  # interpret mode has no lane constraint
-    return h % 128 == 0 and _row_block(rows) > 0
+    # shapes only, so size for the widest case: f32 x, grad and dx in the
+    # backward (the forward's blocks are never larger)
+    if _row_block(rows, h, 12, _BWD_TEMPS) == 0:
+        return False
+    # interpret mode has no lane constraint
+    return _routing.use_interpret() or h % 128 == 0
 
 
 def _fwd_kernel(x_ref, w_ref, o_ref, inv_ref, *, eps, out_dtype):
@@ -69,8 +86,9 @@ def rms_fwd(x, w, eps: float):
     orig_shape = x.shape
     h = orig_shape[-1]
     rows = x.size // h
-    br = _row_block(rows)
     out_dtype = jnp.result_type(x.dtype, w.dtype)
+    br = _row_block(rows, h, x.dtype.itemsize + jnp.dtype(out_dtype).itemsize,
+                    _FWD_TEMPS)
     x2 = x.reshape(rows, h)
     out, inv = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps, out_dtype=out_dtype),
@@ -87,7 +105,8 @@ def rms_fwd(x, w, eps: float):
             jax.ShapeDtypeStruct((rows, h), out_dtype),
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
+        name="rms_norm_fwd",
     )(x2, w.reshape(1, h))
     return out.reshape(orig_shape[:-1] + (h,)), inv
 
@@ -121,7 +140,8 @@ def rms_bwd(x, w, inv, g):
     orig_shape = x.shape
     h = orig_shape[-1]
     rows = x.size // h
-    br = _row_block(rows)
+    br = _row_block(rows, h, 2 * x.dtype.itemsize + g.dtype.itemsize,
+                    _BWD_TEMPS)
     nb = rows // br
     x2 = x.reshape(rows, h)
     g2 = g.reshape(rows, h)
@@ -142,7 +162,8 @@ def rms_bwd(x, w, inv, g):
             jax.ShapeDtypeStruct((rows, h), x.dtype),
             jax.ShapeDtypeStruct((8, h), jnp.float32),
         ],
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
+        name="rms_norm_bwd",
     )(x2, w.reshape(1, h), inv, g2)
     dw = jnp.sum(dw_parts, axis=0).astype(w.dtype)
     return dx.reshape(orig_shape), dw

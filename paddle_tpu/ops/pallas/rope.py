@@ -27,11 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.ops.pallas import _routing
+
 __all__ = ["supported", "rope_fused"]
-
-
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _seq_block(s: int) -> int:
@@ -42,6 +40,8 @@ def _seq_block(s: int) -> int:
 
 
 def supported(x_shape, cos_shape, x_dtype=None, cos_dtype=None) -> bool:
+    if _routing.auto_partitioned():
+        return False
     if len(x_shape) != 4 or len(cos_shape) != 2:
         return False
     b, s, h, d = x_shape
@@ -77,7 +77,7 @@ def _run(x, cos, sin):
         ],
         out_specs=pl.BlockSpec((1, bs, h, d), lambda i, j: (i, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=_use_interpret(),
+        interpret=_routing.use_interpret(),
     )(x, cos.reshape(s, 1, d2), sin.reshape(s, 1, d2))
 
 

@@ -162,7 +162,7 @@ def _pad_for_uneven(value, mesh: ProcessMesh, placements):
 
 def _materialize_partial(t: Tensor, mesh: ProcessMesh):
     """psum pending-partial axes (PToR: reshard/p_to_r_reshard_function.cc)."""
-    from paddle_tpu.framework.jax_compat import shard_map
+    from jax import shard_map
 
     partial_axes = tuple(
         mesh.dim_names[i] for i, p in enumerate(t._placements or [])

@@ -28,7 +28,7 @@ from typing import Callable, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from paddle_tpu.framework.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.parallel.mesh import ProcessMesh

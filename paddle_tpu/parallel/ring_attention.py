@@ -27,7 +27,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from paddle_tpu.framework.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.framework.tensor import Tensor

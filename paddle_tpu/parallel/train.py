@@ -180,8 +180,7 @@ class ShardedTrainer:
 
         def step(params, buffers, opt_state, lr, seed, *batch):
             # seed is a DEVICE-resident counter (donated, bumped in-graph):
-            # no per-step host->device scalar transfer, which costs a
-            # blocking RPC round-trip on tunneled/remote runtimes
+            # no per-step host->device scalar transfer
             if offload:
                 # stream the host-resident optimizer states into HBM for
                 # the update; out_shardings put the new states back on host
@@ -264,10 +263,8 @@ class ShardedTrainer:
 
     def _build_multi(self, n_batch: int):
         """K steps per dispatch: a lax.scan over the single-step body with
-        per-step batch slices. One executable run amortizes the host
-        dispatch / runtime-RPC cost over K steps (on remote/tunneled
-        runtimes each execute costs a round-trip; sustained training
-        should not pay it per step)."""
+        per-step batch slices: one executable run spreads the host's
+        dispatch cost over K steps."""
         import jax.lax as lax
 
         single = self._single_step_fn(n_batch)

@@ -198,7 +198,7 @@ class WorkerPool:
     def spawn_frontend(self, cfg: Dict[str, Any]) -> subprocess.Popen:
         env = dict(os.environ)
         env[ENV_CFG] = json.dumps(cfg)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"    # launch.refuse_tpu_parent
         # -c entry for the same canonical-module reason as the workers
         return subprocess.Popen(
             [sys.executable, "-c",
@@ -275,8 +275,10 @@ def launch_worker_pool(model, workdir: str, prefill: int = 1,
     from paddle_tpu.native.tcp_store import TCPStore
     from paddle_tpu.serving.cluster.launch import (_spawn_store_daemon,
                                                    _spawn_worker,
-                                                   _wait_registered)
+                                                   _wait_registered,
+                                                   refuse_tpu_parent)
 
+    refuse_tpu_parent()
     os.makedirs(workdir, exist_ok=True)
     weights = os.path.join(workdir, "weights_v1.npz")
     np.savez(weights, **{k: np.asarray(v.numpy())

@@ -52,7 +52,7 @@ import numpy as np
 from paddle_tpu.serving.cluster.frontend import ClusterRouter, WorkerHandle
 
 __all__ = ["Cluster", "launch_cluster", "parse_cluster_spec",
-           "adopt_worker_handles"]
+           "adopt_worker_handles", "refuse_tpu_parent"]
 
 
 def parse_cluster_spec(spec: str) -> Dict[str, int]:
@@ -188,10 +188,26 @@ class Cluster:
         self.shutdown()
 
 
+def refuse_tpu_parent() -> None:
+    """The multi-process modes are CPU drills: a chip belongs to one
+    process, so under a parent whose backend is the TPU the children
+    could only serve on the CPU, unseen, beside the parent's device
+    results. Such a parent is refused, before anything is spawned,
+    until a launcher exists that gives each process its own chip
+    (ROADMAP D6/D9)."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "cluster serving spawns worker processes, and this parent "
+            "process holds the TPU: the workers would run on the CPU. "
+            "The multi-process modes are CPU drills — run them with "
+            "JAX_PLATFORMS=cpu.")
+
+
 def _spawn_worker(cfg: dict) -> subprocess.Popen:
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"    # see refuse_tpu_parent
     env["PADDLE_TPU_CLUSTER_CFG"] = json.dumps(cfg)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     # workers inherit the frontend's fault plan (PADDLE_TPU_FAULT_PLAN
     # rides the environment) — cross-process drills need no extra wiring.
     # -c entry (not -m): the worker module must run as its CANONICAL
@@ -300,6 +316,7 @@ def launch_cluster(model, workdir: str, prefill: int = 1,
     from paddle_tpu.distributed.elastic import ElasticManager
     from paddle_tpu.distributed.rpc import RpcAgent
 
+    refuse_tpu_parent()
     os.makedirs(workdir, exist_ok=True)
     weights = os.path.join(workdir, "weights_v1.npz")
     np.savez(weights, **{k: np.asarray(v.numpy())

@@ -1966,6 +1966,10 @@ class ServingEngine:
                 "reset_state with occupied slots would orphan in-flight "
                 "requests; export/clear them first")
         self.state = self._b.new_state()
+        # rows staged in the admission ring were bound for the old carry
+        # (their requests are cleared with the slots): left in place they
+        # hold the ring full for good and every admission re-queues
+        self._ring_meta = [None] * self._ring_slots
 
     # -- live row migration (serving/cluster fleet operations) -------------
     def extract_rows(self, request_ids) -> Dict[str, Any]:

@@ -85,6 +85,27 @@ def test_wired_into_functional():
     assert q.grad is not None
 
 
+@pytest.mark.parametrize("q,k,causal,ok", [
+    ((2, 2048, 32, 128), (2, 2048, 32, 128), True, True),
+    ((1, 64, 4, 32), (1, 128, 4, 32), True, True),      # chunked prefill
+    ((1, 128, 4, 32), (1, 64, 4, 32), True, False),     # a query sees no key
+    ((1, 128, 4, 32), (1, 64, 4, 32), False, True),
+    ((1, 128, 8, 32), (1, 128, 2, 32), True, False),    # KV heads not repeated
+    ((1, 2050, 4, 32), (1, 2050, 4, 32), True, False),  # no 8-aligned tiling
+    ((128, 4, 32), (128, 4, 32), False, False),         # not (B, S, H, D)
+])
+def test_supported_is_the_routing_predicate(q, k, causal, ok):
+    """What used to surface as a ValueError out of the kernel's trace (and
+    was swallowed) is decided up front; the kernel still raises when it is
+    called on such a shape directly."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    assert fa.supported(q, k, causal) is ok
+    if not ok and len(q) == 4:
+        with pytest.raises(ValueError):
+            fa.flash_attention_fn(jnp.zeros(q), jnp.zeros(k), jnp.zeros(k),
+                                  causal=causal)
+
+
 def test_bf16_io():
     rng = np.random.default_rng(3)
     shape = (1, 128, 1, 64)
@@ -129,7 +150,7 @@ def test_single_block_kernel_matches_reference(causal, sq, sk):
 
     def kern(q, k, v):
         # block == full seq -> _fwd_single_kernel path
-        return fa._flash(q, k, v, scale, causal, sq, sk, fa._use_interpret())
+        return fa._flash(q, k, v, scale, causal, sq, sk, fa._routing.use_interpret())
 
     out_r, vjp_r = jax.vjp(ref, q, k, v)
     out_k, vjp_k = jax.vjp(kern, q, k, v)

@@ -113,6 +113,36 @@ def test_rms_norm_unsupported_shape_falls_back():
     assert tuple(out.shape) == (7, 33)
 
 
+# (hidden, itemsize of x/g/dx) -> (forward, backward) row block. These are the
+# largest power-of-two blocks the v5e compiler accepted in an AOT sweep
+# (rows 4096, f32 weight; forward h=4096 bf16 would also take 256): the
+# fixed 256 of before was refused in the backward from h=4096 on.
+_ROW_BLOCKS = {
+    (1024, 2): (256, 256), (1024, 4): (256, 256),
+    (2048, 2): (256, 256), (2048, 4): (256, 128),
+    (4096, 2): (128, 128), (4096, 4): (128, 64),
+    (5120, 2): (128, 64), (5120, 4): (128, 64),
+    (8192, 2): (64, 64), (8192, 4): (64, 32),
+    (16384, 2): (32, 32), (16384, 4): (32, 16),
+}
+
+
+@pytest.mark.parametrize("h,itemsize", sorted(_ROW_BLOCKS))
+def test_rms_row_block_follows_hidden_size_and_vmem_budget(h, itemsize):
+    fwd = prms._row_block(4096, h, itemsize + 4, prms._FWD_TEMPS)
+    bwd = prms._row_block(4096, h, 3 * itemsize, prms._BWD_TEMPS)
+    assert (fwd, bwd) == _ROW_BLOCKS[(h, itemsize)]
+    assert prms.supported((4096, h), (h,))
+
+
+def test_rms_supported_refuses_only_what_cannot_fit_or_divide():
+    assert prms.supported((8, 4096), (4096,))        # the smallest block
+    assert not prms.supported((7, 64), (64,))        # no sublane-aligned rows
+    assert not prms.supported((4096, 65536), (65536,))   # 8 rows pass 16 MiB
+    # the block still divides the rows it is given
+    assert prms._row_block(24, 64, 12, prms._BWD_TEMPS) == 8
+
+
 def _ref_rope(x, cos, sin):
     d2 = x.shape[-1] // 2
     x1, x2 = x[..., :d2], x[..., d2:]

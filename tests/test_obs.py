@@ -373,3 +373,57 @@ def test_scheduler_push_stamps_monotonic_submit_time():
     sch.push(explicit)
     [(_, req2)] = sch.admissions()
     assert req2.submit_time == 12345.0            # caller stamp respected
+
+
+# -- what a compiled program holds, and the one peaks table -------------------
+
+class _Compiled:
+    """The HLO text of a compiled program, as far as the census reads it."""
+    _CALL = ('  %c.{i} = bf16[8,128] custom-call(%x), custom_call_target='
+             '"tpu_custom_call", metadata={{op_name="{op}" stack_frame_id=1}}')
+
+    def __init__(self, ops, collectives):
+        self._lines = [self._CALL.format(i=i, op=op)
+                       for i, op in enumerate(ops)]
+        self._lines += [f"  %r.{i} = f32[8] {c}(%y), replica_groups={{}}"
+                        for i, c in enumerate(collectives)]
+
+    def as_text(self):
+        return "\n".join(["HloModule jit_step", *self._lines, "ROOT %t"])
+
+
+def test_program_census_counts_named_kernels_and_collectives():
+    census = obs.program_census(_Compiled(
+        ["jit(step)/jvp(rms_norm_fwd)/pallas_call",
+         "jit(step)/transpose(jvp(rms_norm_bwd))/pallas_call",
+         "jit(step)/transpose(jvp(rms_norm_bwd))/pallas_call",
+         "jit(f)/decode_attention/pallas_call",
+         "jit(f)/pallas_call"],
+        ["all-reduce", "all-reduce-start", "all-gather", "all-reduce-done",
+         "collective-permute-start", "add"]))
+    assert census["kernels"] == {"rms_norm_fwd": 1, "rms_norm_bwd": 2,
+                                 "decode_attention": 1, "unnamed": 1}
+    assert census["collectives"] == {"all-reduce": 2, "all-gather": 1,
+                                     "collective-permute": 1}
+
+
+@pytest.mark.parametrize("platform,kind,peak", [
+    ("tpu", "TPU v5 lite", 197e12),
+    ("tpu", "TPU v4", 275e12),
+    ("cpu", "cpu", None),                  # no CPU peak: MFU is a device metric
+    ("tpu", "TPU v9 mystery", KeyError),   # not in the table: never a default
+])
+def test_device_peak_flops_is_one_table_keyed_by_device_kind(
+        monkeypatch, platform, kind, peak):
+    import jax
+
+    class Dev:
+        pass
+    Dev.platform, Dev.device_kind = platform, kind
+    monkeypatch.setattr(jax, "devices", lambda: [Dev()])
+    if peak is KeyError:
+        with pytest.raises(KeyError, match="TPU v9 mystery"):
+            obs.device_peak_flops()
+        return
+    assert obs.device_peak_flops() == peak
+    assert obs.mfu(1e12, 1.0) == (None if peak is None else 1e12 / peak)

@@ -68,7 +68,7 @@ Classes swept (decode + checkpoint + bundle + elastic + serving paths):
 
 Prints one human line per class to stderr and ONE parseable JSON line
 to stdout (the bench.py last-line contract); exit code 0 iff all pass.
-Wired into tools/roundtail_bench.py. Usage: python tools/fault_matrix.py
+Usage: python tools/fault_matrix.py
 """
 
 from __future__ import annotations

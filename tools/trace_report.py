@@ -19,7 +19,7 @@ Output: two text tables —
   id whose queued/admitted/finished phase events don't all appear.
 
 ``--json`` additionally emits the summary as one machine-readable JSON
-line on stdout (for roundtail logs / CI greps). Exit code 1 on an
+line on stdout (for logs / CI greps). Exit code 1 on an
 empty or unreadable trace — a smoke gate, not just a pretty-printer.
 
 Usage:
