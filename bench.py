@@ -183,44 +183,6 @@ def _obs_finish(mark, trace_name, **extra):
     return block
 
 
-def _obs_device_session():
-    """Start a device-time attribution capture (jax.profiler merged
-    trace, obs/device.py) when BOTH obs and the device-trace evidence
-    mode (PADDLE_TPU_OBS_DEVICE=1 / FLAGS_obs_device_trace) are on;
-    None otherwise. Call ``.stop()`` BEFORE _obs_finish so the exported
-    trace's spans carry the merged device_ms attrs."""
-    import paddle_tpu.obs as obs
-    if not (obs.enabled() and obs.device_trace_enabled()):
-        return None
-    sess = obs.DeviceTraceSession().start()
-    if not sess.active:
-        raise RuntimeError("device trace requested (PADDLE_TPU_OBS_DEVICE) "
-                           "but the profiler session did not start")
-    return sess
-
-
-def _obs_device_block(summary):
-    """The bench record's ``obs.device`` block: the session summary
-    (per-site measured device_ms + the attribution-coverage check) with
-    MEASURED MFU per site — the site's cost-model FLOPs over its
-    measured device seconds — next to the host-wall cost-model MFU the
-    records already carry."""
-    import paddle_tpu.obs as obs
-    if not summary or not summary.get("active"):
-        return summary
-    costs = obs.site_costs()
-    peak = obs.device_peak_flops()
-    for site, agg in summary.get("by_site", {}).items():
-        c = costs.get(site)
-        if c and c.get("flops") and agg["device_ms"] > 0:
-            agg["flops_per_dispatch"] = c["flops"]
-            if peak is not None:
-                agg["mfu_measured"] = round(obs.mfu(
-                    c["flops"] * agg["spans"], agg["device_ms"] / 1e3,
-                    peak=peak), 6)
-    return summary
-
-
 def _emit(metric: str, value: float, unit: str) -> dict:
     vs = None
     try:
@@ -933,7 +895,6 @@ def bench_decode_modes(steps=None, mesh=None):
     # speculative modes run on a mesh too: the shard_map'd per-row
     # uneven cache advance made SpeculativeMeshError a working path
     run_mark = _obs_mark()        # the whole-run trace export window
-    dev_sess = _obs_device_session()   # PADDLE_TPU_OBS_DEVICE=1 evidence
     rows = {}
     for B in batches:
         prompt = rng.integers(0, cfg.vocab_size, (B, prompt_len))
@@ -992,12 +953,7 @@ def bench_decode_modes(steps=None, mesh=None):
         md = dec.sharding.describe()
         md.pop("partition_rules", None)
         line["decode"]["mesh"] = md
-    # merge measured device time onto the spans BEFORE the export, so
-    # the trace artifact (and trace_report's device columns) carry it
-    dev_summary = dev_sess.stop() if dev_sess is not None else None
     line["obs"] = _obs_finish(run_mark, "obs_trace_decode.json")
-    if dev_summary is not None:
-        line["obs"]["device"] = _obs_device_block(dev_summary)
     # re-print the enriched record as the LAST stdout line (the driver
     # parses the final json line; _emit already printed the bare metric)
     print(json.dumps(line))
@@ -1309,7 +1265,6 @@ def bench_serve(n_requests=None, slots=None, chunk=None, mesh=None,
         exporter.add_engine(eng)
     d0 = dec.dispatch_count
     wm = _obs_mark()    # obs window covers EXACTLY the continuous section
-    dev_sess = _obs_device_session()   # device-time attribution capture
     finish = {}
     submitted = 0
     t0 = time.perf_counter()
@@ -1327,9 +1282,6 @@ def bench_serve(n_requests=None, slots=None, chunk=None, mesh=None,
         for rid, res in eng.step():
             finish[rid] = (time.perf_counter() - t0, res)
     cont_wall = time.perf_counter() - t0
-    # stop + merge BEFORE the trace export below, so the exported spans
-    # carry device_ms and the record can report measured MFU
-    dev_summary = dev_sess.stop() if dev_sess is not None else None
     m = eng.metrics()
     disp_cont = dec.dispatch_count - d0
     lat = np.asarray([finish[i][0] - arrivals[i] for i in range(n_req)])
@@ -1384,8 +1336,6 @@ def bench_serve(n_requests=None, slots=None, chunk=None, mesh=None,
                                 window=w,
                                 engine_metrics_prometheus=eng.registry
                                 .to_prometheus())
-        if dev_summary is not None:
-            obs_block["device"] = _obs_device_block(dev_summary)
     # cost-model MFU, PER DEVICE: decode work is ~2*N_params FLOPs per
     # token; under a mesh each device does 1/mesh_size of it, so the
     # honest utilisation denominator is (devices x wall x peak). Off-mesh

@@ -258,14 +258,6 @@ define_flag("obs_export_port", 0,
             "PADDLE_TPU_OBS_PORT environment variable is an equivalent "
             "switch. ServingEngine.start_exporter() and bench.py --serve "
             "honor it")
-define_flag("obs_device_trace", False,
-            "wrap obs evidence windows in a jax.profiler trace capture "
-            "and merge measured device-op durations back onto the owning "
-            "dispatch spans (device_ms / device_occupancy attrs, "
-            "measured MFU next to the cost-model MFU in bench records); "
-            "the PADDLE_TPU_OBS_DEVICE=1 environment variable is an "
-            "equivalent switch. Costs one profiler session per window — "
-            "strictly an evidence mode, never on the default hot path")
 define_flag("obs_flight_recorder", True,
             "on DecodeFailedError / an exhausted degradation ladder, "
             "atomically dump the last FLAGS_obs_flight_spans spans + the "

@@ -1066,15 +1066,25 @@ class LlamaDecoder:
         count is directly comparable with ``dispatch_count`` and the
         serving engine's asserted accounting. A dispatch that raises
         records an error span, which the accounting comparison excludes
-        (the failed attempt never ran). Disabled: one boolean check."""
+        (the failed attempt never ran). Disabled: one boolean check.
+
+        Always on: the dispatch runs under ``obs.dispatch_site(site)``,
+        so a backend compile inside it counts under
+        ``obs.compiles.<site>`` (an operator sees which site
+        recompiled)."""
         import paddle_tpu.obs as obs
         from paddle_tpu.flags import flags as _flags
         from paddle_tpu.runtime.resilience import (fault_injector,
                                                    resilient_call)
+        obs.watch_compiles()
 
         def attempt(args, kwargs):
             fault_injector.on_call(site)
             self.dispatch_count += 1
+            with obs.dispatch_site(site):
+                return run(args, kwargs)
+
+        def run(args, kwargs):
             if not obs.enabled():
                 return jitted(*args, **kwargs)
             with obs.span(site, kind="dispatch") as sp:

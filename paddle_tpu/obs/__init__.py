@@ -5,7 +5,11 @@ IO and bench:
 
 - :mod:`~paddle_tpu.obs.trace` — thread-safe structured span tracer
   (nested spans, monotonic clocks, bounded ring buffer) with Chrome
-  trace and JSONL exporters;
+  trace and JSONL exporters; ``phase(name, hist)``, the always-on
+  primitive behind ``ServingEngine.step``'s admit / dispatch / wait /
+  harvest. A span or a phase is also a ``jax.profiler.TraceAnnotation``
+  of the same name: a host event on the device trace's own clock
+  whenever a profiler session is live, so no merge step is needed;
 - :mod:`~paddle_tpu.obs.metrics` — typed metrics registry (counters /
   gauges / explicit-bucket histograms) with snapshot + Prometheus text
   export;
@@ -13,10 +17,8 @@ IO and bench:
   ``cost_analysis()`` FLOPs/bytes and ``memory_analysis()`` peak bytes
   attached to the owning dispatch span, so every bench can report
   tokens/s AND MFU per dispatch (Pope et al., 2211.05102 discipline);
-- :mod:`~paddle_tpu.obs.device` — device-time attribution: a
-  ``jax.profiler`` capture merged back onto the owning spans
-  (``device_ms`` / ``device_occupancy`` attrs, measured MFU, an
-  attribution-coverage check);
+  and, always on, backend compiles credited to the dispatch site they
+  fell inside (``obs.compiles.<site>``);
 - :mod:`~paddle_tpu.obs.exporter` — the live telemetry plane:
   ``/metrics`` (Prometheus), ``/statusz`` (JSON status), ``/tracez``
   (recent spans) on a stdlib HTTP thread
@@ -30,20 +32,24 @@ Disabled by default: enable with ``FLAGS_obs_enabled=1`` /
 disabled path is a single enabled check per instrumented call (guarded
 by an overhead test). ``tools/trace_report.py`` renders an exported
 trace into per-phase / per-request summary tables.
+
+What an operator reads: ``ServingEngine.metrics()`` (``step_phase_s``,
+``live_kv_positions_total``, ``ttft_from_submit_*``, ``compiles``
+beside the older keys), the same instruments on
+``/metrics``, and ``serving.step.*`` / ``serving.admit.*`` in any
+profiler trace of a serving run. Device time itself is the
+benchmark's to measure (``benchmark/harness/trace.py``).
 """
 
 from paddle_tpu.obs.trace import (  # noqa: F401
-    Span, Tracer, obs_enabled, set_span_hook, span, tracer,
+    Span, Tracer, obs_enabled, phase, span, tracer,
 )
 from paddle_tpu.obs.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, metrics,
 )
 from paddle_tpu.obs.cost import (  # noqa: F401
-    clear_cost_cache, device_peak_flops, dispatch_cost, mfu,
-    program_census, site_costs,
-)
-from paddle_tpu.obs.device import (  # noqa: F401
-    DeviceTraceSession, device_trace_enabled,
+    clear_cost_cache, compile_counts, device_peak_flops, dispatch_cost,
+    dispatch_site, mfu, program_census, site_costs, watch_compiles,
 )
 from paddle_tpu.obs.exporter import (  # noqa: F401
     ObsExporter, resolve_export_port,
@@ -53,11 +59,11 @@ from paddle_tpu.obs.flight import (  # noqa: F401
 )
 
 __all__ = [
-    "Span", "Tracer", "tracer", "span", "obs_enabled", "set_span_hook",
+    "Span", "Tracer", "tracer", "span", "phase", "obs_enabled",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "metrics",
     "dispatch_cost", "site_costs", "clear_cost_cache",
     "device_peak_flops", "mfu", "program_census",
-    "DeviceTraceSession", "device_trace_enabled",
+    "dispatch_site", "watch_compiles", "compile_counts",
     "ObsExporter", "resolve_export_port",
     "FlightRecorder", "flight_recorder", "record_crash",
     "enabled",

@@ -16,6 +16,12 @@ per site, and any failure degrades to "no cost attached" — telemetry
 never breaks the dispatch it measures. jax.export-deserialized bundle
 entries expose no analysis hooks; bundle dispatch spans carry timing
 only (documented in README).
+
+Always on beside it: which dispatch site compiled. ``dispatch_site``
+names the site for the length of one dispatch and one ``jax.monitoring``
+listener (:func:`watch_compiles`) credits every backend compile to the
+site it fell inside, or to ``other`` — the ``obs.compiles.<site>``
+counters of the global registry, read back by :func:`compile_counts`.
 """
 
 from __future__ import annotations
@@ -24,12 +30,64 @@ import re
 import threading
 from typing import Any, Dict, Optional, Tuple
 
+from paddle_tpu.obs.metrics import metrics
+
 __all__ = ["dispatch_cost", "site_costs", "clear_cost_cache",
-           "program_census", "device_peak_flops", "mfu"]
+           "program_census", "device_peak_flops", "mfu",
+           "dispatch_site", "watch_compiles", "compile_counts"]
 
 _CACHE: Dict[Tuple, Optional[dict]] = {}
 _BY_SITE: Dict[str, dict] = {}      # latest successful analysis per site
 _LOCK = threading.Lock()
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_COMPILES = "obs.compiles."
+_SITE = threading.local()
+_WATCHING = False
+
+
+class dispatch_site:
+    """``with dispatch_site("decode.chunk"):`` around one dispatch: a
+    backend compile inside it (same thread) is credited to the site."""
+
+    __slots__ = ("site", "_prev")
+
+    def __init__(self, site: str):
+        self.site = site
+
+    def __enter__(self):
+        self._prev = getattr(_SITE, "site", None)
+        _SITE.site = self.site
+        return self
+
+    def __exit__(self, *exc):
+        _SITE.site = self._prev
+        return False
+
+
+def _on_compile(event, duration, **kw):
+    if event == _COMPILE_EVENT:
+        metrics.counter(
+            _COMPILES + (getattr(_SITE, "site", None) or "other"),
+            "backend compiles inside this dispatch site").inc()
+
+
+def watch_compiles() -> None:
+    """Register the compile listener, once per process."""
+    global _WATCHING
+    with _LOCK:
+        if _WATCHING:
+            return
+        _WATCHING = True
+    import jax.monitoring
+    jax.monitoring.register_event_duration_secs_listener(_on_compile)
+
+
+def compile_counts() -> Dict[str, int]:
+    """``{site: backend compiles so far}`` in this process."""
+    return {n[len(_COMPILES):]: int(metrics.get(n).value)
+            for n in metrics.names() if n.startswith(_COMPILES)}
 
 
 def _sig(args, kwargs) -> Tuple:

@@ -3,12 +3,17 @@
 One thread-safe tracer serves every layer (decode dispatch wrappers,
 serving engine request timelines, bundle entries, the legacy profiler
 facade): ``with span("decode.chunk", batch=8):`` records a nested,
-monotonic-clock span into a bounded ring buffer. Nothing here touches
-jax — spans measure HOST intervals around device dispatches (what the
-host pays per dispatch, the cost the fused programs exist to spread over
-many tokens); the device-side FLOPs
-and bytes of the dispatched program ride in as span attributes from
-``obs.cost`` (compiled-program cost telemetry).
+monotonic-clock span into a bounded ring buffer. Spans measure HOST
+intervals around device dispatches (what the host pays per dispatch, the
+cost the fused programs exist to spread over many tokens); the
+device-side FLOPs and bytes of the dispatched program ride in as span
+attributes from ``obs.cost`` (compiled-program cost telemetry).
+
+Every active span, and every :func:`phase`, also opens the same-named
+``jax.profiler.TraceAnnotation``: a no-op unless a profiler session is
+live (whoever started it), and then a host event on the device trace's
+own clock — so a profiler trace of a serving run shows which host phase
+each device-idle gap falls under, with no merge step.
 
 Clock discipline: all timestamps are ``time.monotonic_ns()`` — the same
 clock family the serving engine and ``distributed/elastic.py`` use for
@@ -31,25 +36,11 @@ import json
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
-__all__ = ["Span", "Tracer", "tracer", "span", "obs_enabled",
-           "set_span_hook"]
+from jax.profiler import TraceAnnotation
 
-# Optional per-span hook: a callable ``(name, span_id) -> context
-# manager or None`` entered for the lifetime of every ACTIVE span.
-# obs/device.py plugs a jax.profiler.TraceAnnotation factory in here for
-# the duration of a device-trace capture, so the profiler timeline
-# carries one ``obs#<span_id>`` region per obs span and device-op
-# durations can be merged back onto the owning span. None (the default)
-# costs one global read per enabled span; the disabled span path never
-# consults it.
-_SPAN_HOOK: Optional[Callable[[str, int], Any]] = None
-
-
-def set_span_hook(hook: Optional[Callable[[str, int], Any]]) -> None:
-    global _SPAN_HOOK
-    _SPAN_HOOK = hook
+__all__ = ["Span", "Tracer", "tracer", "span", "phase", "obs_enabled"]
 
 
 def obs_enabled() -> bool:
@@ -120,7 +111,7 @@ class _ActiveSpan:
     telemetry hook)."""
 
     __slots__ = ("_tracer", "name", "attrs", "_start", "_parent",
-                 "span_id", "_hook_cm")
+                 "span_id", "_ann")
 
     def __init__(self, tracer_, name, attrs):
         self._tracer = tracer_
@@ -136,28 +127,14 @@ class _ActiveSpan:
         stack = t._stack()
         self._parent = stack[-1] if stack else None
         stack.append(self.span_id)
-        self._hook_cm = None
-        hook = _SPAN_HOOK
-        if hook is not None:
-            # telemetry must never break the spanned body
-            try:
-                cm = hook(self.name, self.span_id)
-                if cm is not None:
-                    cm.__enter__()
-                    self._hook_cm = cm
-            except Exception:
-                self._hook_cm = None
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._start = time.monotonic_ns()
         return self
 
     def __exit__(self, etype, exc, tb):
-        if self._hook_cm is not None:
-            try:
-                self._hook_cm.__exit__(None, None, None)
-            except Exception:
-                pass
-            self._hook_cm = None
         end = time.monotonic_ns()
+        self._ann.__exit__(None, None, None)
         stack = self._tracer._stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
@@ -345,3 +322,35 @@ tracer = Tracer()
 def span(name: str, **attrs):
     """``with obs.span("decode.chunk", batch=8):`` on the global tracer."""
     return tracer.span(name, **attrs)
+
+
+class phase:
+    """``with obs.phase("serving.step.admit", hist):`` — one always-on
+    phase of a loop. Opens the profiler annotation ``name``, adds the
+    elapsed ``time.monotonic()`` interval to ``hist`` (any object with
+    ``observe(seconds)``) on exit, raised or not, and, with obs enabled,
+    records the nested span in the ring as :func:`span` does. Disabled
+    cost: one annotation enter/exit with no session live, two clock
+    reads and one observe."""
+
+    __slots__ = ("name", "hist", "_ann", "_span", "_t0")
+
+    def __init__(self, name: str, hist):
+        self.name = name
+        self.hist = hist
+
+    def __enter__(self):
+        # the span opens the annotation itself when obs is enabled
+        self._span = tracer.span(self.name)
+        self._ann = (TraceAnnotation(self.name) if self._span is _NULL
+                     else _NULL)
+        self._ann.__enter__()
+        self._span.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self.hist.observe(time.monotonic() - self._t0)
+        self._span.__exit__(etype, exc, tb)
+        self._ann.__exit__(None, None, None)
+        return False

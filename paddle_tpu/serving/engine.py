@@ -85,6 +85,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 import paddle_tpu.obs as obs
 from paddle_tpu.obs.metrics import MetricsRegistry
@@ -322,6 +323,13 @@ class _DecoderBackend:
         import jax.numpy as jnp
         self._ring_logits = jnp.zeros((R, self.dec.cfg.vocab_size),
                                       jnp.float32)
+        if self.sharding is not None:
+            # born where the admission prefill pins its output: an
+            # uncommitted buffer would give the first-warmed bucket's
+            # program another input sharding than every later call has,
+            # and that bucket would compile again mid-serving
+            self._ring_logits = self.sharding.put_state_field(
+                "logits", self._ring_logits, self.head_major)
         self._ring_kc, self._ring_vc = self.dec._empty_cache(R)
         self._ring_dkc = self._ring_dvc = None
         if self.spec_eng is not None:
@@ -948,6 +956,36 @@ class ServingEngine:
         self._h_tpot = r.histogram(
             "serving.tpot_s", "per-request mean inter-token time after "
             "the first token")
+        self._h_ttft_submit = r.histogram(
+            "serving.ttft_from_submit_s", "time to first token as the "
+            "caller sees it (submit -> first chunk completion: the "
+            "queue wait + serving.ttft_s, on one clock)")
+        # the step measured from inside: its four phases tile step(), in
+        # this order, each a profiler annotation serving.step.<phase> and
+        # an always-on histogram of the phase's seconds per step.
+        # admit_wait is no fifth phase: it is the part of admit that the
+        # host spends blocked on a device read (the ring's row-key
+        # readback, queued behind the prefill), one interval a readback
+        self._h_phase = {
+            ph: r.histogram(f"serving.step.phase_s.{ph}", what)
+            for ph, what in (
+                ("admit", "deadline sweep, admissions, prefill enqueue "
+                          "and row-key readback, per step"),
+                ("dispatch", "ring arguments and the chunk enqueue, up "
+                             "to its return, per step"),
+                ("wait", "host blocked on the chunk's tokens (device "
+                         "time, not host gap), per step"),
+                ("harvest", "post-chunk logits readback and finite "
+                            "check, slot loop, callbacks, per step"),
+                ("admit_wait", "inside admit: host blocked on the "
+                               "row-key readback behind the prefill "
+                               "(device time), per readback"))}
+        self._open_phase = None
+        self._c_live_kv = r.counter(
+            "serving.chunk.live_kv_positions",
+            "sum over the occupied rows of the row's cache position at "
+            "the chunk's start, per chunk dispatch (the KV a chunk's "
+            "attention must at least read)")
         # prefix-cache instruments: hit classes as the ENGINE admitted
         # them (a shared cache's own stats() aggregate every engine),
         # bytes/slab gauges synced from the cache after each admission
@@ -1371,6 +1409,25 @@ class ServingEngine:
         the list (and in ``result(id)``) — accepted work always resolves
         to tokens or a typed error."""
         now = time.monotonic()
+        self._phase_to("admit")
+        try:
+            return self._step(now)
+        finally:
+            self._phase_to(None)
+
+    def _phase_to(self, name: Optional[str]) -> None:
+        """Close the running step's open phase and open ``name`` (None:
+        close only). The phases follow each other without a gap, so
+        their histograms tile ``step()``: admit -> dispatch -> wait ->
+        harvest; the degradation rungs go back to dispatch."""
+        ph, self._open_phase = self._open_phase, None
+        if ph is not None:
+            ph.__exit__(None, None, None)
+        if name is not None:
+            self._open_phase = obs.phase(
+                "serving.step." + name, self._h_phase[name]).__enter__()
+
+    def _step(self, now: float) -> List[Tuple[int, Any]]:
         if self.adapter_store is not None and \
                 self.adapter_store.version != self._b.lora_version:
             from paddle_tpu.serving.lora import AdapterVersionError
@@ -1393,10 +1450,13 @@ class ServingEngine:
         occupied = self.scheduler.slots.occupied()
         if not occupied:
             return pre
+        self._phase_to("dispatch")
         self._h_occ.observe(len(occupied) / self.num_slots)
+        self._c_live_kv.inc(sum(slot.kv_pos for _, slot in occupied))
         toks = self._dispatch_chunk(occupied)
         nv = self._last_nv
         t_chunk_done = time.monotonic()
+        self._phase_to("harvest")
         # finite guard: one harvest-time check over the post-chunk
         # logits. A numerically poisoned row (NaN/Inf) is frozen ALONE
         # and returned partial — one bad row must never take down the
@@ -1446,6 +1506,7 @@ class ServingEngine:
             # reduction survives chunk boundaries
             slot.tokens.append(toks[i] if nv is None
                                else toks[i][:int(nv[i])])
+            slot.kv_pos += len(slot.tokens[-1])
             if sr is not None:
                 dr = int(sr[i]) - slot.spec_rounds
                 da = int(sa[i]) - slot.spec_accepted
@@ -1465,6 +1526,8 @@ class ServingEngine:
                 # dispatch: admission -> here is the request's TTFT
                 slot.first_token_at = t_chunk_done
                 self._h_ttft.observe(t_chunk_done - slot.admitted_at)
+                self._h_ttft_submit.observe(
+                    t_chunk_done - slot.request.submit_time)
             req = slot.request
             seq = np.concatenate(slot.tokens)
             fin = False
@@ -2213,6 +2276,8 @@ class ServingEngine:
                 slot.chunks = int(sm["chunks"])
                 slot.tokens = [np.asarray(npz[f"row{j}_piece{p}"])
                                for p in range(int(sm["pieces"]))]
+                slot.kv_pos = len(req.prompt) + sum(
+                    len(t) for t in slot.tokens)
                 mapping[old_id] = req.id
         for j, qm in enumerate(meta["queue"]):
             req = self._req_from_meta(qm, npz[f"queue{j}_prompt"], now)
@@ -2429,24 +2494,31 @@ class ServingEngine:
             aidxN = np.asarray([self.adapter_store.index(req.adapter)
                                 for _, req in grp], np.int32)
         ev0 = self._b.event_count()
-        self._b.ring_admit(ids, true_len, pos0, rows, aidx=aidxN)
-        self._c_prefill.inc()
-        if self._spec_active:
-            self._b.ring_admit_draft(ids, rows)
-            self._c_draft_prefill.inc()
+        with TraceAnnotation("serving.admit.prefill_enqueue"):
+            self._b.ring_admit(ids, true_len, pos0, rows, aidx=aidxN)
+            self._c_prefill.inc()
+            if self._spec_active:
+                self._b.ring_admit_draft(ids, rows)
+                self._c_draft_prefill.inc()
         if N > 1:
             self._c_batched_groups.inc()
             self._c_disp_saved.inc(N - 1)
         events = self._b.events_since(ev0)
         for j, (slot_idx, req) in enumerate(grp):
-            if self.request_keyed_rng:
-                rng_id = (req.rng_request_id
-                          if req.rng_request_id is not None else req.id)
-                key1 = np.asarray(derive_row_key(
-                    req.seed, rng_id, req.rng_tokens_emitted))
-            else:
-                key1 = np.asarray(jrandom.split(
-                    jrandom.PRNGKey(req.seed), 1)[0])
+            # the key comes back to the host for the ring's splice
+            # arrays, and the readback waits out the prefill enqueued
+            # above: device time inside admit, kept apart as admit_wait
+            with obs.phase("serving.admit.row_key",
+                           self._h_phase["admit_wait"]):
+                if self.request_keyed_rng:
+                    rng_id = (req.rng_request_id
+                              if req.rng_request_id is not None
+                              else req.id)
+                    key1 = np.asarray(derive_row_key(
+                        req.seed, rng_id, req.rng_tokens_emitted))
+                else:
+                    key1 = np.asarray(jrandom.split(
+                        jrandom.PRNGKey(req.seed), 1)[0])
             self._ring_meta[rows[j]] = {
                 "slot": slot_idx, "pos": len(req.prompt),
                 "key": np.asarray(key1, np.uint32),
@@ -2530,8 +2602,9 @@ class ServingEngine:
             aidxN = np.asarray([self.adapter_store.index(req.adapter)
                                 for _, req, _, _ in grp], np.int32)
         ev0 = self._b.event_count()
-        logitsN, kcN, vcN = self._b.admit_prefill(ids, true_len, pos0,
-                                                  kcN, vcN, aidx=aidxN)
+        with TraceAnnotation("serving.admit.prefill_enqueue"):
+            logitsN, kcN, vcN = self._b.admit_prefill(
+                ids, true_len, pos0, kcN, vcN, aidx=aidxN)
         self._c_prefill.inc()
         if N > 1:
             self._c_batched_groups.inc()
@@ -2649,6 +2722,11 @@ class ServingEngine:
                 last[key] = st[key]
 
     def _dispatch_chunk(self, occupied) -> np.ndarray:
+        """The chunk through the degradation ladder (speculative ->
+        chunked -> per-token), entered in the step's ``dispatch`` phase:
+        each rung switches to ``wait`` once its enqueue has returned and
+        the host blocks on the tokens, and a rung that takes over from a
+        failed one goes back to ``dispatch``."""
         from paddle_tpu.flags import flags as _flags
         from paddle_tpu.runtime.resilience import (
             DecodeFailedError, DegradationEvent, classify_error,
@@ -2669,8 +2747,9 @@ class ServingEngine:
                 self._c_chunk.inc()
                 self._c_slot_steps.inc(self.num_slots * self.chunk_size)
                 self._ring_drained(n_staged)
-                self._last_nv = np.asarray(jax.device_get(nv))
                 self._note_events(occupied, ev0, [])
+                self._phase_to("wait")
+                self._last_nv = np.asarray(jax.device_get(nv))
                 return np.asarray(toks)
             except Exception as e:
                 if classify_error(e) != "transient":
@@ -2699,6 +2778,7 @@ class ServingEngine:
                 record_event(ev)
                 self._c_degr.inc()
                 degr.append(ev)
+                self._phase_to("dispatch")
                 self.state = self._b.spec_demote(self.state)
                 self._spec_active = False
         try:
@@ -2718,6 +2798,7 @@ class ServingEngine:
             self._c_slot_steps.inc(self.num_slots * self.chunk_size)
             self._ring_drained(n_staged)
             self._note_events(occupied, ev0, degr)
+            self._phase_to("wait")
             return np.asarray(toks)
         except Exception as e:
             if classify_error(e) != "transient":
@@ -2753,6 +2834,7 @@ class ServingEngine:
         parts = []
         try:
             for s in range(self.chunk_size):
+                self._phase_to("dispatch")
                 if self.replica_tag:
                     fault_injector.on_call(
                         f"serving.{self.replica_tag}.step")
@@ -2765,6 +2847,7 @@ class ServingEngine:
                 else:
                     toks1, self.state = self._b.decode_step(self.state)
                 self._c_step.inc()
+                self._phase_to("wait")
                 parts.append(np.asarray(toks1))
         except Exception as e2:
             # the ladder is exhausted mid-rung. Tokens from the steps
@@ -2876,7 +2959,12 @@ class ServingEngine:
             "serving": {
                 "queue_delay_s": slot.admitted_at - req.submit_time,
                 "latency_s": latency,
+                # from admission; first_token_s is the same instant
+                # from submit (== queue_delay_s + ttft_s, one clock)
                 "ttft_s": ttft,
+                "first_token_s": (
+                    None if slot.first_token_at is None
+                    else slot.first_token_at - req.submit_time),
                 "tpot_s": tpot,
                 "chunks": slot.chunks,
                 "slot": slot_idx,
@@ -3147,7 +3235,19 @@ class ServingEngine:
         steps; mean slot occupancy over chunk dispatches; queue-delay
         stats; the slot-steps useful-token denominator). New on top:
         p50/p99/mean REQUEST latency (submit -> finished, monotonic
-        end-to-end) and queue-depth now/mean/peak snapshots."""
+        end-to-end) and queue-depth now/mean/peak snapshots.
+
+        ``ttft_*`` runs from ADMISSION (it leaves the queue wait out);
+        ``ttft_from_submit_*`` is what the caller saw. ``step_phase_s``
+        holds the seconds and the number of intervals of each phase of
+        ``step()``: ``admit``, ``dispatch``, ``wait`` and ``harvest``
+        tile it (``admit``'s count is the steps so far), and
+        ``admit_wait`` is the part of ``admit`` spent blocked on the
+        row-key readback. ``wait`` and ``admit_wait`` are device time;
+        the host's own is admit - admit_wait + dispatch + harvest.
+        ``live_kv_positions_total`` is the cache
+        positions live at the start of every chunk dispatched so far,
+        ``compiles`` this process's backend compiles by dispatch site."""
         qd, lat = self._h_qdelay, self._h_latency
         return {
             "num_slots": self.num_slots,
@@ -3164,6 +3264,10 @@ class ServingEngine:
             # ALL rows compute every chunk step, occupied or not — the
             # honest denominator for useful-token occupancy comparisons
             "slot_steps_total": int(self._c_slot_steps.value),
+            "step_phase_s": {ph: {"sum": h.sum, "count": h.count}
+                             for ph, h in self._h_phase.items()},
+            "live_kv_positions_total": int(self._c_live_kv.value),
+            "compiles": obs.compile_counts(),
             "queue_delay_mean_s": qd.mean,
             "queue_delay_p50_s": qd.percentile(50),
             "queue_delay_p99_s": qd.percentile(99),
@@ -3178,6 +3282,8 @@ class ServingEngine:
             "ttft_mean_s": self._h_ttft.mean,
             "ttft_p50_s": self._h_ttft.percentile(50),
             "ttft_p99_s": self._h_ttft.percentile(99),
+            "ttft_from_submit_p50_s": self._h_ttft_submit.percentile(50),
+            "ttft_from_submit_p99_s": self._h_ttft_submit.percentile(99),
             "tpot_mean_s": self._h_tpot.mean,
             "tpot_p50_s": self._h_tpot.percentile(50),
             "slo_violations": int(sum(

@@ -119,6 +119,10 @@ class Slot:
     # stamped after the first chunk dispatch the slot rode: the
     # admission->first-token interval is the TTFT instrument's sample
     first_token_at: Optional[float] = None
+    # the row's cache position at the start of its next chunk: prompt
+    # length + tokens delivered so far, advanced at each harvest (the
+    # live-KV counter sums it over the occupied rows per chunk dispatch)
+    kv_pos: int = 0
     # prefix-cache admission record (serving/prefix_cache.py): the hit
     # class this admission resolved to (None = cache disabled), the
     # prompt tokens whose prefill it skipped, how many prefill
@@ -146,6 +150,11 @@ class Slot:
     # of THIS adapter while the row is in flight raises the typed
     # AdapterVersionError instead of silently switching tenants mid-seq
     adapter_rev: Optional[int] = None
+
+    def __post_init__(self):
+        if not self.kv_pos:
+            self.kv_pos = len(self.request.prompt) + sum(
+                len(t) for t in self.tokens)
 
 
 class SlotTable:

@@ -12,6 +12,8 @@ The load-bearing properties:
   jitted-dispatch span (cached per site/signature);
 - the DISABLED path adds no measurable per-call work (the near-zero
   overhead contract that lets the instrumentation live on hot paths);
+- ``phase`` always feeds its histogram, records a span only with obs
+  on, and, like ``span``, shows up once in a live profiler trace;
 - serving latency math is time.monotonic end-to-end (a scheduler-level
   push stamps the submit time itself).
 """
@@ -136,6 +138,102 @@ def test_disabled_path_near_zero_overhead():
     with obs.span("x"):
         pass
     assert obs.tracer.spans_since(m) == []
+
+# -- phase -------------------------------------------------------------------
+
+class _Sum:
+    """The least a phase needs of a histogram."""
+
+    def __init__(self):
+        self.sum, self.count = 0.0, 0
+
+    def observe(self, v):
+        self.sum += v
+        self.count += 1
+
+
+@pytest.mark.parametrize("enabled", [False, True])
+def test_phase_feeds_its_histogram_and_records_only_when_enabled(enabled):
+    set_flags({"obs_enabled": enabled})
+    try:
+        h = MetricsRegistry().histogram("p")
+        m = obs.tracer.mark()
+        with obs.phase("outer.phase", h):
+            with obs.phase("inner.phase", h):
+                time.sleep(0.002)
+        with pytest.raises(ZeroDivisionError):
+            with obs.phase("outer.phase", h):
+                1 / 0
+        assert h.count == 3                  # a raising body still counts
+        assert 0.004 <= h.sum < 1.0          # inner + outer, both >= 2 ms
+        spans = obs.tracer.spans_since(m)
+        if not enabled:
+            assert spans == []
+            return
+        by = {s.name: s for s in spans[:2]}
+        assert by["inner.phase"].parent_id == by["outer.phase"].span_id
+        assert "error" in spans[2].attrs     # as obs.span records it
+    finally:
+        set_flags({"obs_enabled": False})
+
+
+def test_phase_disabled_path_bounded_cost():
+    """phase() sits in ServingEngine.step four times a step with obs
+    off: an annotation enter/exit with no profiler session, two clock
+    reads and one observe. Bounded per call, and nothing recorded."""
+    set_flags({"obs_enabled": False})
+    h = _Sum()
+    n = 20000
+    for _ in range(100):
+        with obs.phase("x", h):
+            pass
+    t0 = time.perf_counter()
+    for _ in range(n):
+        pass
+    base = time.perf_counter() - t0
+    m = obs.tracer.mark()
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with obs.phase("x", h):
+            pass
+    per_call = (time.perf_counter() - t0 - base) / n
+    assert per_call < 20e-6, f"disabled phase() costs {per_call*1e6:.2f}µs"
+    assert h.count == n + 100
+    assert obs.tracer.spans_since(m) == []
+
+
+def test_span_and_phase_open_one_profiler_annotation_each(obs_on, tmp_path):
+    """With obs on, a span is also a host event of the same name in a
+    live profiler trace, and a phase (which records a span) is there
+    once, not twice."""
+    import glob
+
+    import jax
+    try:
+        from jax.profiler import ProfileData
+    except ImportError:
+        pytest.skip("jax.profiler.ProfileData unavailable")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    try:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    except Exception as e:                     # pragma: no cover
+        pytest.skip(f"jax.profiler unavailable: {e}")
+    try:
+        with obs.span("t.span", kind="dispatch"):
+            with obs.phase("t.phase", _Sum()):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    names = []
+    for path in glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb")):
+        for plane in ProfileData.from_file(path).planes:
+            for line in plane.lines:
+                names += [e.name for e in line.events
+                          if e.name.startswith("t.")]
+    if not names:
+        pytest.skip("this backend's profiler records no host annotations")
+    assert sorted(names) == ["t.phase", "t.span"]
 
 
 # -- metrics -----------------------------------------------------------------

@@ -1,13 +1,15 @@
-"""Live telemetry plane + device-time attribution (PR 6 obs rungs).
+"""Live telemetry plane + the serving step measured from inside.
 
 The load-bearing properties:
 - the exporter serves /metrics (Prometheus text incl. every attached
   registry + the tracer-saturation gauge), /statusz (strict JSON with
   the engine's slot table / queue / ladder rung) and /tracez (recent
   spans), binds an ephemeral port and RELEASES it on stop;
-- the device-trace merge attributes jax.profiler device-op durations
-  back onto the owning dispatch spans on the CPU backend (device_ms /
-  device_occupancy attrs, nonzero coverage);
+- the four phases of ``ServingEngine.step`` tile it (always-on
+  histograms), show up in a ``jax.profiler`` trace as host annotations
+  inside the caller's, and the counts taken at the same boundaries are
+  right: live KV positions per chunk, a request's first token from
+  submit, backend compiles by dispatch site;
 - TTFT/TPOT histograms and per-class SLO violation counters are
   correct on a deterministic serve run;
 - the flight recorder dumps a postmortem JSON (spans + resilience
@@ -31,7 +33,6 @@ import pytest
 import paddle_tpu as paddle
 import paddle_tpu.obs as obs
 from paddle_tpu.flags import set_flags
-from paddle_tpu.obs.device import merge_device_events
 from paddle_tpu.obs.metrics import MetricsRegistry
 
 pytestmark = pytest.mark.obs
@@ -125,59 +126,185 @@ def test_exporter_status_provider_errors_stay_in_band(obs_on):
         exp.stop()
 
 
-# -- device-time attribution -------------------------------------------------
+# -- the serving step measured from inside -----------------------------------
 
-def test_device_trace_merge_on_cpu(obs_on, dec):
-    """A generate inside a DeviceTraceSession: the profiler's device-op
-    durations merge back onto the prefill/fused dispatch spans, and the
-    session's attribution coverage is nonzero — the CPU-backend proof
-    of the jax.profiler merge path."""
-    prompt = np.arange(4)[None] % 64
-    dec.generate(prompt, max_new_tokens=6)      # compile outside capture
-    m0 = obs.tracer.mark()
-    sess = obs.DeviceTraceSession().start()
-    if not sess.active:
-        pytest.skip("jax.profiler unavailable on this backend")
-    dec.generate(prompt, max_new_tokens=6)
-    summary = sess.stop()
-    if summary.get("device_ops", 0) == 0:
-        pytest.skip("profiler captured no device ops on this backend")
-    assert summary["active"] and summary["merged_spans"] >= 2
-    assert 0.0 < summary["coverage"] <= 1.0
-    assert summary["attributed_ms"] > 0
-    by_site = summary["by_site"]
-    assert by_site["decode.prefill"]["spans"] == 1
-    assert by_site["decode.fused"]["spans"] == 1
-    spans = {s.name: s for s in obs.tracer.spans_since(m0)}
-    for site in ("decode.prefill", "decode.fused"):
-        assert spans[site].attrs["device_ms"] > 0
-        assert spans[site].attrs["device_occupancy"] > 0
+# (prompt length, budget) of the requests of one deterministic serve on
+# 2 slots x chunk 4: requests 0 and 1 take the slots, 0 finishes after
+# its second chunk and 2 refills its slot
+SERVED = [(3, 6), (4, 10), (5, 8)]
 
 
-def test_device_merge_attribution_rules():
-    """Pure-merge unit: ops attribute to the window they overlap most
-    (innermost on ties), unattributed ops count against coverage."""
-    ann = [{"name": "obs#1", "ts": 0.0, "dur": 100.0},
-           {"name": "obs#2", "ts": 200.0, "dur": 50.0},
-           {"name": "obs#3", "ts": 10.0, "dur": 20.0}]   # nested in #1
-    ops = [{"name": "dot", "ts": 5.0, "dur": 4.0, "args": {"hlo_op": "dot"}},
-           {"name": "mul", "ts": 12.0, "dur": 10.0,
-            "args": {"hlo_op": "mul"}},                  # innermost -> #3
-           {"name": "add", "ts": 210.0, "dur": 30.0,
-            "args": {"hlo_op": "add"}},                  # -> #2
-           {"name": "orphan", "ts": 500.0, "dur": 10.0,
-            "args": {"hlo_op": "orphan"}}]               # no window
-    out = merge_device_events(ann, ops)
-    assert out["attributed_us"] == {1: 4.0, 3: 10.0, 2: 30.0}
-    assert out["device_total_us"] == 54.0
-    assert out["coverage"] == pytest.approx(44.0 / 54.0)
+def _engine(dec, mode, **kw):
+    from paddle_tpu.serving import ServingEngine
+    if mode == "host_scatter":      # the cache keeps the legacy admission
+        kw.update(prefix_cache=True, prefix_block_tokens=4)
+    return ServingEngine(dec, num_slots=2, chunk_size=4, **kw)
 
 
-def test_device_session_requires_obs():
-    set_flags({"obs_enabled": False})
-    sess = obs.DeviceTraceSession().start()
-    assert not sess.active
-    assert sess.stop() == {"active": False}
+@pytest.fixture(scope="module", params=["ring", "host_scatter"])
+def served(request, dec):
+    """One serve per admission mode: the engine's metrics before and
+    after, the results, and the wall time of the step() calls."""
+    import time
+    eng = _engine(dec, request.param)
+    assert bool(eng._ring_slots) == (request.param == "ring")
+    m0 = eng.metrics()
+    rids = [eng.submit(np.arange(P) % 64, N, seed=i)
+            for i, (P, N) in enumerate(SERVED)]
+    wall, steps = 0.0, 0
+    while len(eng.scheduler) or eng.scheduler.slots.occupied():
+        t0 = time.monotonic()
+        eng.step()
+        wall += time.monotonic() - t0
+        steps += 1
+    t0 = time.monotonic()
+    eng.step()                      # an idle step: admit only
+    wall += time.monotonic() - t0
+    return {"m0": m0, "m1": eng.metrics(), "wall": wall,
+            "steps": steps + 1, "eng": eng,
+            "records": [eng.result(r).resilience["serving"] for r in rids]}
+
+
+def test_step_phases_tile_the_step(served):
+    m0, m1 = served["m0"], served["m1"]
+    ph = dict(m1["step_phase_s"])
+    assert list(ph) == ["admit", "dispatch", "wait", "harvest",
+                        "admit_wait"]
+    # admit_wait is no fifth phase but the part of admit spent blocked
+    # on the ring's row-key readback: one interval a ring admission
+    blocked = ph.pop("admit_wait")
+    ring = bool(served["eng"]._ring_slots)
+    assert blocked["count"] == (len(SERVED) if ring else 0)
+    assert 0 < blocked["sum"] < ph["admit"]["sum"] if ring \
+        else blocked["sum"] == 0
+    # every step admits; only a step with an occupied row goes on
+    assert m0["step_phase_s"]["admit"]["count"] == 0
+    assert ph["admit"]["count"] == served["steps"]
+    for name in ("dispatch", "wait", "harvest"):
+        assert ph[name]["count"] == m1["chunk_dispatches"] \
+            == served["steps"] - 1
+    total = sum(v["sum"] for v in ph.values())
+    assert total <= served["wall"]
+    assert total >= 0.9 * served["wall"], (total, served["wall"])
+    # the registry a Prometheus scrape reads carries the same sums
+    txt = served["eng"].registry.to_prometheus()
+    assert f"serving_step_phase_s_wait_count {ph['wait']['count']}" in txt
+    assert "serving_chunk_live_kv_positions" in txt
+
+
+def test_first_token_from_submit_is_queue_plus_ttft(served):
+    for r in served["records"]:
+        assert r["first_token_s"] == pytest.approx(
+            r["queue_delay_s"] + r["ttft_s"], abs=1e-9)
+        assert r["first_token_s"] >= r["ttft_s"] > 0
+    # request 2 waited a whole chunk for a slot: only the histogram from
+    # submit sees that
+    assert served["records"][2]["queue_delay_s"] > 0
+    m = served["m1"]
+    assert m["ttft_from_submit_p99_s"] >= m["ttft_p99_s"]
+    assert m["ttft_from_submit_p50_s"] == pytest.approx(
+        sorted(r["first_token_s"] for r in served["records"])[1])
+
+
+def test_live_kv_positions_match_a_hand_count(served):
+    # a row's position at the start of its k-th chunk is its prompt
+    # length + 4 k; requests 0, 1, 2 ride 2, 3, 2 chunks
+    want = (3 + 7) + (4 + 8 + 12) + (5 + 9)
+    m = served["m1"]
+    assert [r["chunks"] for r in served["records"]] == [2, 3, 2]
+    assert m["live_kv_positions_total"] == want
+    # rows a chunk: occupancy_mean x samples x slots, as the reader has it
+    rows = m["occupancy_mean"] * m["occupancy_samples"] * m["num_slots"]
+    assert rows == pytest.approx(7)
+    assert served["m0"]["live_kv_positions_total"] == 0
+
+
+@pytest.mark.parametrize("mesh", [None, "tp:2"])
+def test_compiles_are_credited_to_the_dispatch_site(mesh):
+    """A new admission bucket compiles once under decode.admit_prefill,
+    the first chunk once under decode.chunk; the same shapes again
+    compile nothing anywhere — under a mesh too, where a ring buffer
+    born off the mesh once made the first-warmed bucket compile again
+    mid-serving (the counter is what found it)."""
+    from paddle_tpu.inference.generate import LlamaDecoder
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(0)
+    fresh = LlamaDecoder(LlamaForCausalLM(LlamaConfig(**CFG)), max_len=64,
+                         mesh=mesh)
+    eng = _engine(fresh, "ring")
+    base = obs.compile_counts()
+
+    def serve(P):
+        eng.submit(np.arange(P) % 64, 4)
+        eng.drain()
+        now = eng.metrics()["compiles"]
+        assert set(now) <= set(obs.compile_counts())
+        return {k: v - base.get(k, 0) for k, v in now.items()}
+
+    c0 = serve(3)
+    assert c0["decode.admit_prefill"] == 1 and c0["decode.chunk"] == 1
+    c1 = serve(3)
+    assert c1 == c0                            # a repeat compiles nothing
+    c2 = serve(33)                             # a bucket not seen before
+    assert c2["decode.admit_prefill"] == 2 and c2["decode.chunk"] == 1
+    assert serve(33) == c2 and serve(3) == c2
+
+
+def test_profiler_trace_holds_the_phases_inside_the_callers_span(
+        dec, tmp_path):
+    """A profiler session anyone started sees serving.step.* as host
+    annotations on the trace's own clock, nested in the caller's."""
+    import glob
+
+    import jax
+    try:
+        from jax.profiler import ProfileData
+    except ImportError:
+        pytest.skip("jax.profiler.ProfileData unavailable")
+    eng = _engine(dec, "ring")
+    eng.submit(np.arange(3) % 64, 6)
+    eng.drain()                                # compile outside the trace
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    try:
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    except Exception as e:                     # pragma: no cover
+        pytest.skip(f"jax.profiler unavailable: {e}")
+    try:
+        eng.submit(np.arange(3) % 64, 6)
+        with jax.profiler.TraceAnnotation("caller.engine_step"):
+            eng.step()
+        with obs.span("obs.disabled_span"):    # obs off: no annotation
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    assert paths, "the profiler wrote no .xplane.pb"
+    ev = {}
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(("serving.", "caller.", "obs.")):
+                        ev[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    if "caller.engine_step" not in ev:
+        pytest.skip("this backend's profiler records no host annotations")
+    lo, hi = ev["caller.engine_step"]
+    order = ["serving.step.admit", "serving.step.dispatch",
+             "serving.step.wait", "serving.step.harvest"]
+    for name in order:
+        assert lo <= ev[name][0] and ev[name][1] <= hi, name
+    # one after the other, in the step's order
+    assert [n for n, _ in sorted(((n, ev[n][0]) for n in order),
+                                 key=lambda kv: kv[1])] == order
+    for a, b in zip(order, order[1:]):
+        assert ev[a][1] <= ev[b][0]
+    # the prefill enqueue and the row-key readback sit inside admit
+    a0, a1 = ev["serving.step.admit"]
+    for name in ("serving.admit.prefill_enqueue", "serving.admit.row_key"):
+        assert a0 <= ev[name][0] and ev[name][1] <= a1, name
+    assert "obs.disabled_span" not in ev
 
 
 # -- SLO instruments ---------------------------------------------------------
@@ -326,33 +453,3 @@ def test_engine_metrics_nan_before_first_sample(dec):
     safe = json_safe(m)
     assert safe["request_latency_p50_s"] is None
     json.dumps(safe, allow_nan=False)      # strict-JSON clean
-
-
-# -- trace_report device columns ---------------------------------------------
-
-def test_trace_report_device_columns(tmp_path):
-    import sys
-    sys.path.insert(0, "tools")
-    try:
-        import trace_report
-    finally:
-        sys.path.pop(0)
-    spans = [
-        {"name": "decode.chunk", "dur_ms": 2.0, "kind": "span",
-         "attrs": {"device_ms": 1.5, "device_occupancy": 0.75}},
-        {"name": "decode.chunk", "dur_ms": 2.0, "kind": "span",
-         "attrs": {}},                       # never got device time
-        {"name": "serving.request", "dur_ms": 5.0, "kind": "span",
-         "attrs": {}},
-    ]
-    rows = {r["phase"]: r for r in trace_report.phase_table(spans)}
-    chunk = rows["decode.chunk"]
-    assert chunk["device_ms"] == 1.5
-    assert chunk["device_occ_pct"] == pytest.approx(37.5)
-    assert chunk["no_device"] == 1           # one span unattributed
-    assert rows["serving.request"]["device_ms"] is None
-    assert rows["serving.request"]["no_device"] == 1
-    # without any device attrs the table stays in its legacy shape
-    legacy = trace_report.phase_table(
-        [{"name": "x", "dur_ms": 1.0, "attrs": {}}])
-    assert "device_ms" not in legacy[0]

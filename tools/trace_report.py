@@ -8,11 +8,7 @@ Output: two text tables —
 
 - **phases**: per span name, the count / total / mean / p50 / max
   duration, with attached cost-telemetry columns (per-dispatch GFLOPs
-  from the span attrs) when present; when the trace carries device
-  attribution (obs/device.py merged-profiler ``device_ms`` attrs) the
-  table grows host-vs-device columns — measured device_ms, device
-  occupancy % of the host interval — and spans that never got device
-  time are flagged;
+  from the span attrs) when present;
 - **requests**: one row per ``serving.request`` lifetime span (queue
   delay, service latency, chunks, slot, ladder level) — the
   iteration-level serving view; a completeness line flags any request
@@ -79,8 +75,6 @@ def phase_table(spans):
     per = defaultdict(list)
     flops = {}
     errors = defaultdict(int)
-    device_ms = defaultdict(float)
-    device_spans = defaultdict(int)
     for s in spans:
         per[s["name"]].append(s["dur_ms"])
         a = s.get("attrs") or {}
@@ -88,10 +82,6 @@ def phase_table(spans):
             flops[s["name"]] = float(a["flops"])
         if "error" in a:
             errors[s["name"]] += 1
-        if "device_ms" in a:
-            device_ms[s["name"]] += float(a["device_ms"])
-            device_spans[s["name"]] += 1
-    has_device = bool(device_ms)
     rows = []
     for name, durs in sorted(per.items(), key=lambda kv: -sum(kv[1])):
         row = {
@@ -104,21 +94,6 @@ def phase_table(spans):
             "gflops_per_dispatch": (round(flops[name] / 1e9, 6)
                                     if name in flops else None),
         }
-        if has_device:
-            # host-vs-device attribution columns (obs/device.py merge):
-            # measured device time and its share of the host interval;
-            # a dispatch phase with NO device time never got attributed
-            # — flagged rather than silently blank
-            if name in device_ms:
-                row["device_ms"] = round(device_ms[name], 3)
-                row["device_occ_pct"] = round(
-                    100.0 * device_ms[name] / sum(durs), 1) \
-                    if sum(durs) else None
-                row["no_device"] = len(durs) - device_spans[name]
-            else:
-                row["device_ms"] = None
-                row["device_occ_pct"] = None
-                row["no_device"] = len(durs)
         rows.append(row)
     return rows
 
@@ -188,16 +163,8 @@ def main(argv=None):
     requests, completeness = request_table(spans, events)
     cols = ["phase", "count", "total_ms", "mean_ms", "p50_ms", "max_ms",
             "errors", "gflops_per_dispatch"]
-    has_device = any("device_ms" in r for r in phases)
-    if has_device:
-        cols += ["device_ms", "device_occ_pct", "no_device"]
     _print_table(phases, cols,
                  f"phases ({len(spans)} spans, {len(events)} events)")
-    if has_device:
-        missing = [r["phase"] for r in phases if r.get("no_device")]
-        if missing:
-            print(f"spans WITHOUT device attribution (never matched a "
-                  f"profiler device op): {missing}")
     if requests or completeness["timeline_requests"]:
         _print_table(requests, ["request", "queue_delay_ms", "latency_ms",
                                 "chunks", "tokens", "slot", "level"],
