@@ -303,9 +303,7 @@ def _serve_run(dec, sz: dict, prompts, budgets) -> dict:
         done.update(eng.drain())
         wall = time.perf_counter() - t0
         m = eng.metrics()
-        kc = eng.state.kc[0] if isinstance(eng.state.kc, tuple) \
-            else eng.state.kc
-        carry = _placement(kc)
+        carry = _placement(eng.state.kc[0])
         costs = obs.site_costs()
     finally:
         paddle.set_flags(old)

@@ -197,10 +197,6 @@ define_flag("resilience_auto_degrade", True,
             "off = the first level's error propagates (the pre-round-8 "
             "behavior, where only the manual decode_fallback flag could "
             "change the path)")
-define_flag("decode_cache_layout", "stacked",
-            "KV-cache layout for the compiled decoder: 'per_layer' "
-            "(one (B, L, KV, D) buffer per layer) or 'stacked' "
-            "((layers, B, L, KV, D) single buffer)")
 define_flag("fused_ce_logits_budget_mb", 1536,
             "transient f32 logits budget (MB) for the chunked fused "
             "lm-head CE; the vocab chunk is the largest multiple of 1024 "

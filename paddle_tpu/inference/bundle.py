@@ -243,22 +243,22 @@ def export_decoder_bundle(decoder, out_dir: str,
     manifest = {}
 
     def _cache_meta(kc):
+        """What the serving process rebuilds the carry from: ``kc`` is one
+        buffer per layer (``_empty_cache``), all of one shape."""
         from paddle_tpu.quantization.kv_cache import is_quantized_kv
-        bufs = kc if isinstance(kc, tuple) else (kc,)
-        meta = {"n_buffers": len(bufs),
-                "layout": "stacked" if len(bufs) == 1 else "per_layer"}
-        if is_quantized_kv(bufs[0]):
+        meta = {"n_buffers": len(kc), "layout": "per_layer"}
+        buf = kc[0]
+        if is_quantized_kv(buf):
             # int8 KV carry (the int8wk recipe): the serving process
             # rebuilds {"q": int8, "s": f32 scale} buffers from this
             meta.update(
-                shape=list(bufs[0]["q"].shape),
-                dtype=str(bufs[0]["q"].dtype),
-                quant={"kv": str(bufs[0]["q"].dtype),
-                       "scale_shape": list(bufs[0]["s"].shape),
-                       "scale_dtype": str(bufs[0]["s"].dtype)})
+                shape=list(buf["q"].shape),
+                dtype=str(buf["q"].dtype),
+                quant={"kv": str(buf["q"].dtype),
+                       "scale_shape": list(buf["s"].shape),
+                       "scale_dtype": str(buf["s"].dtype)})
         else:
-            meta.update(shape=list(bufs[0].shape),
-                        dtype=str(bufs[0].dtype))
+            meta.update(shape=list(buf.shape), dtype=str(buf.dtype))
         return meta
 
     for B in batch_sizes:
@@ -787,7 +787,10 @@ class AotPredictor:
             return self._sharding.put_state_field("kc", buf,
                                                   self._head_major())
 
-        if cm["n_buffers"] == 1:
+        if cm["layout"] == "stacked":
+            # a bundle exported before the carry became one buffer per
+            # layer: its programs take one array stacked over layers (the
+            # recorded shape is that array's), and still serve
             return z(), z()
         kc = tuple(z() for _ in range(cm["n_buffers"]))
         vc = tuple(z() for _ in range(cm["n_buffers"]))

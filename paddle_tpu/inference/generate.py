@@ -5,10 +5,12 @@ paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu
 (block-table KV cache attention) and the fused generation ops — in the
 TPU-native form: a PURE functional forward with a statically-shaped KV
 cache — token-major ``(B, max_len, KV, D)`` for MHA, head-major
-``(B, KV, max_len, D)`` for GQA (the decode-kernel layout); stacked over
-layers by default, or one buffer per layer via
-``flags.decode_cache_layout='per_layer'`` (measured equal-or-slower on
-v5e; kept as a tuning knob) — so prefill and every decode step are each
+``(B, KV, max_len, D)`` for GQA (the decode-kernel layout); one buffer
+per layer, so that a step writes its token rows into each layer's buffer
+in place (one array stacked over layers cost a copy of the whole cache
+out of and back into the stack every step, 35 % of a serving step on
+the v5e with GQA and 58 % with MHA: PERF.md section 6, PR 27) —
+so prefill and every decode step are each
 ONE cached-compile XLA program (no recompiles across steps; static shapes
 are what the MXU wants). Block tables are unnecessary: XLA owns memory, and
 a padded dense cache + position mask is the layout it tiles best.
@@ -70,8 +72,8 @@ class DecodeState:
     """
 
     logits: Any           # (B, V) f32 — logits the next pick samples from
-    kc: Any               # target KV caches (stacked array or per-layer
-    vc: Any               #   tuple; see _empty_cache)
+    kc: Any               # target KV caches: a tuple of one 4-D buffer
+    vc: Any               #   per layer (see _empty_cache)
     pos: Any              # (B,) i32 — per-row next cache write position
     keys: Any             # (B, 2) u32 — per-row RNG keys
     done: Any             # (B,) bool — frozen rows (eos hit / slot free)
@@ -169,26 +171,6 @@ def _mm(x, p, name, sharded=False, aidx=None):
     return out
 
 
-def _cache_layer(kc, li):
-    """ONE layer's buffer out of a stacked cache: plain slice for an
-    array, per-leaf slice for a quantized ``{"q", "s"}`` buffer."""
-    from paddle_tpu.quantization.kv_cache import is_quantized_kv
-    if is_quantized_kv(kc):
-        return {"q": kc["q"][li], "s": kc["s"][li]}
-    return kc[li]
-
-
-def _cache_layer_set(kc, kc_l, li):
-    """Write one layer's updated buffer back into a stacked cache."""
-    from paddle_tpu.quantization.kv_cache import is_quantized_kv
-    if is_quantized_kv(kc):
-        return {"q": jax.lax.dynamic_update_slice(
-                    kc["q"], kc_l["q"][None], (li, 0, 0, 0, 0)),
-                "s": jax.lax.dynamic_update_slice(
-                    kc["s"], kc_l["s"][None], (li, 0, 0, 0, 0))}
-    return jax.lax.dynamic_update_slice(kc, kc_l[None], (li, 0, 0, 0, 0))
-
-
 def _cache_update(buf, t, pos, head_major, sharded=False):
     """Write t into ONE layer's cache buffer at [pos, pos+S). Scalar pos:
     a single dynamic-update-slice. Per-row (B,) pos: the same DUS vmapped
@@ -238,24 +220,21 @@ def _cache_update(buf, t, pos, head_major, sharded=False):
 
 
 def _row_scatter(dst, src, idx):
-    """Scatter whole batch rows ``src[j] -> dst[idx[j]]`` on the cache
-    batch axis (``ndim - 4``: 0 for a per-layer 4-D buffer, 1 for a
-    stacked 5-D one), recursing over per-layer tuples and quantized
-    ``{"q", "s"}`` leaves. ``idx`` entries >= dst's batch size DROP
-    (``mode="drop"``) — the admission-ring convention maps empty ring
-    rows to that sentinel (NEVER pass raw -1: negative scatter indices
-    wrap). Used both to stage admission-prefill rows into the ring and
-    to splice ring rows into the live carry inside the chunk program."""
+    """Scatter whole batch rows ``src[j] -> dst[idx[j]]`` on the leading
+    (batch) axis of every per-layer cache buffer, recursing over the
+    per-layer tuple and quantized ``{"q", "s"}`` leaves. ``idx`` entries
+    >= dst's batch size DROP (``mode="drop"``) — the admission-ring
+    convention maps empty ring rows to that sentinel (NEVER pass raw -1:
+    negative scatter indices wrap). Used both to stage admission-prefill
+    rows into the ring and to splice ring rows into the live carry inside
+    the chunk program."""
     from paddle_tpu.quantization.kv_cache import is_quantized_kv
     if is_quantized_kv(dst):
         return {"q": _row_scatter(dst["q"], src["q"], idx),
                 "s": _row_scatter(dst["s"], src["s"], idx)}
     if isinstance(dst, tuple):
         return tuple(_row_scatter(d, s, idx) for d, s in zip(dst, src))
-    ax = dst.ndim - 4
-    if ax <= 0:
-        return dst.at[idx].set(src, mode="drop")
-    return dst.at[:, idx].set(src, mode="drop")
+    return dst.at[idx].set(src, mode="drop")
 
 
 def _block_forward(p, cfg: LlamaConfig, li: int, h, kc, vc, pos, max_len,
@@ -291,19 +270,18 @@ def _block_forward(p, cfg: LlamaConfig, li: int, h, kc, vc, pos, max_len,
     #                        which XLA's fused matvec prefers (measured)
     kt = jnp.swapaxes(k, 1, 2) if head_major else k
     vt = jnp.swapaxes(v, 1, 2) if head_major else v
-    if isinstance(kc, tuple):
-        # per-layer cache buffers: an update on THIS layer's array only
-        kc_l = _cache_update(kc[li], kt, pos, head_major, sharded)
-        vc_l = _cache_update(vc[li], vt, pos, head_major, sharded)
-        kc = tuple(kc_l if i == li else c for i, c in enumerate(kc))
-        vc = tuple(vc_l if i == li else c for i, c in enumerate(vc))
-    else:
-        kc_l = _cache_update(_cache_layer(kc, li), kt, pos, head_major,
-                             sharded)
-        vc_l = _cache_update(_cache_layer(vc, li), vt, pos, head_major,
-                             sharded)
-        kc = _cache_layer_set(kc, kc_l, li)
-        vc = _cache_layer_set(vc, vc_l, li)
+    # each layer owns its buffer: the token rows are written into layer
+    # li's array in place and attention reads that array. (From a carry
+    # stacked over layers XLA copied the whole layer out and back in here,
+    # every layer of every step. Measured on the v5e at 7B widths, PR 27:
+    # a serving step 23.3 -> 15.3 ms with 12 GQA layers x 16 slots, 23.6
+    # -> 9.9 ms with 8 MHA layers x 8 slots. The Mosaic kernel had read
+    # one of its two operands from that copy in on-chip memory and now
+    # reads both from HBM: 4.2 -> 5.1 ms of the step. PERF.md section 6.)
+    kc_l = _cache_update(kc[li], kt, pos, head_major, sharded)
+    vc_l = _cache_update(vc[li], vt, pos, head_major, sharded)
+    kc = kc[:li] + (kc_l,) + kc[li + 1:]
+    vc = vc[:li] + (vc_l,) + vc[li + 1:]
 
     from paddle_tpu.flags import flags as _flags
     from paddle_tpu.ops.pallas import decode_attention as _da
@@ -1104,19 +1082,22 @@ class LlamaDecoder:
         def call(*args, **kwargs):
             return resilient_call(attempt, args, kwargs, site=site,
                                   on_event=self._events.append)
+        call._jitted = jitted    # for tests that lower the program itself
         return call
 
     def _empty_cache(self, B, cfg: Optional[LlamaConfig] = None):
+        """Zeroed K and V caches for ``B`` rows: per cache a tuple of
+        ``num_hidden_layers`` buffers, head-major ``(B, KV, L, D)`` for
+        GQA, token-major ``(B, L, KV, D)`` for MHA."""
         cfg = self.cfg if cfg is None else cfg
         dt = jnp.dtype(cfg.dtype)
-        from paddle_tpu.flags import flags
-        if flags.decode_cache_layout not in ("stacked", "per_layer"):
-            raise ValueError(
-                f"decode_cache_layout must be 'stacked' or 'per_layer', "
-                f"got {flags.decode_cache_layout!r}")
         head_major = cfg.num_attention_heads != cfg.num_key_value_heads
+        if head_major:
+            shape = (B, cfg.num_key_value_heads, self.max_len, cfg.head_dim)
+        else:
+            shape = (B, self.max_len, cfg.num_key_value_heads, cfg.head_dim)
 
-        def z(shape):
+        def z():
             if self.quant_kv:
                 # int8 rows + per-row scale buffer (never on a mesh:
                 # int8wk is refused typed at init)
@@ -1130,15 +1111,7 @@ class LlamaDecoder:
             # the carry never exists gathered, not even at init
             return self.sharding.put_state_field("kc", buf, head_major)
 
-        if head_major:
-            per = (B, cfg.num_key_value_heads, self.max_len, cfg.head_dim)
-        else:
-            per = (B, self.max_len, cfg.num_key_value_heads, cfg.head_dim)
-        if flags.decode_cache_layout == "stacked":
-            shape = (cfg.num_hidden_layers,) + per
-            return z(shape), z(shape)
-        shape = per
-        zeros = lambda: tuple(z(shape)  # noqa: E731
+        zeros = lambda: tuple(z()  # noqa: E731
                               for _ in range(cfg.num_hidden_layers))
         return zeros(), zeros()
 

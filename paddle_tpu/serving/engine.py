@@ -110,8 +110,9 @@ def _admit_row(logits, kc, vc, pos, keys, done, eos, temp, aidx,
     dispatch site (the serving dispatch contract counts prefills and
     chunks only)."""
     def put_cache(b, r):
-        # batch axis: 1 for stacked (L, B, ...) buffers, 0 for per-layer
-        # (B, ...) buffers — both are ndim-4 offsets from the row layout
+        # batch axis: 0 for a layer's (B, ...) buffer; 1 for the one
+        # (L, B, ...) array a bundle exported before the per-layer carry
+        # still serves with — both are ndim-4 offsets from the row layout
         ax = b.ndim - 4
         r1 = jax.lax.dynamic_slice_in_dim(r, src, 1, axis=ax)
         starts = tuple(slot if i == ax else 0 for i in range(b.ndim))
@@ -238,6 +239,21 @@ class _DecoderBackend:
             top_k=None if top_k is None else int(top_k),
             top_p=None if top_p is None else float(top_p))
         self._ring_logits = None
+        self._empty1 = {}       # see _prefill_cache
+
+    def _prefill_cache(self, N: int, draft: bool = False):
+        """The empty caches an admission prefill of ``N`` rows starts
+        from. An admission is one row unless ``batch_admission`` groups
+        several, and no prefill program donates its inputs: the batch-1
+        pair is built once and shared by every admission (built afresh,
+        2 x layers eager ``jnp.zeros`` held the v5e's host 15 ms an
+        admission with the device idle: PERF.md section 6, PR 27)."""
+        cfg = self.spec_eng["cfg"] if draft else None
+        if N != 1:
+            return self.dec._empty_cache(N, cfg)
+        if draft not in self._empty1:
+            self._empty1[draft] = self.dec._empty_cache(1, cfg)
+        return self._empty1[draft]
 
     def refresh_adapters(self) -> bool:
         """(Re)merge the adapter store's stacked ``lora.*`` arrays into
@@ -343,7 +359,7 @@ class _DecoderBackend:
         its adapter's deltas (None = base for all rows)."""
         import jax.numpy as jnp
         ids = np.asarray(ids)
-        kc, vc = self.dec._empty_cache(int(ids.shape[0]))
+        kc, vc = self._prefill_cache(int(ids.shape[0]))
         self._ring_logits, self._ring_kc, self._ring_vc = \
             self.dec._ring_admit_prefill(
                 self.dec.params, jnp.asarray(ids, jnp.int32), kc, vc,
@@ -361,7 +377,7 @@ class _DecoderBackend:
         import jax.numpy as jnp
         eng = self.spec_eng
         ids = np.asarray(ids)
-        dkc, dvc = self.dec._empty_cache(int(ids.shape[0]), eng["cfg"])
+        dkc, dvc = self._prefill_cache(int(ids.shape[0]), draft=True)
         self._ring_dkc, self._ring_dvc = eng["ring_prefill"](
             eng["params"], jnp.asarray(ids, jnp.int32), dkc, dvc,
             self._ring_dkc, self._ring_dvc,
@@ -448,13 +464,13 @@ class _DecoderBackend:
                       aidx=None):
         """One (possibly batched) admission-prefill dispatch: ``ids``
         (N, bucket) right-padded rows, per-row ``true_len``/``pos0``.
-        ``kc``/``vc`` default to fresh batch-N caches; the prefix-cache
+        ``kc``/``vc`` default to empty batch-N caches; the prefix-cache
         path passes caches preloaded with each row's slab. ``aidx``
         routes each row's prefill through its adapter's deltas."""
         import jax.numpy as jnp
         ids = np.asarray(ids)
         if kc is None:
-            kc, vc = self.dec._empty_cache(int(ids.shape[0]))
+            kc, vc = self._prefill_cache(int(ids.shape[0]))
         return self.dec._admit_prefill(
             self.dec.params, jnp.asarray(ids, jnp.int32), kc, vc,
             jnp.asarray(np.asarray(true_len), jnp.int32),
@@ -2089,8 +2105,7 @@ class ServingEngine:
                 leaves, _ = jax.tree_util.tree_flatten(tree)
                 for i, leaf in enumerate(leaves):
                     a = np.asarray(jax.device_get(leaf))
-                    # the put_cache batch-axis rule: ndim-4 for both
-                    # stacked (L, B, ...) and per-layer (B, ...) layouts
+                    # the put_cache batch-axis rule: ndim-4
                     store, tag = _np_storable(
                         np.take(a, idx, axis=a.ndim - 4))
                     arrays[f"{name}_leaf_{i}"] = store

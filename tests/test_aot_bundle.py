@@ -511,3 +511,29 @@ def test_decoder_bundle_runtime_temperature(tmp_path):
     with pytest.raises(ValueError, match="re-export"):
         legacy.generate(prompt, max_new_tokens=8, do_sample=True,
                         temperature=1.3, seed=3)
+
+
+@pytest.mark.parametrize("layout,n_buffers,shape", [
+    ("per_layer", 3, (2, 2, 16, 8)),
+    ("per_layer", 1, (2, 2, 16, 8)),       # a one-layer model or draft
+    ("stacked", 1, (3, 2, 2, 16, 8)),      # a bundle from before PR 27
+])
+def test_bundle_runtime_rebuilds_the_recorded_carry(layout, n_buffers,
+                                                    shape):
+    """The serving process builds the KV carry with the pytree structure
+    the bundle's programs were exported with: a tuple of ``n_buffers``
+    per-layer buffers — also when that is one — and, for a bundle written
+    when the carry was one array stacked over layers, that one array."""
+    from paddle_tpu.inference.bundle import AotPredictor
+
+    pred = AotPredictor.__new__(AotPredictor)
+    pred._sharding = None
+    pred.meta = {"max_len": 16, "caches": {"2": {
+        "n_buffers": n_buffers, "layout": layout, "shape": list(shape),
+        "dtype": "float32"}}}
+    for cache in pred._make_cache(2):
+        if layout == "stacked":
+            assert cache.shape == shape
+        else:
+            assert isinstance(cache, tuple)
+            assert [b.shape for b in cache] == [shape] * n_buffers

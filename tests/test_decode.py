@@ -268,29 +268,45 @@ def test_beam_search_eos_freezes_beams():
         assert np.all(seq[first:] == 0)
 
 
-def test_per_layer_cache_layout_parity():
-    """flags.decode_cache_layout='per_layer' must decode identically to
-    the default stacked layout (and bogus values must raise)."""
-    import pytest as _pytest
+@pytest.mark.parametrize("kv_heads", [2, 4], ids=["gqa", "mha"])
+def test_serving_carry_and_ring_are_one_buffer_per_layer(kv_heads):
+    """The KV carry and the admission ring hold one 4-D buffer per layer
+    (head-major for GQA, token-major for MHA) through a whole serve —
+    six requests through two slots, so admissions land mid-stream and the
+    rows of a chunk sit at different cache positions — and every request
+    gets the tokens of the per-token reference."""
+    from paddle_tpu.serving import ServingEngine
 
-    from paddle_tpu.flags import flags
-    from paddle_tpu.inference.generate import LlamaDecoder
+    paddle.seed(7)
+    cfg = LlamaConfig(**{**CFG, "num_key_value_heads": kv_heads})
+    dec = LlamaDecoder(LlamaForCausalLM(cfg), max_len=48)
+    rng = np.random.default_rng(kv_heads)
+    reqs = [(rng.integers(0, cfg.vocab_size, (n,)), new)
+            for n, new in [(3, 9), (7, 5), (5, 12), (4, 6), (9, 8), (6, 10)]]
+    want = _with_fallback(lambda: [np.asarray(dec.generate(p[None], new))
+                                   for p, new in reqs])
 
-    model = _model()
-    rng = np.random.default_rng(0)
-    prompt = rng.integers(0, model.config.vocab_size, (2, 8))
-    dec = LlamaDecoder(model, max_len=24)
-    ref = dec.generate(prompt, max_new_tokens=6)
-    flags.decode_cache_layout = "per_layer"
-    try:
-        dec2 = LlamaDecoder(model, max_len=24)
-        out = dec2.generate(prompt, max_new_tokens=6)
-        np.testing.assert_array_equal(ref, out)
-        flags.decode_cache_layout = "bogus"
-        with _pytest.raises(ValueError):
-            LlamaDecoder(model, max_len=24).generate(prompt, max_new_tokens=2)
-    finally:
-        flags.decode_cache_layout = "stacked"
+    slots, D = 2, cfg.head_dim
+    per_layer = ((slots, kv_heads, 48, D) if kv_heads < 4     # head-major
+                 else (slots, 48, kv_heads, D))               # token-major
+    eng = ServingEngine(dec, num_slots=slots, chunk_size=4)
+    rids = [eng.submit(p, new) for p, new in reqs]
+    got, uneven = {}, False
+    while len(got) < len(reqs):
+        got.update(eng.step())
+        for cache in (eng.state.kc, eng.state.vc,
+                      eng._b._ring_kc, eng._b._ring_vc):
+            assert isinstance(cache, tuple)
+            assert [b.shape for b in cache] == \
+                [per_layer] * cfg.num_hidden_layers
+        pos = np.asarray(eng.state.pos)[~np.asarray(eng.state.done)]
+        uneven = uneven or len(set(pos.tolist())) > 1
+    assert uneven, "no chunk ran with its rows at different positions"
+    m = eng.metrics()
+    assert m["admission_ring"]["staged"] == len(reqs)
+    assert m["admission_ring"]["host_scattered"] == 0
+    for rid, ref in zip(rids, want):
+        np.testing.assert_array_equal(np.asarray(got[rid]), ref)
 
 
 def _with_fallback(fn):
@@ -739,16 +755,17 @@ def test_sharded_decode_chunk_reentry_bitexact_greedy(mesh_pair):
     want = np.asarray(ref.generate(prompt, max_new_tokens=12))
 
     st = sh.init_decode_state(prompt)
-    assert "dp" in _spec_axes(st.kc), st.kc.sharding
+    assert all("dp" in _spec_axes(b) for b in st.kc), st.kc[0].sharding
     assert _spec_axes(st.pos) == {"dp"}
     assert _spec_axes(st.logits) == {"dp", "tp"}
-    kc_spec0 = st.kc.sharding
+    kc_spec0 = [b.sharding for b in st.kc]
     t1, st = sh.decode_chunk(st, 5)
-    # re-entry contract: same placements out as in
-    assert st.kc.sharding.is_equivalent_to(kc_spec0, st.kc.ndim)
-    assert "dp" in _spec_axes(st.kc)
+    # re-entry contract: same placements out as in, layer by layer
+    assert all(b.sharding.is_equivalent_to(s0, b.ndim)
+               for b, s0 in zip(st.kc, kc_spec0))
+    assert all("dp" in _spec_axes(b) for b in st.kc)
     t2, st = sh.decode_chunk(st, 7)
-    assert "dp" in _spec_axes(st.kc)
+    assert all("dp" in _spec_axes(b) for b in st.kc)
     got = np.concatenate([prompt, np.asarray(t1), np.asarray(t2)], axis=1)
     np.testing.assert_array_equal(got, want)
 
@@ -795,11 +812,12 @@ def test_sharded_head_axis_cache_on_2x2():
     sh = LlamaDecoder(model, max_len=32, mesh=_mesh((2, 2)))
     prompt = np.array([[5, 6, 7], [8, 9, 10]])
     st = sh.init_decode_state(prompt)
-    # stacked head-major cache (L, B, KV, max_len, D): dp on B, tp on KV
-    assert _spec_axes(st.kc) == {"dp", "tp"}
-    assert tuple(st.kc.sharding.spec)[1:3] == ("dp", "tp")
+    # each layer's head-major buffer (B, KV, max_len, D): dp on B, tp on KV
+    for b in st.kc:
+        assert _spec_axes(b) == {"dp", "tp"}
+        assert tuple(b.sharding.spec)[:2] == ("dp", "tp")
     toks, st = sh.decode_chunk(st, 8)
-    assert _spec_axes(st.kc) == {"dp", "tp"}
+    assert all(_spec_axes(b) == {"dp", "tp"} for b in st.kc)
     want = np.asarray(ref.generate(prompt, max_new_tokens=8))
     np.testing.assert_array_equal(
         np.concatenate([prompt, np.asarray(toks)], axis=1), want)

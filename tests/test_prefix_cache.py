@@ -59,6 +59,12 @@ def shdec():
 
 
 def _spec_axes(x):
+    """Mesh axes a live array is sharded over; for a KV cache (a tuple of
+    per-layer buffers) the axes every layer's buffer is sharded over."""
+    if isinstance(x, tuple):
+        per_layer = [_spec_axes(b) for b in x]
+        assert all(a == per_layer[0] for a in per_layer), per_layer
+        return per_layer[0]
     axes = set()
     for e in tuple(getattr(x.sharding, "spec", ()) or ()):
         if e is None:

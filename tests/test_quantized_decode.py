@@ -88,8 +88,10 @@ def test_decoder_surface(model):
     assert dec.quant == "int8wk" and dec.quant_kv
     assert dec.weight_dtype == "int8"      # legacy alias surface
     kc, vc = dec._empty_cache(2)
-    assert is_quantized_kv(kc) and kc["q"].dtype == np.int8
-    assert kc["s"].shape == kc["q"].shape[:-1] + (1,)
+    assert len(kc) == model.config.num_hidden_layers
+    for b in kc + vc:
+        assert is_quantized_kv(b) and b["q"].dtype == np.int8
+        assert b["s"].shape == b["q"].shape[:-1] + (1,)
     # the legacy weight_dtype argument still builds int8w
     alias = LlamaDecoder(model, max_len=32, weight_dtype="int8")
     assert alias.quant == "int8w" and not alias.quant_kv
@@ -155,10 +157,10 @@ def test_int8wk_state_reentry_is_quantized(model, prompt):
     re-entry — no fp copy of the cache ever materializes in the carry."""
     dec = LlamaDecoder(model, max_len=32, quant="int8wk")
     st = dec.init_decode_state(prompt)
-    assert is_quantized_kv(st.kc) and is_quantized_kv(st.vc)
+    assert all(is_quantized_kv(b) for b in st.kc + st.vc)
     toks, st2 = dec.decode_chunk(st, 4)
-    assert is_quantized_kv(st2.kc)
-    assert st2.kc["q"].dtype == np.int8
+    assert all(is_quantized_kv(b) and b["q"].dtype == np.int8
+               for b in st2.kc + st2.vc)
     # chained chunks == run-to-completion
     toks2, _ = dec.decode_chunk(st2, 4)
     got = np.concatenate([prompt, np.asarray(toks), np.asarray(toks2)], 1)

@@ -494,6 +494,12 @@ def _mesh(shape=(2, 2)):
 
 
 def _spec_axes(x):
+    """Mesh axes a live array is sharded over; for a KV cache (a tuple of
+    per-layer buffers) the axes every layer's buffer is sharded over."""
+    if isinstance(x, tuple):
+        per_layer = [_spec_axes(b) for b in x]
+        assert all(a == per_layer[0] for a in per_layer), per_layer
+        return per_layer[0]
     axes = set()
     for e in tuple(getattr(x.sharding, "spec", ()) or ()):
         if e is None:
@@ -528,7 +534,7 @@ def test_sharded_engine_parity_and_carry_stays_sharded(dec, shdec):
             finished[rid] = res
         # between EVERY step the carry is still on the mesh: admission
         # scatters and harvests never gathered it
-        seen_specs.add(str(eng.state.kc.sharding.spec))
+        seen_specs.add(str([b.sharding.spec for b in eng.state.kc]))
         assert "dp" in _spec_axes(eng.state.kc)
         assert _spec_axes(eng.state.pos) == {"dp"}
     assert len(seen_specs) == 1, f"carry placement drifted: {seen_specs}"

@@ -141,3 +141,118 @@ def test_kernels_give_way_where_gspmd_would_split_them(monkeypatch):
                           out_specs=P("dp", "mp"), check_vma=False)
             ).lower(jax.ShapeDtypeStruct((4, 4), np.float32))
     assert seen == [False]
+
+
+def _whole_cache_writers(hlo_text, shapes):
+    """Instructions outside the entry computation and outside fusions'
+    bodies whose output has one of ``shapes`` (dims as ``"16,8,2048,128"``)
+    and is materialised: everything but parameters, tuple plumbing,
+    bitcasts and the in-place row update (a ``dynamic-update-slice``, bare
+    or as the root of a fusion, whose update operand is smaller than the
+    smallest of ``shapes``, one layer)."""
+    import re
+    instr = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                       r"([\w\-]+)\((.*)")
+    comps, fused, cur = {}, set(), None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(2), {})
+            if head.group(1):
+                comps.pop(head.group(2))     # the entry's copies are not
+                cur = {}                     # the loop's (carry not donated)
+            continue
+        m = instr.match(line)
+        if m and cur is not None:
+            name, _, dims, op, rest = m.groups()
+            called = re.search(r"calls=%?([\w.\-]+)", rest)
+            if op == "fusion" and called:
+                fused.add(called.group(1))
+            cur[name] = (dims, op, re.findall(r"%([\w.\-]+)", rest),
+                         called.group(1) if called else None,
+                         line.lstrip().startswith("ROOT"))
+
+    def elems(dims):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        return n
+
+    def row_update(comp, name):
+        dims, op, operands, called, _ = comp[name]
+        if op == "fusion":
+            body = comps[called]
+            root = next(n for n, v in body.items() if v[4])
+            return row_update(body, root)
+        return (op == "dynamic-update-slice"
+                and elems(comp[operands[1]][0]) < min(map(elems, shapes)))
+
+    found = []
+    for cname, comp in comps.items():
+        if cname in fused:
+            continue
+        for name, (dims, op, _, _, _) in comp.items():
+            if dims in shapes and op not in (
+                    "parameter", "get-tuple-element", "bitcast") \
+                    and not row_update(comp, name):
+                found.append(f"{cname}: {name} = {op} [{dims}]")
+    return found
+
+
+@pytest.mark.parametrize("kv_heads,slots,layer_shape", [
+    (8, 16, "16,8,2048,128"),     # GQA, head-major, decode_attention kernel
+    (32, 8, "8,2048,32,128"),     # MHA, token-major, XLA attention
+])
+def test_ring_chunk_program_updates_the_kv_carry_in_place(
+        tpu, monkeypatch, kv_heads, slots, layer_shape):
+    """The serving chunk program at 7B attention widths (hidden 4096, 32
+    heads of 128, ``max_len`` 2048; FFN and vocabulary small) holds no
+    copy of a layer's KV buffer: its temporaries stay under one layer's K
+    buffer, and inside the 16-step loop nothing outputs a whole layer but
+    the in-place token-row update. (With the carry stacked over layers
+    the same program held a slice of each layer out of the stack and a
+    write of it back, every layer of every step: temporaries of 271 MB
+    and 543 MB.)"""
+    from paddle_tpu.flags import flags
+    from paddle_tpu.inference.generate import LlamaDecoder
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.obs.cost import program_census
+
+    # the routing asks for a TPU backend or this flag; the kernel is then
+    # compiled, not interpreted (compiled_not_interpreted)
+    monkeypatch.setattr(flags, "decode_attention_interpret", True)
+    layers, vocab, max_len, steps = 2, 512, 2048, 16
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=vocab, hidden_size=4096, intermediate_size=256,
+        num_hidden_layers=layers, num_attention_heads=32,
+        num_key_value_heads=kv_heads, max_position_embeddings=max_len,
+        dtype="bfloat16"))
+    model.to(dtype="bfloat16")
+    dec = LlamaDecoder(model, max_len=max_len)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _s(tpu, a.shape, a.dtype), tree)
+
+    kc, vc = on_chip(jax.eval_shape(lambda: dec._empty_cache(slots)))
+    assert len(kc) == layers and kc[0].shape == tuple(
+        int(d) for d in layer_shape.split(","))
+    logits = _s(tpu, (slots, vocab), jnp.float32)
+    rows_i32, rows_f32 = (_s(tpu, (slots,), dt)
+                          for dt in (jnp.int32, jnp.float32))
+    keys = _s(tpu, (slots, 2), jnp.uint32)
+    done = _s(tpu, (slots,), jnp.bool_)
+    compiled = dec._ring_chunk_decode._jitted.lower(
+        on_chip(dec.params), logits, kc, vc, rows_i32, keys, done,
+        rows_i32, rows_f32, None,
+        # the admission ring: as many rows as the carry has slots
+        logits, kc, vc, rows_i32, rows_i32, keys, rows_i32, rows_f32, None,
+        steps=steps, do_sample=False, top_k=None, top_p=None).compile()
+
+    kernels = program_census(compiled)["kernels"]
+    assert kernels == ({"decode_attention": layers} if kv_heads == 8
+                       else {})
+    # one layer's K buffer in the GQA case, half of one in the MHA case
+    assert compiled.memory_analysis().temp_size_in_bytes < 67_108_864
+    stack_shape = f"{layers},{layer_shape}"
+    assert _whole_cache_writers(compiled.as_text(),
+                                {layer_shape, stack_shape}) == []
