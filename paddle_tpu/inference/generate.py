@@ -6,10 +6,12 @@ paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu
 TPU-native form: a PURE functional forward with a statically-shaped KV
 cache — token-major ``(B, max_len, KV, D)`` for MHA, head-major
 ``(B, KV, max_len, D)`` for GQA (the decode-kernel layout); one buffer
-per layer, so that a step writes its token rows into each layer's buffer
-in place (one array stacked over layers cost a copy of the whole cache
-out of and back into the stack every step, 35 % of a serving step on
-the v5e with GQA and 58 % with MHA: PERF.md section 6, PR 27) —
+per cache layer (a weight layer, times the passes of a looped model:
+``LlamaConfig.num_cache_layers``), so that a step writes its token rows
+into each buffer in place (one array stacked over layers cost a copy of
+the whole cache out of and back into the stack every step, 35 % of a
+serving step on the v5e with GQA and 58 % with MHA: PERF.md section 6,
+PR 27) —
 so prefill and every decode step are each
 ONE cached-compile XLA program (no recompiles across steps; static shapes
 are what the MXU wants). Block tables are unnecessary: XLA owns memory, and
@@ -40,7 +42,13 @@ import numpy as np
 
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM, _rope_tables
 
-__all__ = ["LlamaDecoder", "DecodeState"]
+__all__ = ["LlamaDecoder", "DecodeState", "LoopedDraftError"]
+
+
+class LoopedDraftError(ValueError):
+    """``draft_model='skip:N'`` over a looped model: the layer-skip view
+    drafts with the first N layers of depth, and a model that runs its
+    layers ``total_ut_steps`` times has no such prefix of depth."""
 
 
 @dataclasses.dataclass
@@ -237,11 +245,24 @@ def _row_scatter(dst, src, idx):
     return dst.at[idx].set(src, mode="drop")
 
 
-def _block_forward(p, cfg: LlamaConfig, li: int, h, kc, vc, pos, max_len,
-                   sharded=False, aidx=None):
-    """One decoder block over h (B, S, H) writing K/V into the cache at
-    [pos, pos+S); attention reads the whole cache masked to < pos+S with
-    causal alignment to the bottom-right (query i attends to <= pos+i).
+def _rms(x, w, eps):
+    var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
+    return (x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
+            ).astype(x.dtype) * w
+
+
+def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
+                   max_len, sharded=False, aidx=None):
+    """One decoder block over h (B, S, H), weights of layer ``li``,
+    writing K/V into CACHE layer ``ci`` at [pos, pos+S); attention reads
+    that whole buffer masked to < pos+S with causal alignment to the
+    bottom-right (query i attends to <= pos+i). ``ci`` is ``li`` for a
+    model that makes one pass over its layers; a looped model's pass
+    ``t`` keeps its own keys and values in ``t * num_hidden_layers +
+    li``. Both are trace-time integers. Where the parameters hold
+    ``input_layernorm_2`` / ``post_attention_layernorm_2`` (a sandwich
+    block, models/ouro.py) each sub-layer's output is normed before it
+    joins the residual stream.
     ``pos``: scalar or per-row (B,) vector. ``sharded`` (trace-time
     static): the decoder runs under a GSPMD mesh — hand-written Pallas
     kernels (no partitioning rules) give way to the XLA forms, which
@@ -250,13 +271,9 @@ def _block_forward(p, cfg: LlamaConfig, li: int, h, kc, vc, pos, max_len,
     B, S, _ = h.shape
     H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     pre = f"model.layers.{li}."
+    eps = cfg.rms_norm_eps
 
-    def rms(x, w):
-        var = jnp.mean(jnp.square(x.astype(jnp.float32)), -1, keepdims=True)
-        return (x.astype(jnp.float32) * jax.lax.rsqrt(
-            var + cfg.rms_norm_eps)).astype(x.dtype) * w
-
-    x = rms(h, p[pre + "input_layernorm.weight"])
+    x = _rms(h, p[pre + "input_layernorm.weight"], eps)
     qkv = _mm(x, p, pre + "self_attn.qkv.weight", sharded, aidx)
     q = qkv[..., :H * D].reshape(B, S, H, D)
     k = qkv[..., H * D:H * D + KV * D].reshape(B, S, KV, D)
@@ -270,18 +287,18 @@ def _block_forward(p, cfg: LlamaConfig, li: int, h, kc, vc, pos, max_len,
     #                        which XLA's fused matvec prefers (measured)
     kt = jnp.swapaxes(k, 1, 2) if head_major else k
     vt = jnp.swapaxes(v, 1, 2) if head_major else v
-    # each layer owns its buffer: the token rows are written into layer
-    # li's array in place and attention reads that array. (From a carry
+    # each CACHE layer owns its buffer: the token rows are written into
+    # buffer ci in place and attention reads that array. (From a carry
     # stacked over layers XLA copied the whole layer out and back in here,
     # every layer of every step. Measured on the v5e at 7B widths, PR 27:
     # a serving step 23.3 -> 15.3 ms with 12 GQA layers x 16 slots, 23.6
     # -> 9.9 ms with 8 MHA layers x 8 slots. The Mosaic kernel had read
     # one of its two operands from that copy in on-chip memory and now
     # reads both from HBM: 4.2 -> 5.1 ms of the step. PERF.md section 6.)
-    kc_l = _cache_update(kc[li], kt, pos, head_major, sharded)
-    vc_l = _cache_update(vc[li], vt, pos, head_major, sharded)
-    kc = kc[:li] + (kc_l,) + kc[li + 1:]
-    vc = vc[:li] + (vc_l,) + vc[li + 1:]
+    kc_l = _cache_update(kc[ci], kt, pos, head_major, sharded)
+    vc_l = _cache_update(vc[ci], vt, pos, head_major, sharded)
+    kc = kc[:ci] + (kc_l,) + kc[ci + 1:]
+    vc = vc[:ci] + (vc_l,) + vc[ci + 1:]
 
     from paddle_tpu.flags import flags as _flags
     from paddle_tpu.ops.pallas import decode_attention as _da
@@ -334,14 +351,17 @@ def _block_forward(p, cfg: LlamaConfig, li: int, h, kc, vc, pos, max_len,
         scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
         attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", attn, vv).reshape(B, S, H * D)
-    h = h + _mm(out, p, pre + "self_attn.o_proj.weight", sharded, aidx)
+    att = _mm(out, p, pre + "self_attn.o_proj.weight", sharded, aidx)
+    w2 = p.get(pre + "input_layernorm_2.weight")
+    h = h + (att if w2 is None else _rms(att, w2, eps))
 
-    x = rms(h, p[pre + "post_attention_layernorm.weight"])
+    x = _rms(h, p[pre + "post_attention_layernorm.weight"], eps)
     gu = _mm(x, p, pre + "mlp.gate_up.weight", sharded, aidx)
     F_ = gu.shape[-1] // 2
     a = jax.nn.silu(gu[..., :F_]) * gu[..., F_:]
-    return h + _mm(a, p, pre + "mlp.down_proj.weight", sharded, aidx), \
-        kc, vc
+    mlp = _mm(a, p, pre + "mlp.down_proj.weight", sharded, aidx)
+    w2 = p.get(pre + "post_attention_layernorm_2.weight")
+    return h + (mlp if w2 is None else _rms(mlp, w2, eps)), kc, vc
 
 
 def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
@@ -352,14 +372,22 @@ def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
     scores every drafted position in one batched forward) — plus the
     updated caches. ``pos``: scalar or per-row (B,) vector. ``aidx``
     (B,) i32: per-row LoRA adapter index (projections only — the head
-    stays base)."""
+    stays base).
+
+    The layer list is run ``cfg.total_ut_steps`` times (once for every
+    model but a looped one), each pass over its own cache layers and
+    closed by the final norm, whose output feeds the next pass. The
+    passes are written out at trace time, so every cache index is a
+    Python integer: a buffer indexed by a traced pass number would be
+    copied whole out of and back into the carry every step (PERF.md
+    section 6, PRs 27 and 28)."""
     h = p["model.embed_tokens.weight"][ids]
-    for li in range(cfg.num_hidden_layers):
-        h, kc, vc = _block_forward(p, cfg, li, h, kc, vc, pos, max_len,
-                                   sharded, aidx)
-    var = jnp.mean(jnp.square(h.astype(jnp.float32)), -1, keepdims=True)
-    h = (h.astype(jnp.float32) * jax.lax.rsqrt(var + cfg.rms_norm_eps)
-         ).astype(h.dtype) * p["model.norm.weight"]
+    L = cfg.num_hidden_layers
+    for t in range(cfg.total_ut_steps):
+        for li in range(L):
+            h, kc, vc = _block_forward(p, cfg, li, t * L + li, h, kc, vc,
+                                       pos, max_len, sharded, aidx)
+        h = _rms(h, p["model.norm.weight"], cfg.rms_norm_eps)
     hh = h if return_all else h[:, -1]
     if "head:int8" in p:
         logits = _mm(hh, p, "head", sharded).astype(jnp.float32)
@@ -1087,8 +1115,10 @@ class LlamaDecoder:
 
     def _empty_cache(self, B, cfg: Optional[LlamaConfig] = None):
         """Zeroed K and V caches for ``B`` rows: per cache a tuple of
-        ``num_hidden_layers`` buffers, head-major ``(B, KV, L, D)`` for
-        GQA, token-major ``(B, L, KV, D)`` for MHA."""
+        ``cfg.num_cache_layers`` buffers — one per weight layer per pass
+        over the layers, so ``num_hidden_layers`` of them for every model
+        but a looped one — head-major ``(B, KV, L, D)`` for GQA,
+        token-major ``(B, L, KV, D)`` for MHA."""
         cfg = self.cfg if cfg is None else cfg
         dt = jnp.dtype(cfg.dtype)
         head_major = cfg.num_attention_heads != cfg.num_key_value_heads
@@ -1112,7 +1142,7 @@ class LlamaDecoder:
             return self.sharding.put_state_field("kc", buf, head_major)
 
         zeros = lambda: tuple(z()  # noqa: E731
-                              for _ in range(cfg.num_hidden_layers))
+                              for _ in range(cfg.num_cache_layers))
         return zeros(), zeros()
 
     # -- chunked resumable decode -----------------------------------------
@@ -1365,6 +1395,13 @@ class LlamaDecoder:
                     "draft_quant does not compose with 'skip:N' drafts: "
                     "the layer-skip view reuses the TARGET's params, so "
                     "quantize the target (quant='int8w') instead")
+            if cfg.total_ut_steps > 1:
+                raise LoopedDraftError(
+                    f"draft_model={draft_model!r} needs a model that "
+                    f"makes one pass over its layers: this one runs them "
+                    f"total_ut_steps={cfg.total_ut_steps} times, so its "
+                    f"first N layers are not a prefix of its depth; pass "
+                    f"a separate draft model")
             n = int(draft_model.split(":", 1)[1])
             if not 0 < n < cfg.num_hidden_layers:
                 raise ValueError(

@@ -47,9 +47,19 @@ class LlamaConfig:
     tie_word_embeddings: bool = False
     dtype: str = "float32"
 
+    # passes over the layer list: one here, a field of a looped model's
+    # config (models/ouro.py)
+    total_ut_steps = 1
+
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def num_cache_layers(self) -> int:
+        """KV buffers a decoder holds: each pass over the layers keeps
+        its own keys and values."""
+        return self.num_hidden_layers * self.total_ut_steps
 
 
 TINY_CONFIG = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -204,10 +214,12 @@ class LlamaModel(nn.Layer):
 
 
 class LlamaForCausalLM(nn.Layer):
+    model_class = LlamaModel     # the trunk under the head (see ouro.py)
+
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
-        self.model = LlamaModel(config)
+        self.model = self.model_class(config)
         if config.tie_word_embeddings:
             self.lm_head = None
         else:

@@ -1002,6 +1002,20 @@ class ServingEngine:
             "sum over the occupied rows of the row's cache position at "
             "the chunk's start, per chunk dispatch (the KV a chunk's "
             "attention must at least read)")
+        # the cache as built, from the carry's own buffers: a looped
+        # model holds its weight layers once per pass
+        kc = self.state.kc
+        self._cache_layers = (len(kc) if isinstance(kc, tuple)
+                              else int(kc.shape[0]))
+        self._cache_bytes_per_position = sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(
+                (kc, self.state.vc))) // (self.num_slots * self._b.max_len)
+        r.gauge("serving.cache.layers",
+                "KV cache layers in the carry (weight layers x passes "
+                "over them)").set(self._cache_layers)
+        r.gauge("serving.cache.bytes_per_position",
+                "bytes of K and V one token position holds over all "
+                "cache layers").set(self._cache_bytes_per_position)
         # prefix-cache instruments: hit classes as the ENGINE admitted
         # them (a shared cache's own stats() aggregate every engine),
         # bytes/slab gauges synced from the cache after each admission
@@ -3261,7 +3275,9 @@ class ServingEngine:
         row-key readback. ``wait`` and ``admit_wait`` are device time;
         the host's own is admit - admit_wait + dispatch + harvest.
         ``live_kv_positions_total`` is the cache
-        positions live at the start of every chunk dispatched so far,
+        positions live at the start of every chunk dispatched so far (token
+        positions, whatever ``cache_layers`` each holds; a position's bytes
+        over all of them are ``cache_bytes_per_position``),
         ``compiles`` this process's backend compiles by dispatch site."""
         qd, lat = self._h_qdelay, self._h_latency
         return {
@@ -3282,6 +3298,8 @@ class ServingEngine:
             "step_phase_s": {ph: {"sum": h.sum, "count": h.count}
                              for ph, h in self._h_phase.items()},
             "live_kv_positions_total": int(self._c_live_kv.value),
+            "cache_layers": self._cache_layers,
+            "cache_bytes_per_position": self._cache_bytes_per_position,
             "compiles": obs.compile_counts(),
             "queue_delay_mean_s": qd.mean,
             "queue_delay_p50_s": qd.percentile(50),

@@ -256,3 +256,44 @@ def test_ring_chunk_program_updates_the_kv_carry_in_place(
     stack_shape = f"{layers},{layer_shape}"
     assert _whole_cache_writers(compiled.as_text(),
                                 {layer_shape, stack_shape}) == []
+
+
+def test_looped_chunk_program_keeps_every_pass_cache_in_place(tpu):
+    """A looped model's chunk program (models/ouro.py: 2 weight layers run
+    3 times, so 6 cache layers; Ouro-2.6B's attention widths, small FFN and
+    vocabulary) writes each pass's token rows into that pass's own buffer
+    in place: the passes are unrolled at trace time, so no cache buffer is
+    indexed by a traced pass number and none is copied whole inside the
+    step loop (PERF.md section 6, PR 28)."""
+    from paddle_tpu.inference.generate import LlamaDecoder
+    from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+
+    layers, passes, vocab, max_len, slots, steps = 2, 3, 512, 1024, 8, 16
+    model = OuroForCausalLM(OuroConfig(
+        vocab_size=vocab, hidden_size=2048, intermediate_size=256,
+        num_hidden_layers=layers, num_attention_heads=16,
+        num_key_value_heads=16, max_position_embeddings=max_len,
+        total_ut_steps=passes, dtype="bfloat16"))
+    model.to(dtype="bfloat16")
+    dec = LlamaDecoder(model, max_len=max_len)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _s(tpu, a.shape, a.dtype), tree)
+
+    kc, vc = on_chip(jax.eval_shape(lambda: dec._empty_cache(slots)))
+    assert len(kc) == layers * passes
+    assert kc[0].shape == (slots, max_len, 16, 128)
+    logits = _s(tpu, (slots, vocab), jnp.float32)
+    rows_i32, rows_f32 = (_s(tpu, (slots,), dt)
+                          for dt in (jnp.int32, jnp.float32))
+    keys = _s(tpu, (slots, 2), jnp.uint32)
+    done = _s(tpu, (slots,), jnp.bool_)
+    compiled = dec._ring_chunk_decode._jitted.lower(
+        on_chip(dec.params), logits, kc, vc, rows_i32, keys, done,
+        rows_i32, rows_f32, None,
+        logits, kc, vc, rows_i32, rows_i32, keys, rows_i32, rows_f32, None,
+        steps=steps, do_sample=False, top_k=None, top_p=None).compile()
+    # under one cache buffer (33.5 MB): nothing holds a copy of one
+    assert compiled.memory_analysis().temp_size_in_bytes < 33_554_432
+    assert _whole_cache_writers(compiled.as_text(),
+                                {"8,1024,16,128"}) == []
