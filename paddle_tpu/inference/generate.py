@@ -292,9 +292,7 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     # stacked over layers XLA copied the whole layer out and back in here,
     # every layer of every step. Measured on the v5e at 7B widths, PR 27:
     # a serving step 23.3 -> 15.3 ms with 12 GQA layers x 16 slots, 23.6
-    # -> 9.9 ms with 8 MHA layers x 8 slots. The Mosaic kernel had read
-    # one of its two operands from that copy in on-chip memory and now
-    # reads both from HBM: 4.2 -> 5.1 ms of the step. PERF.md section 6.)
+    # -> 9.9 ms with 8 MHA layers x 8 slots. PERF.md section 6.)
     kc_l = _cache_update(kc[ci], kt, pos, head_major, sharded)
     vc_l = _cache_update(vc[ci], vt, pos, head_major, sharded)
     kc = kc[:ci] + (kc_l,) + kc[ci + 1:]
@@ -318,12 +316,16 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     if use_kernel:
         # one-kernel GQA cache attention (block_multi_head_attention
         # capability): no repeated-KV materialization, online softmax,
-        # compute skipped past the valid prefix; ``pos`` may be per-row
-        # (the chunked serving path, where rows sit at different cache
-        # offsets). Int8 caches (int8wk) stream int8 tiles and dequant
-        # in VMEM against their per-row scales. Measured (v5e, B=8
-        # D=64): 8-way GQA L=4096 0.24 ms vs 0.88 ms XLA; 4-way L=8192
-        # 0.60 ms vs 2.06 ms; ~1B GQA4 end-to-end 2.98 vs 7.08 ms/tok.
+        # cache blocks past a row's valid prefix neither fetched nor
+        # computed; ``pos`` may be per-row (the chunked serving path,
+        # where rows sit at different cache offsets). Int8 caches
+        # (int8wk) stream int8 tiles and dequant in VMEM against their
+        # per-row scales. Measured on the v5e at Mistral-7B widths (12
+        # layers, 16 slots, max_len 2048, rows at 32-2047 live positions,
+        # 475 on average): 0.97 ms of an 11.3 ms serving step, where
+        # the live KV's bytes need 0.47 ms at 819 GB/s (PERF.md section
+        # 6, PR 29; 5.0 ms of a 15.3 ms step while the kernel streamed
+        # all of max_len, one KV head a grid step: ledger, PR 28).
         if quant_kv:
             out = _da.decode_attention(
                 q[:, 0], kc_l["q"], vc_l["q"], pos + 1,

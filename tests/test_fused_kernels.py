@@ -236,74 +236,153 @@ def test_int8_matmul_kernel_matches_dequant():
     assert not supported(jnp.zeros((8, 200)), jnp.zeros((200, 512), jnp.int8))
 
 
-def test_decode_attention_kernel_interpret_parity():
-    """ops/pallas/decode_attention (block_multi_head_attention capability):
-    interpret-mode parity with the masked dense reference, incl. GQA and
-    dynamic valid-length masking."""
-    import jax
-    import jax.numpy as jnp
+def _dense_decode_attention(q, kc, vc, pos):
+    """The masked dense reference: softmax over the first ``pos`` (scalar
+    or per-row) cache positions, KV heads repeated for GQA."""
+    rep = q.shape[1] // kc.shape[1]
+    L, D = kc.shape[2], kc.shape[3]
+    kk, vv = jnp.repeat(kc, rep, 1), jnp.repeat(vc, rep, 1)
+    s = jnp.einsum("bhd,bhkd->bhk", q, kk) / np.sqrt(D)
+    bound = jnp.reshape(jnp.asarray(pos), (-1, 1, 1))
+    s = jnp.where(jnp.arange(L)[None, None, :] < bound, s, -jnp.inf)
+    return np.asarray(jnp.einsum("bhk,bhkd->bhd", jax.nn.softmax(s, -1), vv))
 
+
+def _decode_attention_inputs(seed, B, KV, H, L, D=8):
+    rng = np.random.default_rng(seed)
+    return (jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32),
+            jnp.asarray(rng.standard_normal((B, KV, L, D)), jnp.float32),
+            jnp.asarray(rng.standard_normal((B, KV, L, D)), jnp.float32))
+
+
+@pytest.mark.parametrize("pos", [1, 100, 256])
+@pytest.mark.parametrize("KV,H", [(4, 4), (2, 6)])
+def test_decode_attention_kernel_interpret_parity(KV, H, pos):
+    """ops/pallas/decode_attention (block_multi_head_attention capability):
+    interpret-mode parity with the masked dense reference, incl. GQA
+    (``rep`` 1 and 3) and dynamic valid-length masking (scalar ``pos``)."""
     from paddle_tpu.ops.pallas.decode_attention import (
         decode_attention, supported)
 
-    rng = np.random.default_rng(0)
-    B, L, D = 2, 256, 8
-    for KV, H in ((4, 4), (2, 6)):
-        q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-        kc = jnp.asarray(rng.standard_normal((B, KV, L, D)), jnp.float32)
-        vc = jnp.asarray(rng.standard_normal((B, KV, L, D)), jnp.float32)
-        assert supported(q, kc)
-        for pos in (1, 100, L):
-            got = np.asarray(decode_attention(q, kc, vc, pos, block_l=128))
-            rep = H // KV
-            kk = jnp.repeat(kc, rep, 1) if rep > 1 else kc
-            vv = jnp.repeat(vc, rep, 1) if rep > 1 else vc
-            s = jnp.einsum("bhd,bhkd->bhk", q, kk) / np.sqrt(D)
-            s = jnp.where(jnp.arange(L)[None, None, :] < pos, s, -jnp.inf)
-            want = np.asarray(jnp.einsum("bhk,bhkd->bhd",
-                                         jax.nn.softmax(s, -1), vv))
-            np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5,
-                                       err_msg=f"KV={KV} pos={pos}")
+    q, kc, vc = _decode_attention_inputs(0, 2, KV, H, 256)
+    assert supported(q, kc)
+    got = np.asarray(decode_attention(q, kc, vc, pos, block_l=128))
+    np.testing.assert_allclose(got, _dense_decode_attention(q, kc, vc, pos),
+                               rtol=2e-5, atol=2e-5)
     assert not supported(jnp.zeros((2, 5, 8)), jnp.zeros((2, 2, 256, 8)))
 
 
-def test_decode_attention_per_row_pos_and_int8_parity():
+# per-row valid lengths at block_l=128 over L=512: two rows inside a block
+# (the pair the test had), then one row on, before and after every block
+# boundary a row can sit at, shortest and longest mixed in one batch
+_ROW_LENGTHS = {
+    "inside": [100, 37],
+    "boundaries": [1, 127, 128, 129, 512],
+    "boundaries_reversed": [512, 385, 384, 383, 256, 255, 1],
+}
+
+
+@pytest.mark.parametrize("cache", ["float", "int8"])
+@pytest.mark.parametrize("KV,H", [(2, 6), (2, 2)])
+@pytest.mark.parametrize("lengths", sorted(_ROW_LENGTHS))
+def test_decode_attention_per_row_pos_and_int8_parity(lengths, KV, H, cache):
     """The kernel's per-row valid-length bound ((B,) pos — the chunked
     serving path, where rows sit at different cache offsets) and the
     int8-cache tiles (dequant in VMEM against per-row scales) both match
-    the masked dense reference."""
-    import jax
-    import jax.numpy as jnp
-
+    the masked dense reference, with rows on and beside the block
+    boundaries mixed in one batch (a row's dead blocks are sent to its
+    last live one and skipped)."""
     from paddle_tpu.ops.pallas.decode_attention import decode_attention
     from paddle_tpu.quantization.kv_cache import (dequantize_kv,
                                                   quantize_kv_rows)
 
-    rng = np.random.default_rng(1)
-    B, L, D, KV, H = 2, 256, 8, 2, 6
-    q = jnp.asarray(rng.standard_normal((B, H, D)), jnp.float32)
-    kc = jnp.asarray(rng.standard_normal((B, KV, L, D)), jnp.float32)
-    vc = jnp.asarray(rng.standard_normal((B, KV, L, D)), jnp.float32)
-    pos = jnp.asarray([100, 37], jnp.int32)
-    rep = H // KV
+    pos = jnp.asarray(_ROW_LENGTHS[lengths], jnp.int32)
+    q, kc, vc = _decode_attention_inputs(1, pos.shape[0], KV, H, 512)
+    if cache == "int8":
+        qk, qv = quantize_kv_rows(kc), quantize_kv_rows(vc)
+        got = decode_attention(q, qk["q"], qv["q"], pos, block_l=128,
+                               k_scale=qk["s"], v_scale=qv["s"])
+        kc, vc = (dequantize_kv(qk, jnp.float32),
+                  dequantize_kv(qv, jnp.float32))
+    else:
+        got = decode_attention(q, kc, vc, pos, block_l=128)
+    np.testing.assert_allclose(np.asarray(got),
+                               _dense_decode_attention(q, kc, vc, pos),
+                               rtol=2e-5, atol=2e-5)
 
-    def ref(kd, vd):
-        kk, vv = jnp.repeat(kd, rep, 1), jnp.repeat(vd, rep, 1)
-        s = jnp.einsum("bhd,bhkd->bhk", q, kk) / np.sqrt(D)
-        s = jnp.where(jnp.arange(L)[None, None, :] < pos[:, None, None],
-                      s, -jnp.inf)
-        return np.asarray(jnp.einsum("bhk,bhkd->bhd",
-                                     jax.nn.softmax(s, -1), vv))
 
-    got = np.asarray(decode_attention(q, kc, vc, pos, block_l=128))
-    np.testing.assert_allclose(got, ref(kc, vc), rtol=2e-5, atol=2e-5)
-    qk, qv = quantize_kv_rows(kc), quantize_kv_rows(vc)
-    got8 = np.asarray(decode_attention(
-        q, qk["q"], qv["q"], pos, block_l=128,
-        k_scale=qk["s"], v_scale=qv["s"]))
-    want8 = ref(dequantize_kv(qk, jnp.float32),
-                dequantize_kv(qv, jnp.float32))
-    np.testing.assert_allclose(got8, want8, rtol=2e-5, atol=2e-5)
+@pytest.mark.parametrize("cache", ["float", "int8"])
+def test_decode_attention_ignores_what_lies_past_a_rows_valid_prefix(cache):
+    """Large finite values written past each row's valid prefix — what a
+    slot's earlier tenant leaves in the cache — change nothing in the
+    output: the tail of the last live block is masked, whole dead blocks
+    are never read."""
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+    from paddle_tpu.quantization.kv_cache import quantize_kv_rows
+
+    lengths = _ROW_LENGTHS["boundaries"]
+    pos = jnp.asarray(lengths, jnp.int32)
+    q, kc, vc = _decode_attention_inputs(2, len(lengths), 2, 6, 512)
+    dead = jnp.arange(512)[None, None, :, None] >= pos[:, None, None, None]
+    kg, vg = jnp.where(dead, 3e4, kc), jnp.where(dead, -3e4, vc)
+
+    def run(k, v):
+        if cache == "int8":
+            k, v = quantize_kv_rows(k), quantize_kv_rows(v)
+            return np.asarray(decode_attention(
+                q, k["q"], v["q"], pos, block_l=128,
+                k_scale=k["s"], v_scale=v["s"]))
+        return np.asarray(decode_attention(q, k, v, pos, block_l=128))
+
+    np.testing.assert_array_equal(run(kg, vg), run(kc, vc))
+
+
+@pytest.mark.parametrize("bl", [128, 256])
+def test_decode_attention_dead_blocks_map_to_the_last_live_block(bl):
+    """The clamped block-index rule: L-step ``l`` fetches block ``l`` while
+    it holds a live position, else the row's last live block — never one
+    past it, never below 0 — so the index of a dead step repeats and the
+    pipeline issues no copy for it."""
+    from paddle_tpu.ops.pallas.decode_attention import _live_block
+
+    nl = 2048 // bl
+    for n_valid in (0, 1, bl - 1, bl, bl + 1, 475, 2047, 2048):
+        last_live = max(n_valid - 1, 0) // bl
+        got = [int(_live_block(jnp.int32(l), jnp.int32(n_valid), bl))
+               for l in range(nl)]
+        assert got == [min(l, last_live) for l in range(nl)], n_valid
+        assert 0 <= min(got) and max(got) == min(last_live, nl - 1)
+        # live steps fetch their own block; the fetches a row causes are
+        # the distinct indices: ceil(n_valid / bl), and one for an empty row
+        assert len(set(got)) == max(1, -(-n_valid // bl))
+
+
+# (block_l, L, KV, D, itemsize, int8 cache) -> block length; what the v5e's
+# compiler took and refused at these shapes is in the kernel's comment
+_BLOCK_LENS = {
+    (256, 2048, 8, 128, 2, False): 256,    # the Mistral serving cell
+    (256, 4096, 8, 128, 2, False): 256,
+    (256, 4096, 8, 128, 1, True): 256,     # int8wk: the scale tiles fit
+    (1024, 4096, 8, 128, 1, True): 512,    # ... and bound a larger ask
+    (2048, 2048, 8, 128, 2, False): 1024,
+    (256, 2048, 32, 128, 2, False): 256,   # MHA-wide rows
+    (256, 2048, 32, 128, 1, True): 128,
+    (256, 384, 8, 128, 2, False): 128,     # a divisor of L
+    (256, 128, 2, 8, 4, False): 128,       # never over L
+    (256, 2048, 128, 512, 4, False): 0,    # nothing fits: not supported
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_LENS))
+def test_decode_attention_block_follows_the_vmem_budget(case):
+    from paddle_tpu.ops.pallas.decode_attention import _block_len, supported
+
+    assert _block_len(*case) == _BLOCK_LENS[case]
+    block_l, L, KV, D, itemsize, quant = case
+    dtype = {1: jnp.int8, 2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    q = jax.ShapeDtypeStruct((2, KV, D), jnp.float32)
+    kc = jax.ShapeDtypeStruct((2, KV, L, D), dtype)
+    assert supported(q, kc) == (_block_len(256, L, KV, D, itemsize, quant) > 0)
 
 
 def test_decode_attention_chunked_path_parity():

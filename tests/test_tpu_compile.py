@@ -84,14 +84,26 @@ def test_rms_norm_fwd_bwd_compiles_at_hidden_4096(tpu, dtype):
                     _s(tpu, (rows, h), dtype)) == {"rms_norm_bwd": 1}
 
 
-@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
-def test_decode_attention_compiles(tpu, kv_dtype):
-    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+@pytest.mark.parametrize("shape,kv_dtype", [
+    ((8, 32, 8, 4096, 128), jnp.bfloat16),
+    ((8, 32, 8, 4096, 128), jnp.int8),
+    # the Mistral serving cell's own: 16 slots, max_len 2048
+    ((16, 32, 8, 2048, 128), jnp.bfloat16),
+])
+def test_decode_attention_compiles(tpu, shape, kv_dtype):
+    """All KV heads of a row ride one grid step, so the block of cache
+    positions the kernel picks (``_block_len``) has to keep its tiles —
+    and an int8 cache's lane-padded scale tiles — inside scoped VMEM."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _block_len, decode_attention)
 
-    B, H, KV, L, D = 8, 32, 8, 4096, 128
+    B, H, KV, L, D = shape
+    quant = kv_dtype == jnp.int8
+    assert _block_len(256, L, KV, D, jnp.dtype(kv_dtype).itemsize,
+                      quant) == 256
     q, pos = _s(tpu, (B, H, D)), _s(tpu, (B,), jnp.int32)
     kc = _s(tpu, (B, KV, L, D), kv_dtype)
-    if kv_dtype == jnp.int8:
+    if quant:
         sc = _s(tpu, (B, KV, L, 1), jnp.float32)
         kernels = _kernels(
             lambda q, k, v, p, ks, vs: decode_attention(
