@@ -131,11 +131,6 @@ define_flag("use_fused_group_norm", True,
             "normalization dominating the step")
 define_flag("use_fused_rms_norm", True,
             "route rms_norm through the fused Pallas kernel when eligible")
-define_flag("use_fused_rope", False,
-            "route rotary embedding through the fused Pallas kernel; off by "
-            "default (XLA fuses rope into neighbors at train shapes: 67.2 -> "
-            "73.9 ms/step on the 134M Llama when forced on, builder-measured "
-            "on a v5e before PR 1)")
 define_flag("flash_attention_min_seq", 512,
             "min KV seq length to route through the Pallas flash kernel "
             "(below this XLA's fused sdpa wins — measured end-to-end on "

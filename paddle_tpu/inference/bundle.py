@@ -364,11 +364,15 @@ def export_decoder_bundle(decoder, out_dir: str,
             # admissions); T=1 doubles as the per-token degradation rung
             def cdecode(logits, kc, vc, pos, keys, done, eos, temp,
                         T=int(T)):
-                return decoder._chunk_decode(
-                    p, logits, kc, vc, pos, keys, done, eos, temp, None,
+                # the chunk program with no adapter index and no ring;
+                # the entry returns the seven values it always has (eos
+                # and temp come back as they went in)
+                return decoder._ring_chunk_decode(
+                    p, logits, kc, vc, pos, keys, done, eos, temp,
+                    *((None,) * 10),
                     steps=T, do_sample=bool(do_sample),
                     top_k=None if top_k is None else int(top_k),
-                    top_p=None if top_p is None else float(top_p))
+                    top_p=None if top_p is None else float(top_p))[:7]
 
             logits0 = sput(jnp.zeros(logits_sds.shape, logits_sds.dtype),
                            "logits")

@@ -553,7 +553,7 @@ def _spec_round_rows(p, dp, cfg, dcfg, tok, pos, keys, done, kc, vc, dkc,
     """``_spec_round`` under the CHUNKED-SERVING carry contract: PER-ROW
     RNG keys (each row splits its OWN (2,) raw uint32 key per round, so
     its sample stream is invariant to batch neighbours — the admission
-    contract ``chunk_decode`` already honours), per-row eos ids (``-1``
+    contract ``ring_chunk_decode`` already honours), per-row eos ids (``-1``
     = none; rows already done flush their eos fill at the full K+1 rate)
     and per-row temperatures. Same Leviathan accept/reject math as
     ``_spec_round`` — greedy rounds are bit-identical, which is what the
@@ -758,24 +758,14 @@ class LlamaDecoder:
         if self.sharding is not None:
             self.params = self.sharding.shard_params(self.params)
         cfg = self.cfg
-        # trace-time statics the closures below capture: the LIVE
+        # trace-time static the closures below capture: the LIVE
         # DecodeSharding when the programs run under GSPMD (falsy
         # off-mesh — every `if not sharded` check still reads naturally,
         # and _cache_update can reach the mesh for its shard_map
-        # lowering), and the cache layout's head axis
+        # lowering)
         shd = self.sharding if self.sharding is not None else False
-        head_major = cfg.num_attention_heads != cfg.num_key_value_heads
-        self._head_major = head_major
-        srd = self.sharding
-
-        def pin_carry(logits, kc, vc, pos, keys, done):
-            """Sharding-preserving jit: carry outputs keep the carry
-            inputs' placements, so re-entry never decays to replicated
-            (no-op off-mesh)."""
-            if not shd:
-                return logits, kc, vc, pos, keys, done
-            return srd.constrain_carry(logits, kc, vc, pos, keys, done,
-                                       head_major)
+        self._head_major = (cfg.num_attention_heads
+                            != cfg.num_key_value_heads)
         self.trace_count = 0     # python side effect: bumps only on (re)trace
         self.dispatch_count = 0  # one per device program execution
         self._spec_engines = {}  # draft-model state for speculative decode
@@ -783,23 +773,19 @@ class LlamaDecoder:
         self.last_resilience = None  # retry/degradation record of the last
         #                              generate (also on the result array)
         self._events = []        # typed events of the in-flight generate
-
-        def pin_fwd(logits, kc, vc):
-            if not shd:
-                return logits, kc, vc
-            return (srd.constrain(logits, "logits", head_major),
-                    srd.constrain(kc, "kc", head_major),
-                    srd.constrain(vc, "vc", head_major))
+        pin = self._pin
 
         def prefill(p, ids, kc, vc):
             self.trace_count += 1
-            return pin_fwd(*_forward_cached(p, cfg, ids, kc, vc, 0,
-                                            max_len, sharded=shd))
+            logits, kc, vc = _forward_cached(p, cfg, ids, kc, vc, 0,
+                                             max_len, sharded=shd)
+            return pin(logits=logits, kc=kc, vc=vc)
 
         def step(p, ids, kc, vc, pos):
             self.trace_count += 1
-            return pin_fwd(*_forward_cached(p, cfg, ids, kc, vc, pos,
-                                            max_len, sharded=shd))
+            logits, kc, vc = _forward_cached(p, cfg, ids, kc, vc, pos,
+                                             max_len, sharded=shd)
+            return pin(logits=logits, kc=kc, vc=vc)
 
         def fused_decode(p, logits0, kc, vc, pos0, key0, done0, eos_id,
                          temperature, steps: int, do_sample: bool,
@@ -843,62 +829,17 @@ class LlamaDecoder:
             return jnp.concatenate([jnp.moveaxis(toks, 0, 1),
                                     last[:, None]], axis=1)
 
-        def chunk_decode(p, logits0, kc, vc, pos0, keys0, done0, eos,
-                         temperature, aidx, steps: int, do_sample: bool,
-                         top_k, top_p):
-            """T steps of the fused token loop as ONE re-enterable
-            dispatch: the carry comes in and goes back out as plain
-            arrays (DecodeState), so a serving engine can admit new
-            requests into freed rows BETWEEN chunks instead of holding
-            dead slots until the slowest row finishes (Orca-style
-            iteration-level batching). Per-row everything: positions
-            (rows admitted at different times sit at different cache
-            offsets), eos ids (-1 = none), temperatures, and RNG keys —
-            each row splits its OWN key per step, so a row's sample
-            stream is invariant to its batch neighbours. Greedy chunks
-            chained over N steps are bit-exact with the run-to-completion
-            fused path (same pick-then-forward stream). ``aidx`` (B,) i32
-            or None: per-row LoRA adapter routing — read-only here, like
-            eos/temperature (admission rewrites it via the ring/scatter
-            paths)."""
-            self.trace_count += 1
-
-            def pick(logits, keys, done):
-                if do_sample:
-                    kk = jax.vmap(jax.random.split)(keys)       # (B,2,2)
-                    keys, subs = kk[:, 0], kk[:, 1]
-                    flt = _filter_logits(logits, temperature[:, None],
-                                         top_k, top_p)
-                    tok = jax.vmap(jax.random.categorical)(
-                        subs, flt).astype(jnp.int32)
-                else:
-                    tok = jnp.argmax(logits, -1).astype(jnp.int32)
-                tok = jnp.where(done, jnp.where(eos >= 0, eos, 0), tok)
-                done = jnp.logical_or(done, tok == eos)
-                return tok, keys, done
-
-            def body(carry, _):
-                logits, kc, vc, pos, keys, done = carry
-                tok, keys, done = pick(logits, keys, done)
-                logits, kc, vc = _forward_cached(p, cfg, tok[:, None], kc,
-                                                 vc, pos, max_len,
+        def admit_rows(p, ids, kc, vc, true_len, pos0, aidx):
+            """The traced body both admission entries share: forward the
+            right-padded rows at their cache offsets and take each row's
+            logits at position ``true_len - 1`` of its bucket."""
+            logits_all, kc, vc = _forward_cached(p, cfg, ids, kc, vc,
+                                                 pos0, max_len,
+                                                 return_all=True,
                                                  sharded=shd, aidx=aidx)
-                # rows past their budget keep stepping until the chunk
-                # boundary; clamping pins their (discarded) writes to the
-                # last cache slot instead of running off the buffer
-                pos = jnp.minimum(pos + 1, max_len - 1)
-                return (logits, kc, vc, pos, keys, done), tok
-
-            (logits, kc, vc, pos, keys, done), toks = jax.lax.scan(
-                body, (logits0, kc, vc, pos0, keys0, done0), None,
-                length=steps)
-            # the re-entry contract: the carry leaves this program with
-            # the SAME placements it arrived with (sharding-preserving
-            # jit) — chaining chunks never gathers the state to host
-            logits, kc, vc, pos, keys, done = pin_carry(
-                logits, kc, vc, pos, keys, done)
-            return (jnp.moveaxis(toks, 0, 1), logits, kc, vc, pos, keys,
-                    done)
+            logits = jnp.take_along_axis(
+                logits_all, (true_len - 1)[:, None, None], axis=1)[:, 0]
+            return logits, kc, vc
 
         def admit_prefill(p, ids, kc, vc, true_len, pos0, aidx=None):
             """Length-bucketed admission prefill: ``ids`` is a batch of
@@ -920,13 +861,9 @@ class LlamaDecoder:
             adapter's deltas, so the cached prefix KV matches what a
             dense per-tenant model would have produced."""
             self.trace_count += 1
-            logits_all, kc, vc = _forward_cached(p, cfg, ids, kc, vc,
-                                                 pos0, max_len,
-                                                 return_all=True,
-                                                 sharded=shd, aidx=aidx)
-            logits = jnp.take_along_axis(
-                logits_all, (true_len - 1)[:, None, None], axis=1)[:, 0]
-            return pin_fwd(logits, kc, vc)
+            logits, kc, vc = admit_rows(p, ids, kc, vc, true_len, pos0,
+                                        aidx)
+            return pin(logits=logits, kc=kc, vc=vc)
 
         def ring_admit_prefill(p, ids, kc, vc, true_len, pos0,
                                ring_logits, ring_kc, ring_vc, ring_idx,
@@ -940,17 +877,13 @@ class LlamaDecoder:
             counted prefill dispatch — the host-side ``_admit_row``
             scatter round-trip is gone."""
             self.trace_count += 1
-            logits_all, kc, vc = _forward_cached(p, cfg, ids, kc, vc,
-                                                 pos0, max_len,
-                                                 return_all=True,
-                                                 sharded=shd, aidx=aidx)
-            logits = jnp.take_along_axis(
-                logits_all, (true_len - 1)[:, None, None], axis=1)[:, 0]
+            logits, kc, vc = admit_rows(p, ids, kc, vc, true_len, pos0,
+                                        aidx)
             ring_logits = ring_logits.at[ring_idx].set(logits,
                                                        mode="drop")
             ring_kc = _row_scatter(ring_kc, kc, ring_idx)
             ring_vc = _row_scatter(ring_vc, vc, ring_idx)
-            return pin_fwd(ring_logits, ring_kc, ring_vc)
+            return pin(logits=ring_logits, kc=ring_kc, vc=ring_vc)
 
         def ring_chunk_decode(p, logits0, kc, vc, pos0, keys0, done0,
                               eos0, temp0, aidx0, ring_logits, ring_kc,
@@ -958,20 +891,34 @@ class LlamaDecoder:
                               ring_eos, ring_temp, ring_aidx,
                               steps: int, do_sample: bool,
                               top_k, top_p):
-            """``chunk_decode`` with a DEVICE-SIDE slot-refill prologue:
-            before the T-step scan, ring rows staged by
-            ``ring_admit_prefill`` scatter into the carry at their
-            destination slots (``ring_slot``; empty ring rows carry the
-            B sentinel and drop). Admitting mid-stream therefore never
-            adds a dispatch boundary — steady state is ONE fused
-            dispatch per chunk per replica regardless of admission rate.
-            ``ring_slot=None`` (with every ring operand None) skips the
-            prologue and is trace-identical to the plain chunk. Because
+            """T steps of the fused token loop as ONE re-enterable
+            dispatch — the decoder's only chunk program: the carry comes
+            in and goes back out as plain arrays (DecodeState), so a
+            serving engine can admit new requests into freed rows
+            BETWEEN chunks instead of holding dead slots until the
+            slowest row finishes (Orca-style iteration-level batching).
+            Per-row everything: positions (rows admitted at different
+            times sit at different cache offsets), eos ids (-1 = none),
+            temperatures, and RNG keys — each row splits its OWN key per
+            step, so a row's sample stream is invariant to its batch
+            neighbours. Greedy chunks chained over N steps are bit-exact
+            with the run-to-completion fused path (same
+            pick-then-forward stream).
+
+            The DEVICE-SIDE slot-refill prologue: before the T-step
+            scan, ring rows staged by ``ring_admit_prefill`` scatter
+            into the carry at their destination slots (``ring_slot``;
+            empty ring rows carry the B sentinel and drop). Admitting
+            mid-stream therefore never adds a dispatch boundary — steady
+            state is ONE fused dispatch per chunk per replica regardless
+            of admission rate. With every ring operand ``None``
+            (``LlamaDecoder.decode_chunk``, bundle entries, engines that
+            admit by host scatter) the prologue is not traced. Because
             admission can rewrite per-row eos/temp, BOTH are part of the
-            returned carry here (the plain program treats them as
-            read-only inputs). ``aidx0``/``ring_aidx``: per-row LoRA
-            adapter indices — part of the returned carry for the same
-            reason (admission rewrites a freed slot's tenant)."""
+            returned carry. ``aidx0``/``ring_aidx`` (B,) i32 or None:
+            per-row LoRA adapter indices — part of the returned carry
+            for the same reason (admission rewrites a freed slot's
+            tenant)."""
             self.trace_count += 1
             B = logits0.shape[0]
             logits, pos, keys, done = logits0, pos0, keys0, done0
@@ -1009,21 +956,21 @@ class LlamaDecoder:
                 logits, kc, vc = _forward_cached(p, cfg, tok[:, None], kc,
                                                  vc, pos, max_len,
                                                  sharded=shd, aidx=aidx)
+                # rows past their budget keep stepping until the chunk
+                # boundary; clamping pins their (discarded) writes to the
+                # last cache slot instead of running off the buffer
                 pos = jnp.minimum(pos + 1, max_len - 1)
                 return (logits, kc, vc, pos, keys, done), tok
 
             (logits, kc, vc, pos, keys, done), toks = jax.lax.scan(
                 body, (logits, kc, vc, pos, keys, done), None,
                 length=steps)
-            logits, kc, vc, pos, keys, done = pin_carry(
-                logits, kc, vc, pos, keys, done)
-            if shd:
-                eos = srd.constrain(eos, "eos", head_major)
-                temp = srd.constrain(temp, "temp", head_major)
-                if aidx is not None:
-                    aidx = srd.constrain(aidx, "adapter_idx", head_major)
-            return (jnp.moveaxis(toks, 0, 1), logits, kc, vc, pos, keys,
-                    done, eos, temp, aidx)
+            # the re-entry contract: the carry leaves this program with
+            # the SAME placements it arrived with (sharding-preserving
+            # jit) — chaining chunks never gathers the state to host
+            carry = pin(logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
+                        done=done, eos=eos, temp=temp, adapter_idx=aidx)
+            return (jnp.moveaxis(toks, 0, 1),) + carry
 
         self._prefill = self._counted(jax.jit(prefill), "decode.prefill")
         self._step = self._counted(jax.jit(step), "decode.step")
@@ -1031,32 +978,28 @@ class LlamaDecoder:
             fused_decode,
             static_argnames=("steps", "do_sample", "use_eos", "top_k",
                              "top_p")), "decode.fused")
-        self._chunk_decode = self._counted(jax.jit(
-            chunk_decode,
-            static_argnames=("steps", "do_sample", "top_k", "top_p")),
-            "decode.chunk")
-        # the same trace fn jitted under its own fault site: the serving
+        # one jitted chunk program under two fault sites: the serving
         # degradation ladder's per-token rung must stay dispatchable when
         # a plan is killing "decode.chunk"
-        self._chunk_step = self._counted(jax.jit(
-            chunk_decode,
-            static_argnames=("steps", "do_sample", "top_k", "top_p")),
-            "decode.chunk_step")
+        chunk = jax.jit(ring_chunk_decode, static_argnames=(
+            "steps", "do_sample", "top_k", "top_p"))
+        self._ring_chunk_decode = self._counted(chunk, "decode.chunk")
+        self._ring_chunk_step = self._counted(chunk, "decode.chunk_step")
+        # both admission entries dispatch under one site: the serving
+        # ladder, fault plans and the obs span-vs-dispatch accounting see
+        # ONE logical site per role
         self._admit_prefill = self._counted(jax.jit(admit_prefill),
                                             "decode.admit_prefill")
-        # ring-admission variants: same fault sites as their plain
-        # counterparts — the serving ladder, fault plans and the obs
-        # span-vs-dispatch accounting see ONE logical site per role
-        self._ring_chunk_decode = self._counted(jax.jit(
-            ring_chunk_decode,
-            static_argnames=("steps", "do_sample", "top_k", "top_p")),
-            "decode.chunk")
-        self._ring_chunk_step = self._counted(jax.jit(
-            ring_chunk_decode,
-            static_argnames=("steps", "do_sample", "top_k", "top_p")),
-            "decode.chunk_step")
         self._ring_admit_prefill = self._counted(jax.jit(
             ring_admit_prefill), "decode.admit_prefill")
+
+    def _pin(self, **fields) -> tuple:
+        """Sharding-preserving jit: the named carry fields a program
+        returns keep the placements the carry arrived with, so re-entry
+        never decays to replicated. Off-mesh the values pass through."""
+        if self.sharding is None:
+            return tuple(fields.values())
+        return self.sharding.constrain_carry(self._head_major, **fields)
 
     def _counted(self, jitted, site="decode.dispatch"):
         """Count dispatches AND guard each one: the fault-injection hook
@@ -1280,16 +1223,27 @@ class LlamaDecoder:
                 tok=tok, spec_rounds=sr, spec_accepted=sa, nv=nv,
                 adapter_idx=aidx, spec_on=son,
                 steps_done=state.steps_done + int(num_tokens))
-        toks, logits, kc, vc, pos, keys, done = self._chunk_decode(
-            self.params, state.logits, state.kc, state.vc, state.pos,
-            state.keys, state.done, state.eos, state.temp,
-            state.adapter_idx,
-            steps=int(num_tokens), do_sample=bool(do_sample),
+        return self._advance(
+            self._ring_chunk_decode, state, num_tokens,
+            do_sample=bool(do_sample),
             top_k=None if top_k is None else int(top_k),
             top_p=None if top_p is None else float(top_p))
+
+    def _advance(self, entry, state: DecodeState, steps: int, ring=None,
+                 **statics):
+        """One dispatch of the chunk program over a plain carry.
+        ``entry`` is ``_ring_chunk_decode`` or its per-token-site twin
+        ``_ring_chunk_step``; ``ring`` the program's nine ring operands
+        (``ServingEngine``'s staged admissions), ``None`` for none."""
+        (toks, logits, kc, vc, pos, keys, done, eos, temp, aidx) = entry(
+            self.params, state.logits, state.kc, state.vc, state.pos,
+            state.keys, state.done, state.eos, state.temp,
+            state.adapter_idx, *((None,) * 9 if ring is None else ring),
+            steps=int(steps), **statics)
         return toks, dataclasses.replace(
             state, logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
-            done=done, steps_done=state.steps_done + int(num_tokens))
+            done=done, eos=eos, temp=temp, adapter_idx=aidx,
+            steps_done=state.steps_done + int(steps))
 
     def _generate_chunked(self, ids, max_new, eos_norm, do_sample,
                           temperature, top_k, top_p, seed, chunk_size):
@@ -1416,7 +1370,7 @@ class LlamaDecoder:
         if eng is not None:
             return eng
         shd = self.sharding if self.sharding is not None else False
-        srd, head_major = self.sharding, self._head_major
+        pin = self._pin
         if isinstance(draft_model, str):
             dcfg = dataclasses.replace(cfg, num_hidden_layers=n)
             dp = self.params
@@ -1428,8 +1382,8 @@ class LlamaDecoder:
                     f"vocab_size {cfg.vocab_size}")
             dp = _build_params(draft_model, max_len,
                                "int8" if draft_quant else self.weight_dtype)
-            if srd is not None:
-                dp = srd.shard_params(dp)
+            if self.sharding is not None:
+                dp = self.sharding.shard_params(dp)
 
         def draft_prefill(dp_, ids, dkc, dvc):
             self.trace_count += 1
@@ -1502,20 +1456,6 @@ class LlamaDecoder:
                 cond, body,
                 (buf, pos, tok0, key0, done, kc, vc, dkc, dvc, z, z))
             return out[0], out[9], out[10]
-
-        def pin_spec_carry(logits, kc, vc, dkc, dvc, pos, keys, done,
-                           eos, temp, tok, sr, sa, aidx=None, son=None):
-            if srd is None:
-                return (logits, kc, vc, dkc, dvc, pos, keys, done, eos,
-                        temp, tok, sr, sa, aidx, son)
-            c = lambda x, f: srd.constrain(x, f, head_major)  # noqa: E731
-            return (c(logits, "logits"), c(kc, "kc"), c(vc, "vc"),
-                    c(dkc, "dkc"), c(dvc, "dvc"), c(pos, "pos"),
-                    c(keys, "keys"), c(done, "done"), c(eos, "eos"),
-                    c(temp, "temp"), c(tok, "tok"), c(sr, "spec_rounds"),
-                    c(sa, "spec_accepted"),
-                    None if aidx is None else c(aidx, "adapter_idx"),
-                    None if son is None else c(son, "spec_on"))
 
         def spec_chunk(p, dp_, logits0, kc, vc, dkc, dvc, pos0, keys0,
                        done0, eos0, temp0, tok0, sr0, sa0, aidx0, son0,
@@ -1632,12 +1572,11 @@ class LlamaDecoder:
              sr, sa) = jax.lax.fori_loop(
                 0, T, body, (buf, cnt, logits, tok, pos, keys, done, kc,
                              vc, dkc, dvc, sr, sa))
-            (logits, kc, vc, dkc, dvc, pos, keys, done, eos, temp, tok,
-             sr, sa, aidx, son) = pin_spec_carry(
-                logits, kc, vc, dkc, dvc, pos, keys, done, eos, temp,
-                tok, sr, sa, aidx, son)
-            return (buf, cnt, logits, kc, vc, dkc, dvc, pos, keys, done,
-                    eos, temp, tok, sr, sa, aidx, son)
+            return (buf, cnt) + pin(
+                logits=logits, kc=kc, vc=vc, dkc=dkc, dvc=dvc, pos=pos,
+                keys=keys, done=done, eos=eos, temp=temp, tok=tok,
+                spec_rounds=sr, spec_accepted=sa, adapter_idx=aidx,
+                spec_on=son)
 
         def spec_demote(p, logits0, kc, vc, tok, pos, aidx=None):
             """One-time speculative->chunked demotion of a live carry:
@@ -1656,12 +1595,7 @@ class LlamaDecoder:
                                          max_len, sharded=shd, aidx=aidx)
             logits = jnp.where(need[:, None], lg, logits0)
             pos = jnp.where(need, jnp.minimum(pos + 1, max_len - 1), pos)
-            if srd is not None:
-                logits = srd.constrain(logits, "logits", head_major)
-                kc = srd.constrain(kc, "kc", head_major)
-                vc = srd.constrain(vc, "vc", head_major)
-                pos = srd.constrain(pos, "pos", head_major)
-            return logits, kc, vc, pos
+            return pin(logits=logits, kc=kc, vc=vc, pos=pos)
 
         def ring_draft_prefill(dp_, ids, dkc, dvc, ring_dkc, ring_dvc,
                                ring_idx):
@@ -1673,10 +1607,7 @@ class LlamaDecoder:
                                           max_len, sharded=shd)
             ring_dkc = _row_scatter(ring_dkc, dkc, ring_idx)
             ring_dvc = _row_scatter(ring_dvc, dvc, ring_idx)
-            if srd is not None:
-                ring_dkc = srd.constrain(ring_dkc, "dkc", head_major)
-                ring_dvc = srd.constrain(ring_dvc, "dvc", head_major)
-            return ring_dkc, ring_dvc
+            return pin(dkc=ring_dkc, dvc=ring_dvc)
 
         eng = {
             "cfg": dcfg, "params": dp, "ekey": ekey,
@@ -1695,10 +1626,6 @@ class LlamaDecoder:
             "chunk": self._counted(jax.jit(spec_chunk, static_argnames=(
                 "steps", "K", "do_sample", "top_k", "top_p")),
                 "decode.chunk"),
-            "chunk_step": self._counted(jax.jit(
-                spec_chunk, static_argnames=(
-                    "steps", "K", "do_sample", "top_k", "top_p")),
-                "decode.chunk_step"),
             "demote": self._counted(jax.jit(spec_demote),
                                     "decode.spec_demote"),
             "ring_prefill": self._counted(jax.jit(ring_draft_prefill),

@@ -237,14 +237,14 @@ class DecodeSharding:
                         self.state_entries(field, x.ndim, head_major))
         return jax.lax.with_sharding_constraint(x, ns)
 
-    def constrain_carry(self, logits, kc, vc, pos, keys, done,
-                        head_major: bool):
-        return (self.constrain(logits, "logits", head_major),
-                self.constrain(kc, "kc", head_major),
-                self.constrain(vc, "vc", head_major),
-                self.constrain(pos, "pos", head_major),
-                self.constrain(keys, "keys", head_major),
-                self.constrain(done, "done", head_major))
+    def constrain_carry(self, head_major: bool, **fields) -> tuple:
+        """``constrain`` over the named ``DecodeState`` fields a program
+        returns (``logits=``, ``kc=``, ``eos=``, ``adapter_idx=`` ...; a
+        ``None`` value stays ``None``), in the order given: the one call
+        every carry-returning program and the admission scatter pin
+        their outputs through."""
+        return tuple(self.constrain(v, f, head_major)
+                     for f, v in fields.items())
 
     # -- metadata (bundle.json / statusz / bench records) -------------------
     def describe(self) -> Dict[str, object]:

@@ -84,14 +84,8 @@ from paddle_tpu.ops.registry import register_op
 @register_op("rope", ref="paddle/phi/kernels/fusion/gpu/fused_rope_kernel.cu (capability analog)")
 def _rope_op(x, cos, sin):
     """Rotate (B, S, H, D) by position tables (S, D/2). Interleaved halves
-    (Llama convention: split at D/2, not even/odd). Routes to the fused
-    Pallas kernel (ops/pallas/rope.py) when shapes/flags allow."""
-    from paddle_tpu.flags import flags
-    if flags.use_fused_rope:
-        from paddle_tpu.ops.pallas import rope as k
-        if k.supported(jnp.shape(x), jnp.shape(cos),
-                       jnp.asarray(x).dtype, jnp.asarray(cos).dtype):
-            return k.rope_fused(x, cos, sin)
+    (Llama convention: split at D/2, not even/odd). Left to XLA, which
+    fuses it into its neighbours."""
     d2 = x.shape[-1] // 2
     x1, x2 = x[..., :d2], x[..., d2:]
     c = cos[None, :, None, :]

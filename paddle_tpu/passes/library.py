@@ -198,14 +198,13 @@ FUSED_ROUTING_OFF: Dict[str, object] = {
     "use_fused_group_norm": False,
     "use_fused_attention": False,
     "use_fused_lm_ce": False,
-    "use_fused_rope": False,
     "use_decode_attention": False,
 }
 
 
 class decompose_fused:
     """Context manager: inside it, every fused op (fused_rms_norm,
-    fused GroupNorm+SiLU, flash/decode attention, fused rope, chunked
+    fused GroupNorm+SiLU, flash/decode attention, chunked
     fused lm-head CE, fused_linear_activation/swiglu) traces as its
     canonical base-prim composition — no pallas_call, no vocab-chunk
     scan. Routing happens at trace time, so wrapping a trace (NOT just a

@@ -28,8 +28,8 @@ one-prefill-per-request accounting above exact.
 
 Two backends serve the same scheduler:
 
-- ``LlamaDecoder`` (in-process): jitted ``_admit_prefill`` /
-  ``_chunk_decode`` entries;
+- ``LlamaDecoder`` (in-process): jitted ``_ring_admit_prefill`` /
+  ``_admit_prefill`` and ``_ring_chunk_decode`` entries;
 - ``AotPredictor`` over a bundle exported with ``chunk_sizes=``:
   ``admit_prefill_s{S}.aot`` / ``decode_chunk_b{B}_t{T}.aot`` StableHLO
   entries — zero model Python at serve time (``decode_mode.chunked``).
@@ -154,13 +154,9 @@ def _make_admit_fn(sharding, head_major):
     def admit(*args):
         logits, kc, vc, pos, keys, done, eos, temp, aidx = \
             _admit_row(*args)
-        logits, kc, vc, pos, keys, done = sharding.constrain_carry(
-            logits, kc, vc, pos, keys, done, head_major)
-        eos = sharding.constrain(eos, "eos", head_major)
-        temp = sharding.constrain(temp, "temp", head_major)
-        if aidx is not None:
-            aidx = sharding.constrain(aidx, "adapter_idx", head_major)
-        return logits, kc, vc, pos, keys, done, eos, temp, aidx
+        return sharding.constrain_carry(
+            head_major, logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
+            done=done, eos=eos, temp=temp, adapter_idx=aidx)
 
     return admit
 
@@ -395,25 +391,20 @@ class _DecoderBackend:
                 None if aidx is None else jnp.asarray(aidx, jnp.int32),
                 None if son is None else jnp.asarray(son, jnp.bool_))
 
-    def _run_ring(self, entry, st, steps, ring):
-        slot, pos, keys, eos, temp, aidx, _son = self._ring_dev(ring)
-        (toks, logits, kc, vc, pos2, keys2, done, eos2, temp2,
-         aidx2) = entry(
-            self.dec.params, st.logits, st.kc, st.vc, st.pos, st.keys,
-            st.done, st.eos, st.temp, st.adapter_idx, self._ring_logits,
-            self._ring_kc, self._ring_vc, slot, pos, keys, eos, temp,
-            aidx, steps=int(steps), **self._kw)
-        return toks, dataclasses.replace(
-            st, logits=logits, kc=kc, vc=vc, pos=pos2, keys=keys2,
-            done=done, eos=eos2, temp=temp2, adapter_idx=aidx2,
-            steps_done=st.steps_done + int(steps))
-
-    def decode_chunk_ring(self, st, chunk_size, ring):
-        return self._run_ring(self.dec._ring_chunk_decode, st,
-                              chunk_size, ring)
-
-    def decode_step_ring(self, st, ring):
-        return self._run_ring(self.dec._ring_chunk_step, st, 1, ring)
+    def decode(self, st, steps, ring=None, rung="chunk"):
+        """One dispatch of the decoder's chunk program over the serving
+        carry: ``steps`` tokens for every slot, the host-side splice
+        arrays ``ring`` (``ServingEngine._ring_args``; ``None`` on an
+        engine that admits by host scatter) spliced in first.
+        ``rung="step"`` dispatches the same program under the per-token
+        rung's own fault site."""
+        if ring is not None:
+            slot, pos, keys, eos, temp, aidx, _son = self._ring_dev(ring)
+            ring = (self._ring_logits, self._ring_kc, self._ring_vc,
+                    slot, pos, keys, eos, temp, aidx)
+        return self.dec._advance(
+            self.dec._ring_chunk_decode if rung == "chunk"
+            else self.dec._ring_chunk_step, st, steps, ring, **self._kw)
 
     def decode_chunk_spec(self, st, chunk_size, ring, K=None):
         """One chunked-speculative dispatch over the serving carry;
@@ -477,21 +468,6 @@ class _DecoderBackend:
             jnp.asarray(np.asarray(pos0), jnp.int32),
             None if aidx is None
             else jnp.asarray(np.asarray(aidx), jnp.int32))
-
-    def _run(self, entry, st, steps):
-        toks, logits, kc, vc, pos, keys, done = entry(
-            self.dec.params, st.logits, st.kc, st.vc, st.pos, st.keys,
-            st.done, st.eos, st.temp, st.adapter_idx, steps=int(steps),
-            **self._kw)
-        return toks, dataclasses.replace(
-            st, logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
-            done=done, steps_done=st.steps_done + int(steps))
-
-    def decode_chunk(self, st, chunk_size):
-        return self._run(self.dec._chunk_decode, st, chunk_size)
-
-    def decode_step(self, st):
-        return self._run(self.dec._chunk_step, st, 1)
 
     def has_step_rung(self) -> bool:
         return True
@@ -663,19 +639,23 @@ class _BundleBackend:
         return self.pred._run_entry(
             self._admit[S], "bundle.admit_prefill", ids_d, kc, vc, tl, p0)
 
-    def _run(self, fname, site, st):
+    def decode(self, st, steps, ring=None, rung="chunk"):
+        """One dispatch of the exported chunk entry (``rung="step"``: the
+        T=1 entry, under its own fault site); ``steps`` was baked at
+        export."""
+        if ring is not None:
+            raise ValueError(
+                "bundle chunk entries carry no admission ring "
+                "(decode_mode.chunked.admit_ring is false)")
+        fname, site = ((self._chunk_file, "bundle.chunk")
+                       if rung == "chunk"
+                       else (self._step_file, "bundle.chunk_step"))
         toks, logits, kc, vc, pos, keys, done = self.pred._run_entry(
             fname, site, st.logits, st.kc, st.vc, st.pos, st.keys,
             st.done, st.eos, st.temp)
         return toks, dataclasses.replace(
             st, logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
             done=done)
-
-    def decode_chunk(self, st, chunk_size):
-        return self._run(self._chunk_file, "bundle.chunk", st)
-
-    def decode_step(self, st):
-        return self._run(self._step_file, "bundle.chunk_step", st)
 
     def has_step_rung(self) -> bool:
         return self._step_file is not None
@@ -2502,26 +2482,51 @@ class ServingEngine:
                 for item in grp:
                     self._admit_group_ring(w, [item], free, now)
 
+    def _row_key(self, req: Request):
+        """The admitted row's RNG key. By default the SAME rule as
+        ``generate(chunk_size=)`` at B=1: the request's stream is keyed
+        by its seed alone. Under ``request_keyed_rng`` the request-keyed
+        stream: a requeued row that replays T teacher-forced tokens
+        resumes at the key the undisturbed row would hold after T
+        advances (sampled replay parity)."""
+        import jax.random as jrandom
+        if self.request_keyed_rng:
+            rng_id = (req.rng_request_id if req.rng_request_id is not None
+                      else req.id)
+            return derive_row_key(req.seed, rng_id, req.rng_tokens_emitted)
+        return jrandom.split(jrandom.PRNGKey(req.seed), 1)[0]
+
+    def _pack_group(self, w: int, items):
+        """The host arrays of one admission-prefill dispatch over
+        ``items`` = ``(request, cached)`` pairs of one bucket width
+        ``w``: ids right-padded past each prompt's uncached suffix,
+        per-row true lengths and cache offsets, and the rows' adapter
+        indices (``None`` without an adapter store)."""
+        N = len(items)
+        ids = np.zeros((N, w), np.int32)
+        true_len = np.zeros((N,), np.int32)
+        pos0 = np.zeros((N,), np.int32)
+        for j, (req, cached) in enumerate(items):
+            suffix = np.asarray(req.prompt)[cached:]
+            ids[j, :len(suffix)] = suffix
+            true_len[j] = len(suffix)
+            pos0[j] = cached
+        aidxN = None
+        if self.adapter_store is not None:
+            aidxN = np.asarray([self.adapter_store.index(req.adapter)
+                                for req, _ in items], np.int32)
+        return ids, true_len, pos0, aidxN
+
     def _admit_group_ring(self, w: int, grp, free, now: float) -> None:
         """ONE ring-staged admission-prefill dispatch for the group
         (plus one draft-cache staging dispatch under speculation): the
         freshly prefilled rows land in device ring rows, never on the
         host."""
-        import jax.random as jrandom
         t0 = time.monotonic()
         N = len(grp)
-        ids = np.zeros((N, w), np.int32)
-        true_len = np.zeros((N,), np.int32)
-        pos0 = np.zeros((N,), np.int32)
         rows = [free.popleft() for _ in range(N)]
-        for j, (slot_idx, req) in enumerate(grp):
-            p = np.asarray(req.prompt)
-            ids[j, :len(p)] = p
-            true_len[j] = len(p)
-        aidxN = None
-        if self.adapter_store is not None:
-            aidxN = np.asarray([self.adapter_store.index(req.adapter)
-                                for _, req in grp], np.int32)
+        ids, true_len, pos0, aidxN = self._pack_group(
+            w, [(req, 0) for _, req in grp])
         ev0 = self._b.event_count()
         with TraceAnnotation("serving.admit.prefill_enqueue"):
             self._b.ring_admit(ids, true_len, pos0, rows, aidx=aidxN)
@@ -2539,15 +2544,7 @@ class ServingEngine:
             # above: device time inside admit, kept apart as admit_wait
             with obs.phase("serving.admit.row_key",
                            self._h_phase["admit_wait"]):
-                if self.request_keyed_rng:
-                    rng_id = (req.rng_request_id
-                              if req.rng_request_id is not None
-                              else req.id)
-                    key1 = np.asarray(derive_row_key(
-                        req.seed, rng_id, req.rng_tokens_emitted))
-                else:
-                    key1 = np.asarray(jrandom.split(
-                        jrandom.PRNGKey(req.seed), 1)[0])
+                key1 = np.asarray(self._row_key(req))
             self._ring_meta[rows[j]] = {
                 "slot": slot_idx, "pos": len(req.prompt),
                 "key": np.asarray(key1, np.uint32),
@@ -2611,25 +2608,16 @@ class ServingEngine:
         cache, ops = self.prefix_cache, self._slab_ops
         t0 = time.monotonic()
         N = len(grp)
-        ids = np.zeros((N, w), np.int32)
-        true_len = np.zeros((N,), np.int32)
-        pos0 = np.zeros((N,), np.int32)
+        ids, true_len, pos0, aidxN = self._pack_group(
+            w, [(req, cached) for _, req, _, cached in grp])
         kcN = vcN = None
         for j, (slot_idx, req, hit, cached) in enumerate(grp):
-            suffix = np.asarray(req.prompt)[cached:]
-            ids[j, :len(suffix)] = suffix
-            true_len[j] = len(suffix)
-            pos0[j] = cached
             if cached:
                 cache.pin(hit.slab)
                 if kcN is None:
                     kcN, vcN = self._b.empty_cache(N)
                 kcN, vcN = ops.load(kcN, vcN, hit.slab.kc, hit.slab.vc,
                                     j)
-        aidxN = None
-        if self.adapter_store is not None:
-            aidxN = np.asarray([self.adapter_store.index(req.adapter)
-                                for _, req, _, _ in grp], np.int32)
         ev0 = self._b.event_count()
         with TraceAnnotation("serving.admit.prefill_enqueue"):
             logitsN, kcN, vcN = self._b.admit_prefill(
@@ -2667,24 +2655,9 @@ class ServingEngine:
         admission (prefix-cache and bundle backends); ring-served
         engines never reach it (``admission.host_scattered`` stays 0)."""
         import jax.numpy as jnp
-        import jax.random as jrandom
 
         self._c_host_scattered.inc()
-
-        if self.request_keyed_rng:
-            # request-keyed stream: a requeued row that replays T
-            # teacher-forced tokens resumes at the key the undisturbed
-            # row would hold after T advances (sampled replay parity)
-            rng_id = (req.rng_request_id if req.rng_request_id is not None
-                      else req.id)
-            key1 = jnp.asarray(
-                derive_row_key(req.seed, rng_id, req.rng_tokens_emitted),
-                jnp.uint32)
-        else:
-            # the SAME row-key rule as generate(chunk_size=) at B=1: the
-            # request's stream is keyed by its seed alone
-            key1 = jnp.asarray(
-                jrandom.split(jrandom.PRNGKey(req.seed), 1)[0], jnp.uint32)
+        key1 = jnp.asarray(self._row_key(req), jnp.uint32)
         st = self.state
         aidx1 = None
         if st.adapter_idx is not None:
@@ -2817,12 +2790,8 @@ class ServingEngine:
                 # its ReplicaSet peers (different tags) keep serving
                 fault_injector.on_call(
                     f"serving.{self.replica_tag}.chunk")
-            if ring is not None:
-                toks, self.state = self._b.decode_chunk_ring(
-                    self.state, self.chunk_size, ring)
-            else:
-                toks, self.state = self._b.decode_chunk(self.state,
-                                                        self.chunk_size)
+            toks, self.state = self._b.decode(self.state, self.chunk_size,
+                                              ring)
             self._c_chunk.inc()
             self._c_slot_steps.inc(self.num_slots * self.chunk_size)
             self._ring_drained(n_staged)
@@ -2867,14 +2836,11 @@ class ServingEngine:
                 if self.replica_tag:
                     fault_injector.on_call(
                         f"serving.{self.replica_tag}.step")
-                if ring is not None:
-                    toks1, self.state = self._b.decode_step_ring(
-                        self.state, ring)
-                    if s == 0:
-                        self._ring_drained(n_staged)
-                        ring, _ = self._ring_args()   # now empty
-                else:
-                    toks1, self.state = self._b.decode_step(self.state)
+                toks1, self.state = self._b.decode(self.state, 1, ring,
+                                                   rung="step")
+                if s == 0 and n_staged:
+                    self._ring_drained(n_staged)
+                    ring, _ = self._ring_args()   # now empty
                 self._c_step.inc()
                 self._phase_to("wait")
                 parts.append(np.asarray(toks1))

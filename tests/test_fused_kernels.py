@@ -1,7 +1,7 @@
-"""Pallas fused-kernel parity tests: rms_norm + rope vs the XLA composition.
+"""Pallas fused-kernel parity tests: rms_norm vs the XLA composition.
 
-Reference capability: paddle/phi/kernels/fusion/ fused_rms_norm +
-fused_rope. Kernels run in interpret mode on CPU (same code path as TPU).
+Reference capability: paddle/phi/kernels/fusion/ fused_rms_norm. Kernels
+run in interpret mode on CPU (same code path as TPU).
 """
 
 import jax
@@ -12,7 +12,6 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.flags import flags
 from paddle_tpu.ops.pallas import rms_norm as prms
-from paddle_tpu.ops.pallas import rope as prope
 
 
 def _lax_rms(x, w, eps):
@@ -143,49 +142,9 @@ def test_rms_supported_refuses_only_what_cannot_fit_or_divide():
     assert prms._row_block(24, 64, 12, prms._BWD_TEMPS) == 8
 
 
-def _ref_rope(x, cos, sin):
-    d2 = x.shape[-1] // 2
-    x1, x2 = x[..., :d2], x[..., d2:]
-    c = cos[None, :, None, :]
-    s = sin[None, :, None, :]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_pallas_rope_forward_parity(dtype):
-    rng = np.random.default_rng(4)
-    B, S, H, D = 2, 16, 3, 8
-    x = jnp.asarray(rng.normal(size=(B, S, H, D)), dtype)
-    t = rng.normal(size=(S, D // 2))
-    cos = jnp.asarray(np.cos(t), dtype)
-    sin = jnp.asarray(np.sin(t), dtype)
-    assert prope.supported(x.shape, cos.shape)
-    out = prope.rope_fused(x, cos, sin)
-    ref = _ref_rope(x, cos, sin)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=1e-6, atol=1e-6)
-
-
-def test_pallas_rope_grad_parity():
-    rng = np.random.default_rng(5)
-    B, S, H, D = 2, 8, 2, 8
-    x = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    t = rng.normal(size=(S, D // 2))
-    cos = jnp.asarray(np.cos(t), jnp.float32)
-    sin = jnp.asarray(np.sin(t), jnp.float32)
-    g0 = jax.grad(lambda x, c, s: (_ref_rope(x, c, s) ** 2).sum(),
-                  (0, 1, 2))(x, cos, sin)
-    g1 = jax.grad(lambda x, c, s: (prope.rope_fused(x, c, s) ** 2).sum(),
-                  (0, 1, 2))(x, cos, sin)
-    for a, b in zip(g0, g1):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-5)
-
-
-def test_llama_rope_op_fused_vs_unfused_training_parity():
-    """One eager train step of the tiny Llama with fused kernels on vs off:
-    losses and a sampled grad must agree."""
+def test_llama_fused_rms_norm_vs_unfused_training_parity():
+    """One eager train step of the tiny Llama with the fused RMSNorm
+    kernel on vs off: losses and a sampled grad must agree."""
     from paddle_tpu.models.llama import TINY_CONFIG, LlamaForCausalLM
 
     rng = np.random.default_rng(6)
@@ -201,13 +160,12 @@ def test_llama_rope_op_fused_vs_unfused_training_parity():
         return float(loss.numpy()), np.asarray(g.numpy())
 
     try:
-        paddle.set_flags({"use_fused_rms_norm": True, "use_fused_rope": True})
+        paddle.set_flags({"use_fused_rms_norm": True})
         l_fused, g_fused = one_loss_and_grad()
-        paddle.set_flags({"use_fused_rms_norm": False,
-                          "use_fused_rope": False})
+        paddle.set_flags({"use_fused_rms_norm": False})
         l_ref, g_ref = one_loss_and_grad()
-    finally:  # restore defaults (rope fused is opt-in, see flags.py)
-        paddle.set_flags({"use_fused_rms_norm": True, "use_fused_rope": False})
+    finally:
+        paddle.set_flags({"use_fused_rms_norm": True})
     assert abs(l_fused - l_ref) < 1e-5, (l_fused, l_ref)
     np.testing.assert_allclose(g_fused, g_ref, rtol=1e-4, atol=1e-5)
 

@@ -15,6 +15,8 @@ The load-bearing properties:
   without dropping any in-flight request (``faults`` drill).
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -213,6 +215,100 @@ def test_engine_sampled_fixed_keys_row_independent(dec):
     np.testing.assert_array_equal(g, outs[0][0])
 
 
+def _tiny_decoder(kind):
+    """Tiny decoders of the three kinds the benchmark's configurations
+    are: GQA (head-major cache), MHA, and a looped model whose cache has
+    layers x passes buffers."""
+    paddle.seed(3)
+    if kind == "ouro":
+        from paddle_tpu.models.ouro import OURO_TINY, OuroForCausalLM
+        return LlamaDecoder(OuroForCausalLM(OURO_TINY), max_len=64)
+    kv = 2 if kind == "gqa" else 4
+    return LlamaDecoder(LlamaForCausalLM(LlamaConfig(
+        **{**CFG, "num_key_value_heads": kv})), max_len=64)
+
+
+@pytest.mark.parametrize("kind", ["gqa", "mha", "ouro"])
+def test_one_chunk_program_serves_plain_carry_and_engine(kind):
+    """The decoder has ONE chunk program: ``decode_chunk`` on a plain
+    carry (no ring operand) and the engine's dispatch with an empty ring
+    run it to the same tokens and the same carry, leaf for leaf; the
+    per-token rung is the same jitted object under its own fault site."""
+    import jax
+    d = _tiny_decoder(kind)
+    assert not hasattr(d, "_chunk_decode")
+    assert not hasattr(d, "_chunk_step")
+    assert d._ring_chunk_decode._jitted is d._ring_chunk_step._jitted
+    ids = np.random.default_rng(21).integers(0, d.cfg.vocab_size, (3, 6))
+    st0 = d.init_decode_state(ids, eos_token_id=5, temperature=0.7,
+                              seed=4)
+    toks_d, st_d = d.decode_chunk(st0, 4, do_sample=True, top_k=8)
+    eng = ServingEngine(d, num_slots=3, chunk_size=4, do_sample=True,
+                        top_k=8)
+    ring, staged = eng._ring_args()
+    assert staged == 0 and (ring[0] == -1).all()
+    toks_e, st_e = eng._b.decode(st0, 4, ring)
+    np.testing.assert_array_equal(np.asarray(toks_e), np.asarray(toks_d))
+    def flat(st):
+        return jax.tree_util.tree_flatten(
+            {f.name: getattr(st, f.name) for f in dataclasses.fields(st)})
+    leaves_d, tree_d = flat(st_d)
+    leaves_e, tree_e = flat(st_e)
+    assert tree_d == tree_e
+    assert len(leaves_d) >= 6 + 2 * d.cfg.num_cache_layers
+    for a, b in zip(leaves_d, leaves_e):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and the rung's site dispatches the same program one step at a time
+    toks_1, st_1 = eng._b.decode(st0, 1, ring, rung="step")
+    np.testing.assert_array_equal(np.asarray(toks_1)[:, 0],
+                                  np.asarray(toks_d)[:, 0])
+    assert int(st_1.steps_done) == 1 and int(st_e.steps_done) == 4
+
+
+@pytest.mark.parametrize("request_keyed", [False, True])
+def test_row_key_rule_has_one_site(dec, request_keyed):
+    """``ServingEngine._row_key`` is the admitted row's key on the ring
+    path and on the host-scatter path alike: the seed alone (the rule of
+    ``generate(chunk_size=)`` at B=1), or the request-keyed stream."""
+    import jax.random as jrandom
+
+    from paddle_tpu.serving.engine import derive_row_key
+    eng = ServingEngine(dec, num_slots=2, chunk_size=4,
+                        request_keyed_rng=request_keyed)
+    req = Request(id=7, prompt=np.arange(4), max_new_tokens=4, seed=123,
+                  rng_request_id=41, rng_tokens_emitted=3)
+    want = (derive_row_key(123, 41, 3) if request_keyed
+            else jrandom.split(jrandom.PRNGKey(123), 1)[0])
+    np.testing.assert_array_equal(np.asarray(eng._row_key(req)),
+                                  np.asarray(want))
+    req.rng_request_id = None      # falls back to the engine's own id
+    if request_keyed:
+        np.testing.assert_array_equal(
+            np.asarray(eng._row_key(req)),
+            np.asarray(derive_row_key(123, 7, 3)))
+
+
+def test_host_scatter_and_ring_engines_draw_the_same_samples(dec):
+    """One seed, one stream: an engine that admits by host scatter (the
+    prefix cache is on) and a ring engine sample the same tokens."""
+    rng = np.random.default_rng(17)
+    reqs = [(rng.integers(0, 64, (int(rng.integers(3, 10)),)),
+             int(rng.integers(4, 10)), 100 + i) for i in range(5)]
+    outs = []
+    for kw in (dict(), dict(prefix_cache=True,
+                            prefix_cache_bytes=1 << 30)):
+        eng = ServingEngine(dec, num_slots=2, chunk_size=3,
+                            do_sample=True, top_k=12, **kw)
+        ids = [eng.submit(p, n, temperature=0.9, seed=sd)
+               for p, n, sd in reqs]
+        res = eng.drain()
+        outs.append([np.asarray(res[r]) for r in ids])
+        scattered = int(eng._c_host_scattered.value)
+        assert (scattered == len(reqs)) if kw else (scattered == 0)
+    for a, b in zip(*outs):
+        np.testing.assert_array_equal(a, b)
+
+
 def test_engine_speculative_parity_stats_and_accounting(dec):
     """Tentpole: the engine over the chunked speculative program is
     bit-exact vs the PLAIN engine on the same submissions, with the
@@ -367,6 +463,27 @@ def test_bundle_chunked_serving_parity(dec, tmp_path):
     for i, rid in enumerate(ids):
         np.testing.assert_array_equal(np.asarray(res[rid]), solo[i])
     assert eng.metrics()["prefill_dispatches"] == len(reqs)
+    # a bundle has no ring entries: every admission was a host scatter
+    assert eng.metrics()["admission_ring"] is None
+    assert int(eng._c_host_scattered.value) == len(reqs)
+    with pytest.raises(ValueError, match="admission ring"):
+        eng._b.decode(eng.state, 4, ring=())
+    # the entries' signatures are the exported contract (a bundle written
+    # before the decoder had one chunk program still loads): carry in,
+    # tokens + carry out, each cache one argument
+    import jax
+
+    from paddle_tpu.inference.aot import _split
+    for fname, n_in, n_out in (("decode_chunk_b2_t4.aot", 8, 7),
+                               ("decode_chunk_b2_t1.aot", 8, 7),
+                               ("admit_prefill_s8.aot", 5, 3)):
+        path = str(tmp_path / fname)
+        with open(path, "rb") as f:
+            exp = jax.export.deserialize(
+                bytearray(_split(f.read(), path)[1]))
+        args = jax.tree_util.treedef_children(exp.in_tree)[0]
+        assert len(args.children()) == n_in, fname
+        assert len(exp.out_tree.children()) == n_out, fname
 
 
 def test_bundle_without_chunked_entries_refuses(dec, tmp_path):
