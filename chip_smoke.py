@@ -316,7 +316,7 @@ def _serve_run(dec, sz: dict, prompts, budgets) -> dict:
         programs[site] = _program(
             c["kernels"], c["collectives"],
             **{k: c.get(k) for k in ("argument_bytes", "output_bytes",
-                                     "temp_bytes")})
+                                     "temp_bytes", "alias_bytes")})
     return {
         "tokens": tokens,
         "budgets_met": all(len(t) == len(p) + b

@@ -51,6 +51,24 @@ class LoopedDraftError(ValueError):
     layers ``total_ut_steps`` times has no such prefix of depth."""
 
 
+class ConsumedStateError(RuntimeError):
+    """A dispatch was handed buffers an earlier dispatch had been given
+    to keep: the chunk program takes its carry's KV caches and the ring
+    prefill takes the admission ring (donated, so the program writes
+    them in place), and what a caller still holds of them afterwards is
+    deleted. Go on from the state the call returned."""
+
+
+def _consumed(tree) -> bool:
+    """Whether the buffers of ``tree`` went into a donating dispatch.
+    One call donates a whole argument, so its first leaf speaks for the
+    rest."""
+    leaves = jax.tree_util.tree_leaves(tree)
+    return bool(leaves) and isinstance(leaves[0], jax.Array) \
+        and not isinstance(leaves[0], jax.core.Tracer) \
+        and leaves[0].is_deleted()
+
+
 @dataclasses.dataclass
 class DecodeState:
     """The exported/re-enterable carry of the fused decode loop.
@@ -64,6 +82,10 @@ class DecodeState:
     per-row eos ids (``-1`` = no eos for that row) and temperatures.
     ``decode_chunk`` advances the state by T tokens in ONE dispatch;
     chaining chunks is bit-exact with run-to-completion for greedy.
+    The chunk program CONSUMES the state it is given — ``kc`` and ``vc``
+    are donated, so the caches are written in place and never copied —
+    and ``consumed`` says so afterwards; the state to go on from is the
+    one the call returned.
 
     A SPECULATIVE carry (``init_decode_state(draft_model=...)``)
     additionally holds the draft model's KV caches (``dkc``/``dvc``), a
@@ -103,6 +125,11 @@ class DecodeState:
     #                       rows speculate, the pre-multiplex behaviour)
     spec: Any = None      # host-side: {"ekey", "K"} engine routing meta
     steps_done: int = 0   # host-side: loop steps executed so far
+
+    @property
+    def consumed(self) -> bool:
+        """True once a chunk dispatch has taken this state's caches."""
+        return _consumed(self.kc)
 
 
 def _rope_at(x, pos, cfg, p):
@@ -875,7 +902,11 @@ class LlamaDecoder:
             dispatch, and the next chunk program splices them into the
             live carry mid-chunk. Admission thus costs exactly its one
             counted prefill dispatch — the host-side ``_admit_row``
-            scatter round-trip is gone."""
+            scatter round-trip is gone. The three ring buffers are
+            DONATED: the scatter writes the admitted rows in place and
+            the returned ring is the one passed in, which the caller
+            must not touch again (``kc``/``vc``, the empty pair the
+            rows prefill from, are not)."""
             self.trace_count += 1
             logits, kc, vc = admit_rows(p, ids, kc, vc, true_len, pos0,
                                         aidx)
@@ -904,6 +935,12 @@ class LlamaDecoder:
             neighbours. Greedy chunks chained over N steps are bit-exact
             with the run-to-completion fused path (same
             pick-then-forward stream).
+
+            ``kc`` and ``vc`` are DONATED: the returned caches are
+            the ones passed in, written in place, and the caller's
+            handles to them are dead after the call. The ring operands
+            are only read — the ring outlives the chunk and the next
+            admission writes into it.
 
             The DEVICE-SIDE slot-refill prologue: before the T-step
             scan, ring rows staged by ``ring_admit_prefill`` scatter
@@ -980,18 +1017,29 @@ class LlamaDecoder:
                              "top_p")), "decode.fused")
         # one jitted chunk program under two fault sites: the serving
         # degradation ladder's per-token rung must stay dispatchable when
-        # a plan is killing "decode.chunk"
+        # a plan is killing "decode.chunk". The program is GIVEN its
+        # carry's caches (kc, vc donated): its output carry aliases them
+        # and no chunk begins by copying the whole cache. The ring
+        # operands stay the caller's: the ring outlives the chunk
+        carry = (2, 3)
         chunk = jax.jit(ring_chunk_decode, static_argnames=(
-            "steps", "do_sample", "top_k", "top_p"))
-        self._ring_chunk_decode = self._counted(chunk, "decode.chunk")
-        self._ring_chunk_step = self._counted(chunk, "decode.chunk_step")
+            "steps", "do_sample", "top_k", "top_p"), donate_argnums=carry)
+        self._ring_chunk_decode = self._counted(chunk, "decode.chunk",
+                                                consumes=carry)
+        self._ring_chunk_step = self._counted(chunk, "decode.chunk_step",
+                                              consumes=carry)
         # both admission entries dispatch under one site: the serving
         # ladder, fault plans and the obs span-vs-dispatch accounting see
-        # ONE logical site per role
+        # ONE logical site per role. The ring entry is given the ring
+        # (ring_logits, ring_kc, ring_vc donated) and writes the admitted
+        # rows into it in place; its kc, vc are the empty pair every
+        # admission shares and stay the caller's
         self._admit_prefill = self._counted(jax.jit(admit_prefill),
                                             "decode.admit_prefill")
-        self._ring_admit_prefill = self._counted(jax.jit(
-            ring_admit_prefill), "decode.admit_prefill")
+        ring = (6, 7, 8)
+        self._ring_admit_prefill = self._counted(
+            jax.jit(ring_admit_prefill, donate_argnums=ring),
+            "decode.admit_prefill", consumes=ring)
 
     def _pin(self, **fields) -> tuple:
         """Sharding-preserving jit: the named carry fields a program
@@ -1001,13 +1049,20 @@ class LlamaDecoder:
             return tuple(fields.values())
         return self.sharding.constrain_carry(self._head_major, **fields)
 
-    def _counted(self, jitted, site="decode.dispatch"):
+    def _counted(self, jitted, site="decode.dispatch", consumes=()):
         """Count dispatches AND guard each one: the fault-injection hook
         fires first (an injected failure is a dispatch that never ran, so
         counters stay parity-comparable with the no-fault run), then the
         execution retries transient backend errors with backoff
         (resilient_call; FLAGS_resilience_retries/backoff_s). Retry
         events land in the in-flight generate's record.
+
+        ``consumes``: the positions ``jitted`` donates. An attempt that
+        finds one of them already gone — the caller reused a consumed
+        state, or this is the retry of a dispatch that failed after it
+        had taken them — raises ``ConsumedStateError`` (fatal: nothing
+        is left to retry) where the runtime would say "buffer has been
+        deleted or donated".
 
         Observability (paddle_tpu/obs, FLAGS_obs_enabled): each executed
         dispatch records a span named after its fault site with the
@@ -1030,6 +1085,13 @@ class LlamaDecoder:
         obs.watch_compiles()
 
         def attempt(args, kwargs):
+            for i in consumes:
+                if _consumed(args[i]):
+                    raise ConsumedStateError(
+                        f"{site}: argument {i} was given to an earlier "
+                        f"dispatch, which consumed it (its buffers are "
+                        f"donated); go on from the state that dispatch "
+                        f"returned, or build a fresh one")
             fault_injector.on_call(site)
             self.dispatch_count += 1
             with obs.dispatch_site(site):
@@ -1184,10 +1246,15 @@ class LlamaDecoder:
                      K: Optional[int] = None):
         """Advance the loop carry by ``num_tokens`` steps in ONE device
         dispatch; returns ``(tokens (B, num_tokens), new_state)``.
-        Chaining chunks totalling N steps emits the same greedy tokens,
-        bit-exactly, as one run-to-completion ``generate`` of N — the
-        property continuous batching rides on (a request's output can't
-        depend on how admission sliced its decode into dispatches).
+        ``state`` is CONSUMED: its caches are donated to the program and
+        come back, written in place, in ``new_state``; decoding the old
+        state again raises ``ConsumedStateError`` (to branch twice from
+        one prompt, build the state twice). A speculative carry is not
+        consumed. Chaining chunks totalling N steps emits the same
+        greedy tokens, bit-exactly, as one run-to-completion
+        ``generate`` of N — the property continuous batching rides on (a
+        request's output can't depend on how admission sliced its decode
+        into dispatches).
 
         A SPECULATIVE carry (``init_decode_state(draft_model=...)``)
         routes to the chunked speculative program instead:
@@ -1231,10 +1298,12 @@ class LlamaDecoder:
 
     def _advance(self, entry, state: DecodeState, steps: int, ring=None,
                  **statics):
-        """One dispatch of the chunk program over a plain carry.
-        ``entry`` is ``_ring_chunk_decode`` or its per-token-site twin
-        ``_ring_chunk_step``; ``ring`` the program's nine ring operands
-        (``ServingEngine``'s staged admissions), ``None`` for none."""
+        """One dispatch of the chunk program over a plain carry, which
+        it consumes (``state.kc`` / ``state.vc`` are donated; the other
+        fields stay readable). ``entry`` is ``_ring_chunk_decode`` or
+        its per-token-site twin ``_ring_chunk_step``; ``ring`` the
+        program's nine ring operands (``ServingEngine``'s staged
+        admissions; read, not consumed), ``None`` for none."""
         (toks, logits, kc, vc, pos, keys, done, eos, temp, aidx) = entry(
             self.params, state.logits, state.kc, state.vc, state.pos,
             state.keys, state.done, state.eos, state.temp,

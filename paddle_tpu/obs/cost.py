@@ -169,13 +169,18 @@ def dispatch_cost(site: str, jitted, args=(), kwargs=None,
             mem = compiled.memory_analysis()
             for field, k in (("temp_size_in_bytes", "temp_bytes"),
                              ("argument_size_in_bytes", "argument_bytes"),
-                             ("output_size_in_bytes", "output_bytes")):
+                             ("output_size_in_bytes", "output_bytes"),
+                             ("alias_size_in_bytes", "alias_bytes")):
                 v = getattr(mem, field, None)
                 if v is not None:
                     out[k] = int(v)
             if "temp_bytes" in out:
+                # outputs that alias a donated argument (a chunk's KV
+                # carry) are counted among the outputs and take no new
+                # memory
                 out["peak_bytes"] = (out["temp_bytes"]
-                                     + out.get("output_bytes", 0))
+                                     + out.get("output_bytes", 0)
+                                     - out.get("alias_bytes", 0))
         except Exception:
             pass
         # the bytes-moved-per-dispatch record (the weight-bandwidth

@@ -240,10 +240,12 @@ class _DecoderBackend:
     def _prefill_cache(self, N: int, draft: bool = False):
         """The empty caches an admission prefill of ``N`` rows starts
         from. An admission is one row unless ``batch_admission`` groups
-        several, and no prefill program donates its inputs: the batch-1
-        pair is built once and shared by every admission (built afresh,
-        2 x layers eager ``jnp.zeros`` held the v5e's host 15 ms an
-        admission with the device idle: PERF.md section 6, PR 27)."""
+        several, and no prefill program donates these inputs (the ring
+        prefill donates the ring it stages into, never the pair it
+        prefills from): the batch-1 pair is built once and shared by
+        every admission (built afresh, 2 x layers eager ``jnp.zeros``
+        held the v5e's host 15 ms an admission with the device idle:
+        PERF.md section 6, PR 27)."""
         cfg = self.spec_eng["cfg"] if draft else None
         if N != 1:
             return self.dec._empty_cache(N, cfg)
@@ -352,7 +354,10 @@ class _DecoderBackend:
         """ONE counted admission-prefill dispatch whose results stage
         straight into device ring rows ``ring_idx`` — no host round-trip
         for the row state. ``aidx`` prefills each admitted row through
-        its adapter's deltas (None = base for all rows)."""
+        its adapter's deltas (None = base for all rows). The program is
+        given the ring (donated: the rows are written in place) and the
+        three buffers are rebound from its result; a dispatch that
+        fails after it has taken them leaves ``ring_consumed()`` true."""
         import jax.numpy as jnp
         ids = np.asarray(ids)
         kc, vc = self._prefill_cache(int(ids.shape[0]))
@@ -365,6 +370,12 @@ class _DecoderBackend:
                 jnp.asarray(np.asarray(ring_idx), jnp.int32),
                 None if aidx is None
                 else jnp.asarray(np.asarray(aidx), jnp.int32))
+
+    def ring_consumed(self) -> bool:
+        """Whether a failed ring prefill took the donated ring with it
+        (``ring_init`` builds a new, empty one)."""
+        from paddle_tpu.inference.generate import _consumed
+        return _consumed(self._ring_kc)
 
     def ring_admit_draft(self, ids, ring_idx):
         """The draft-model analog: one counted dispatch prefills the
@@ -397,7 +408,8 @@ class _DecoderBackend:
         arrays ``ring`` (``ServingEngine._ring_args``; ``None`` on an
         engine that admits by host scatter) spliced in first.
         ``rung="step"`` dispatches the same program under the per-token
-        rung's own fault site."""
+        rung's own fault site. ``st`` is consumed (its caches are
+        donated); the ring's buffers are read and stay the backend's."""
         if ring is not None:
             slot, pos, keys, eos, temp, aidx, _son = self._ring_dev(ring)
             ring = (self._ring_logits, self._ring_kc, self._ring_vc,
@@ -2475,12 +2487,16 @@ class ServingEngine:
         for slot_idx, req in admitted:
             w = self.scheduler.bucket(len(req.prompt))
             groups.setdefault(w, []).append((slot_idx, req))
-        for w, grp in sorted(groups.items()):
-            if self.batch_admission and len(grp) > 1:
-                self._admit_group_ring(w, grp, free, now)
-            else:
-                for item in grp:
-                    self._admit_group_ring(w, [item], free, now)
+        try:
+            for w, grp in sorted(groups.items()):
+                if self.batch_admission and len(grp) > 1:
+                    self._admit_group_ring(w, grp, free, now)
+                else:
+                    for item in grp:
+                        self._admit_group_ring(w, [item], free, now)
+        except Exception:
+            self._admission_failed(admitted)
+            raise
 
     def _row_key(self, req: Request):
         """The admitted row's RNG key. By default the SAME rule as
@@ -2590,6 +2606,26 @@ class ServingEngine:
                 son[r] = m.get("spec_on", True)
             n += 1
         return (slot, pos, keys, eos, temp, aidx, son), n
+
+    def _admission_failed(self, admitted) -> None:
+        """A ring prefill of this round raised. No request may stay in
+        a slot without a row: those the round had not staged yet go
+        back to their tier's head (slot released, original submit_time,
+        as under ``ring_full``). Where the failed program had taken the
+        donated ring, the rows staged in it went with it: a new, empty
+        ring is built and their requests go back too — a chunk must
+        never splice rows of zeros under their positions."""
+        entries = self.scheduler.slots.entries
+        staged = [m["slot"] for m in self._ring_meta if m is not None]
+        back = [(i, req) for i, req in admitted if i not in staged]
+        if self._b.ring_consumed():
+            back = [(i, entries[i].request) for i in staged] + back
+            self._ring_meta = [None] * self._ring_slots
+            self._b.ring_init(self._ring_slots)
+        for slot_idx, req in reversed(back):
+            self.scheduler.slots.release(slot_idx)
+            self.scheduler.push_front(req)
+        self._g_qdepth.set(len(self.scheduler))
 
     def _ring_drained(self, n: Optional[int]) -> None:
         """A chunk program's ring prologue ran: the staged rows are in
@@ -2799,22 +2835,30 @@ class ServingEngine:
             self._phase_to("wait")
             return np.asarray(toks)
         except Exception as e:
-            if classify_error(e) != "transient":
+            # the chunk program is given the carry (donated): a dispatch
+            # that failed after it had taken it leaves no state for the
+            # per-token rung — or for the next step — to re-enter
+            gone = self.state.consumed
+            if classify_error(e) != "transient" and not gone:
                 # fatal: the router's breaker counts this. Harvest rows
                 # whose HOST tokens already finish them and dump the
                 # postmortem before the error propagates — a finished
                 # request must never ride down with the batch
                 self._harvest_before_raise(e, "serving.chunk_fatal")
                 raise
-            if (not _flags.resilience_auto_degrade
+            if (gone or not _flags.resilience_auto_degrade
                     or not self._b.has_step_rung()):
+                why, reason = (
+                    ("after it had consumed the carry",
+                     "serving.carry_consumed") if gone else
+                    ("with no per-token rung available",
+                     "serving.chunk_failed_no_rung"))
                 err = DecodeFailedError(
-                    f"serving chunk dispatch failed with no per-token "
-                    f"rung available: {str(e)[:300]}",
+                    f"serving chunk dispatch failed {why}: "
+                    f"{str(e)[:300]}",
                     events=self._b.events_since(ev0) + degr,
                     last_error=e)
-                self._harvest_before_raise(
-                    e, "serving.chunk_failed_no_rung")
+                self._harvest_before_raise(e, reason)
                 raise err from e
             ev = DegradationEvent(
                 site="serve.chunk", from_level="chunked",
@@ -2824,11 +2868,13 @@ class ServingEngine:
             self._c_degr.inc()
             degr.append(ev)
         # per-token rung: T single-step dispatches on the SAME carry —
-        # the failed chunk never consumed it (faults fire before
-        # execution; the in-process chunk doesn't donate its inputs), so
-        # every admitted request rides through the degradation. The
-        # FIRST step carries the pending ring splice; later steps pass
-        # an empty ring (same compiled program, all rows dropped).
+        # the failed chunk never took it (an injected fault fires before
+        # the dispatch; a dispatch that did take its donated carry was
+        # turned into DecodeFailedError above), so every admitted
+        # request rides through the degradation. Each step consumes the
+        # carry it is given and hands back the next. The FIRST step
+        # carries the pending ring splice; later steps pass an empty
+        # ring (same compiled program, all rows dropped).
         parts = []
         try:
             for s in range(self.chunk_size):

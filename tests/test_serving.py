@@ -240,14 +240,15 @@ def test_one_chunk_program_serves_plain_carry_and_engine(kind):
     assert not hasattr(d, "_chunk_step")
     assert d._ring_chunk_decode._jitted is d._ring_chunk_step._jitted
     ids = np.random.default_rng(21).integers(0, d.cfg.vocab_size, (3, 6))
-    st0 = d.init_decode_state(ids, eos_token_id=5, temperature=0.7,
-                              seed=4)
-    toks_d, st_d = d.decode_chunk(st0, 4, do_sample=True, top_k=8)
+    # each dispatch consumes the state it is given: one state a branch
+    st0 = lambda: d.init_decode_state(  # noqa: E731
+        ids, eos_token_id=5, temperature=0.7, seed=4)
+    toks_d, st_d = d.decode_chunk(st0(), 4, do_sample=True, top_k=8)
     eng = ServingEngine(d, num_slots=3, chunk_size=4, do_sample=True,
                         top_k=8)
     ring, staged = eng._ring_args()
     assert staged == 0 and (ring[0] == -1).all()
-    toks_e, st_e = eng._b.decode(st0, 4, ring)
+    toks_e, st_e = eng._b.decode(st0(), 4, ring)
     np.testing.assert_array_equal(np.asarray(toks_e), np.asarray(toks_d))
     def flat(st):
         return jax.tree_util.tree_flatten(
@@ -259,7 +260,7 @@ def test_one_chunk_program_serves_plain_carry_and_engine(kind):
     for a, b in zip(leaves_d, leaves_e):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # and the rung's site dispatches the same program one step at a time
-    toks_1, st_1 = eng._b.decode(st0, 1, ring, rung="step")
+    toks_1, st_1 = eng._b.decode(st0(), 1, ring, rung="step")
     np.testing.assert_array_equal(np.asarray(toks_1)[:, 0],
                                   np.asarray(toks_d)[:, 0])
     assert int(st_1.steps_done) == 1 and int(st_e.steps_done) == 4
