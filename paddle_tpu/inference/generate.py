@@ -42,13 +42,21 @@ import numpy as np
 
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM, _rope_tables
 
-__all__ = ["LlamaDecoder", "DecodeState", "LoopedDraftError"]
+__all__ = ["LlamaDecoder", "DecodeState", "LoopedDraftError",
+           "WindowedModelError"]
 
 
 class LoopedDraftError(ValueError):
     """``draft_model='skip:N'`` over a looped model: the layer-skip view
     drafts with the first N layers of depth, and a model that runs its
     layers ``total_ut_steps`` times has no such prefix of depth."""
+
+
+class WindowedModelError(ValueError):
+    """A path that reads or writes cache positions by their index (the
+    speculative verify, a prefill that starts past position 0) was asked
+    of a model with windowed layers, whose rolling buffers keep a
+    position at ``position % window``."""
 
 
 class ConsumedStateError(RuntimeError):
@@ -124,6 +132,12 @@ class DecodeState:
     #                       the SAME speculative chunk program (None = all
     #                       rows speculate, the pre-multiplex behaviour)
     spec: Any = None      # host-side: {"ekey", "K"} engine routing meta
+    moe: Any = None       # (3,) i32, an OUTPUT of a chunk over a model with
+    #                       routed feed-forwards: token-expert pairs that
+    #                       landed on held experts and held experts touched,
+    #                       both summed over the chunk's steps and routed
+    #                       layers, and the largest count one expert took
+    #                       in one step (None for every other model)
     steps_done: int = 0   # host-side: loop steps executed so far
 
     @property
@@ -278,8 +292,59 @@ def _rms(x, w, eps):
             ).astype(x.dtype) * w
 
 
+def _prefill_rows(t, true_len, L: int, head_major: bool):
+    """The rows a prefill from position 0 leaves in a rolling buffer of
+    ``L`` positions, out of its ``S > L`` fresh rows ``t``: slot ``s``
+    holds the last position ``p < true_len`` with ``p % L == s``. The
+    padded tail past ``true_len`` is left out (in a buffer that wraps it
+    would land on live positions); where ``true_len < L`` the slots from
+    it on hold an arbitrary row, masked until decode writes them."""
+    S = t.shape[2] if head_major else t.shape[1]
+    n = (jnp.full((t.shape[0],), S, jnp.int32) if true_len is None
+         else true_len)[:, None]
+    s = jnp.arange(L)[None, :]
+    idx = jnp.clip(s + L * ((n - 1 - s) // L), 0, S - 1)       # (B, L)
+    if head_major:
+        return jnp.take_along_axis(t, idx[:, None, :, None], axis=2)
+    return jnp.take_along_axis(t, idx[:, :, None, None], axis=1)
+
+
+def _fresh_attention(q, k, v, window, sharded):
+    """Causal attention of a prefill from position 0 over its own fresh
+    keys (B, S, KV, D), under a band of ``window`` positions where the
+    layer has one: blockwise through the flash forward kernel, which
+    neither computes nor fetches blocks outside the band and never holds
+    an (S, S) score matrix; XLA's masked attention where the kernel does
+    not take the shape or the program runs under a mesh."""
+    from paddle_tpu.ops.pallas import flash_attention as _fa
+    B, S, H, D = q.shape
+    rep = H // k.shape[2]
+    if rep > 1:
+        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    if window is not None and window >= S:
+        window = None                       # the band is all of the past
+    if not sharded and _fa.supported(q.shape, k.shape, True, window):
+        out = _fa.flash_attention_fn(q, k, v, causal=True, window=window)
+        return out.reshape(B, S, H * D)
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
+        jnp.float32(D)).astype(q.dtype)
+    d = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]
+    mask = d >= 0 if window is None else jnp.logical_and(d >= 0, d < window)
+    scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
+    attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, S, H * D)
+
+
+def _moe_reduce(c):
+    """(n, 3) routing counts -> (3,): pairs on held experts and held
+    experts touched add up, the largest count one expert took is a
+    maximum."""
+    return jnp.concatenate([jnp.sum(c[:, :2], 0), jnp.max(c[:, 2:], 0)])
+
+
 def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
-                   max_len, sharded=False, aidx=None):
+                   max_len, sharded=False, aidx=None, true_len=None,
+                   live=None, moe_stats=None):
     """One decoder block over h (B, S, H), weights of layer ``li``,
     writing K/V into CACHE layer ``ci`` at [pos, pos+S); attention reads
     that whole buffer masked to < pos+S with causal alignment to the
@@ -294,7 +359,26 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     static): the decoder runs under a GSPMD mesh — hand-written Pallas
     kernels (no partitioning rules) give way to the XLA forms, which
     shard via sharding propagation. ``aidx`` (B,) i32 routes per-row LoRA
-    deltas through every projection (see ``_mm``)."""
+    deltas through every projection (see ``_mm``).
+
+    What the layer is, the config says at trace time and the parameters
+    by their presence. ``cfg.layer_rope(li)``: whether queries and keys
+    are rotated. ``cfg.layer_window(li)``: a windowed layer's cache
+    buffer is ROLLING — ``cfg.cache_len(ci, max_len)`` positions, a
+    position kept at ``pos % length``; keys are rotated before they are
+    cached and softmax does not care for order, so a decode step attends
+    over the buffer's first ``min(pos + 1, length)`` rows as it does over
+    a plain one. In a model with any windowed layer (``cfg.has_windows``)
+    ``S > 1`` is a prefill FROM POSITION 0: every layer attends over its
+    own fresh keys (``_fresh_attention``) and writes the last
+    ``min(true_len, length)`` of them (``true_len`` (B,), None = S).
+    ``self_attn.q_norm`` / ``k_norm``: per-head RMSNorm on q and k. A
+    fourth column block of the fused q|k|v matrix: an output gate,
+    ``(o * sigmoid(x Wg)) Wo``. ``mlp.router``: a routed feed-forward
+    over this chip's held experts plus a shared expert
+    (``ops/moe.py:routed_ffn``), ``live`` (B, S) bool the rows that reach
+    an expert, its three counts and the chosen experts appended to
+    ``moe_stats``."""
     B, S, _ = h.shape
     H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
     pre = f"model.layers.{li}."
@@ -304,9 +388,22 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     qkv = _mm(x, p, pre + "self_attn.qkv.weight", sharded, aidx)
     q = qkv[..., :H * D].reshape(B, S, H, D)
     k = qkv[..., H * D:H * D + KV * D].reshape(B, S, KV, D)
-    v = qkv[..., H * D + KV * D:].reshape(B, S, KV, D)
-    q = _rope_at(q, pos, cfg, p)
-    k = _rope_at(k, pos, cfg, p)
+    gate = None
+    if qkv.shape[-1] > (H + 2 * KV) * D:
+        v = qkv[..., (H + KV) * D:(H + 2 * KV) * D].reshape(B, S, KV, D)
+        gate = qkv[..., (H + 2 * KV) * D:]
+    else:
+        v = qkv[..., H * D + KV * D:].reshape(B, S, KV, D)
+    qn = p.get(pre + "self_attn.q_norm.weight")
+    if qn is not None:
+        q = _rms(q, qn, eps)
+        k = _rms(k, p[pre + "self_attn.k_norm.weight"], eps)
+    if cfg.layer_rope(li):
+        q = _rope_at(q, pos, cfg, p)
+        k = _rope_at(k, pos, cfg, p)
+    rolling = cfg.has_windows
+    fresh = rolling and S > 1
+    L = cfg.cache_len(ci, max_len)
 
     rep = H // KV
     head_major = rep > 1   # GQA: (B, KV, L, D) tiles feed the Pallas
@@ -320,8 +417,15 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     # every layer of every step. Measured on the v5e at 7B widths, PR 27:
     # a serving step 23.3 -> 15.3 ms with 12 GQA layers x 16 slots, 23.6
     # -> 9.9 ms with 8 MHA layers x 8 slots. PERF.md section 6.)
-    kc_l = _cache_update(kc[ci], kt, pos, head_major, sharded)
-    vc_l = _cache_update(vc[ci], vt, pos, head_major, sharded)
+    if fresh:
+        if S > L:
+            kt = _prefill_rows(kt, true_len, L, head_major)
+            vt = _prefill_rows(vt, true_len, L, head_major)
+        at = 0
+    else:
+        at = pos % L if rolling else pos
+    kc_l = _cache_update(kc[ci], kt, at, head_major, sharded)
+    vc_l = _cache_update(vc[ci], vt, at, head_major, sharded)
     kc = kc[:ci] + (kc_l,) + kc[ci + 1:]
     vc = vc[:ci] + (vc_l,) + vc[ci + 1:]
 
@@ -340,7 +444,15 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     # per-row qpos: scalar pos broadcasts as (1,1,S,1), vector as (B,1,S,1)
     qpos = (jnp.reshape(pos, (-1, 1, 1, 1))
             + jnp.arange(S)[None, None, :, None])
-    if use_kernel:
+
+    def live_rows():
+        # the buffer's rows a decode step attends over: all up to its own
+        # position, which in a rolling buffer that has wrapped is every row
+        return jnp.minimum(pos + 1, L) if rolling else pos + 1
+
+    if fresh:
+        out = _fresh_attention(q, k, v, cfg.layer_window(li), sharded)
+    elif use_kernel:
         # one-kernel GQA cache attention (block_multi_head_attention
         # capability): no repeated-KV materialization, online softmax,
         # cache blocks past a row's valid prefix neither fetched nor
@@ -355,18 +467,19 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
         # all of max_len, one KV head a grid step: ledger, PR 28).
         if quant_kv:
             out = _da.decode_attention(
-                q[:, 0], kc_l["q"], vc_l["q"], pos + 1,
+                q[:, 0], kc_l["q"], vc_l["q"], live_rows(),
                 k_scale=kc_l["s"], v_scale=vc_l["s"]).reshape(B, S, H * D)
         else:
             out = _da.decode_attention(q[:, 0], kc_l, vc_l,
-                                       pos + 1).reshape(B, S, H * D)
+                                       live_rows()).reshape(B, S, H * D)
     elif head_major:
         kk = jnp.repeat(dequantize_kv(kc_l, q.dtype), rep, axis=1)
         vv = jnp.repeat(dequantize_kv(vc_l, q.dtype), rep, axis=1)
         scores = jnp.einsum("bqhd,bhkd->bhqk", q, kk) / jnp.sqrt(
             jnp.float32(D)).astype(q.dtype)
-        kpos = jnp.arange(max_len)[None, None, None, :]
-        mask = kpos <= qpos                       # bottom-right causal
+        kpos = jnp.arange(L)[None, None, None, :]
+        mask = (kpos < jnp.minimum(qpos + 1, L) if rolling
+                else kpos <= qpos)                # bottom-right causal
         scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
         attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         out = jnp.einsum("bhqk,bhkd->bqhd", attn, vv).reshape(B, S, H * D)
@@ -375,27 +488,43 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
         vv = dequantize_kv(vc_l, q.dtype)
         scores = jnp.einsum("bqhd,bkhd->bhqk", q, kk) / jnp.sqrt(
             jnp.float32(D)).astype(q.dtype)
-        kpos = jnp.arange(max_len)[None, None, None, :]
-        mask = kpos <= qpos                       # bottom-right causal
+        kpos = jnp.arange(L)[None, None, None, :]
+        mask = (kpos < jnp.minimum(qpos + 1, L) if rolling
+                else kpos <= qpos)                # bottom-right causal
         scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
         attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", attn, vv).reshape(B, S, H * D)
+    if gate is not None:
+        out = out * jax.nn.sigmoid(gate)
     att = _mm(out, p, pre + "self_attn.o_proj.weight", sharded, aidx)
     w2 = p.get(pre + "input_layernorm_2.weight")
     h = h + (att if w2 is None else _rms(att, w2, eps))
 
     x = _rms(h, p[pre + "post_attention_layernorm.weight"], eps)
-    gu = _mm(x, p, pre + "mlp.gate_up.weight", sharded, aidx)
+    router = p.get(pre + "mlp.router.weight")
+    ffn = pre + ("mlp." if router is None else "mlp.shared_experts.")
+    gu = _mm(x, p, ffn + "gate_up.weight", sharded, aidx)
     F_ = gu.shape[-1] // 2
     a = jax.nn.silu(gu[..., :F_]) * gu[..., F_:]
-    mlp = _mm(a, p, pre + "mlp.down_proj.weight", sharded, aidx)
+    mlp = _mm(a, p, ffn + "down_proj.weight", sharded, aidx)
+    if router is not None:
+        from paddle_tpu.ops.moe import routed_ffn
+        routed, counts, chosen = routed_ffn(
+            x.reshape(B * S, -1), router, p[pre + "mlp.expert_bias"],
+            p[pre + "mlp.experts_gate_up"], p[pre + "mlp.experts_down"],
+            top_k=cfg.num_experts_per_tok, route_norm=cfg.route_norm,
+            route_scale=cfg.route_scale, expert_offset=cfg.expert_offset,
+            live=None if live is None else live.reshape(B * S))
+        mlp = mlp + routed.reshape(B, S, -1)
+        if moe_stats is not None:
+            moe_stats.append((counts, chosen))
     w2 = p.get(pre + "post_attention_layernorm_2.weight")
     return h + (mlp if w2 is None else _rms(mlp, w2, eps)), kc, vc
 
 
 def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
                     return_all: bool = False, sharded: bool = False,
-                    aidx=None):
+                    aidx=None, true_len=None, live=None, moe_stats=None):
     """ids (B, S) -> logits (B, V) of the LAST position — or of ALL S
     positions (B, S, V) with ``return_all=True`` (speculative verify
     scores every drafted position in one batched forward) — plus the
@@ -411,11 +540,14 @@ def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
     copied whole out of and back into the carry every step (PERF.md
     section 6, PRs 27 and 28)."""
     h = p["model.embed_tokens.weight"][ids]
+    if cfg.embedding_scale != 1.0:
+        h = h * jnp.asarray(cfg.embedding_scale, h.dtype)
     L = cfg.num_hidden_layers
     for t in range(cfg.total_ut_steps):
         for li in range(L):
             h, kc, vc = _block_forward(p, cfg, li, t * L + li, h, kc, vc,
-                                       pos, max_len, sharded, aidx)
+                                       pos, max_len, sharded, aidx,
+                                       true_len, live, moe_stats)
         h = _rms(h, p["model.norm.weight"], cfg.rms_norm_eps)
     hh = h if return_all else h[:, -1]
     if "head:int8" in p:
@@ -434,20 +566,29 @@ def _build_params(model: LlamaForCausalLM, max_len: int,
     tables for the whole cache window. Shared by the target decoder and
     any separate-weights draft model (speculative decoding)."""
     raw = {name: t.value for name, t in model.state_dict().items()}
-    # fuse qkv and gate/up per layer (one matmul each; fewer kernels)
+    # fuse qkv and gate/up per layer (one matmul each; fewer kernels). An
+    # output gate rides the q|k|v matmul as a fourth block; a routed
+    # layer's gate|up is its shared expert's, and its experts' stacks
+    # (already gate|up and down, models/afmoe.py) pass through by
+    # reference: a second copy of them would not fit beside the first
     for li in range(model.config.num_hidden_layers):
         pre = f"model.layers.{li}."
-        raw[pre + "self_attn.qkv.weight"] = jnp.concatenate(
-            [raw.pop(pre + "self_attn.q_proj.weight"),
-             raw.pop(pre + "self_attn.k_proj.weight"),
-             raw.pop(pre + "self_attn.v_proj.weight")], axis=1)
-        raw[pre + "mlp.gate_up.weight"] = jnp.concatenate(
-            [raw.pop(pre + "mlp.gate_proj.weight"),
-             raw.pop(pre + "mlp.up_proj.weight")], axis=1)
+        qkv = [raw.pop(pre + "self_attn.q_proj.weight"),
+               raw.pop(pre + "self_attn.k_proj.weight"),
+               raw.pop(pre + "self_attn.v_proj.weight")]
+        if pre + "self_attn.gate_proj.weight" in raw:
+            qkv.append(raw.pop(pre + "self_attn.gate_proj.weight"))
+        raw[pre + "self_attn.qkv.weight"] = jnp.concatenate(qkv, axis=1)
+        ffn = pre + ("mlp." if pre + "mlp.gate_proj.weight" in raw
+                     else "mlp.shared_experts.")
+        raw[ffn + "gate_up.weight"] = jnp.concatenate(
+            [raw.pop(ffn + "gate_proj.weight"),
+             raw.pop(ffn + "up_proj.weight")], axis=1)
     p = {}
     for name, v in raw.items():
         if (weight_dtype == "int8" and v.ndim == 2
-                and ("self_attn." in name or "mlp." in name)):
+                and ("self_attn." in name or "mlp." in name)
+                and "mlp.router." not in name):
             from paddle_tpu.quantization import weight_quantize
             from paddle_tpu.framework.tensor import Tensor
             q, scale = weight_quantize(Tensor(v))
@@ -802,6 +943,8 @@ class LlamaDecoder:
         self._events = []        # typed events of the in-flight generate
         pin = self._pin
 
+        routed = cfg.routed
+
         def prefill(p, ids, kc, vc):
             self.trace_count += 1
             logits, kc, vc = _forward_cached(p, cfg, ids, kc, vc, 0,
@@ -860,10 +1003,14 @@ class LlamaDecoder:
             """The traced body both admission entries share: forward the
             right-padded rows at their cache offsets and take each row's
             logits at position ``true_len - 1`` of its bucket."""
-            logits_all, kc, vc = _forward_cached(p, cfg, ids, kc, vc,
-                                                 pos0, max_len,
-                                                 return_all=True,
-                                                 sharded=shd, aidx=aidx)
+            # the padded tail reaches no routed expert, and is not
+            # written into a rolling buffer
+            live = (jnp.arange(ids.shape[1])[None, :] < true_len[:, None]
+                    if routed else None)
+            logits_all, kc, vc = _forward_cached(
+                p, cfg, ids, kc, vc, pos0, max_len, return_all=True,
+                sharded=shd, aidx=aidx,
+                true_len=true_len if cfg.has_windows else None, live=live)
             logits = jnp.take_along_axis(
                 logits_all, (true_len - 1)[:, None, None], axis=1)[:, 0]
             return logits, kc, vc
@@ -990,9 +1137,17 @@ class LlamaDecoder:
             def body(carry, _):
                 logits, kc, vc, pos, keys, done = carry
                 tok, keys, done = pick(logits, keys, done)
-                logits, kc, vc = _forward_cached(p, cfg, tok[:, None], kc,
-                                                 vc, pos, max_len,
-                                                 sharded=shd, aidx=aidx)
+                # (a routed model: a frozen row reaches no expert, and
+                # what the routing did this step leaves the scan beside
+                # the tokens)
+                counts = [] if routed else None
+                logits, kc, vc = _forward_cached(
+                    p, cfg, tok[:, None], kc, vc, pos, max_len,
+                    sharded=shd, aidx=aidx, moe_stats=counts,
+                    live=jnp.logical_not(done)[:, None] if routed else None)
+                if routed:
+                    tok = (tok, _moe_reduce(jnp.stack(
+                        [c for c, _ in counts])))
                 # rows past their budget keep stepping until the chunk
                 # boundary; clamping pins their (discarded) writes to the
                 # last cache slot instead of running off the buffer
@@ -1002,12 +1157,16 @@ class LlamaDecoder:
             (logits, kc, vc, pos, keys, done), toks = jax.lax.scan(
                 body, (logits, kc, vc, pos, keys, done), None,
                 length=steps)
+            moe = None
+            if routed:
+                toks, c = toks                          # c (steps, 3)
+                moe = _moe_reduce(c)
             # the re-entry contract: the carry leaves this program with
             # the SAME placements it arrived with (sharding-preserving
             # jit) — chaining chunks never gathers the state to host
             carry = pin(logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
                         done=done, eos=eos, temp=temp, adapter_idx=aidx)
-            return (jnp.moveaxis(toks, 0, 1),) + carry
+            return (jnp.moveaxis(toks, 0, 1),) + carry + (moe,)
 
         self._prefill = self._counted(jax.jit(prefill), "decode.prefill")
         self._step = self._counted(jax.jit(step), "decode.step")
@@ -1125,16 +1284,20 @@ class LlamaDecoder:
         ``cfg.num_cache_layers`` buffers — one per weight layer per pass
         over the layers, so ``num_hidden_layers`` of them for every model
         but a looped one — head-major ``(B, KV, L, D)`` for GQA,
-        token-major ``(B, L, KV, D)`` for MHA."""
+        token-major ``(B, L, KV, D)`` for MHA, ``L`` being ``max_len``
+        or, for a windowed layer, its window."""
         cfg = self.cfg if cfg is None else cfg
         dt = jnp.dtype(cfg.dtype)
         head_major = cfg.num_attention_heads != cfg.num_key_value_heads
-        if head_major:
-            shape = (B, cfg.num_key_value_heads, self.max_len, cfg.head_dim)
-        else:
-            shape = (B, self.max_len, cfg.num_key_value_heads, cfg.head_dim)
 
-        def z():
+        def z(ci):
+            # each cache layer at its own length: a windowed layer's
+            # buffer is rolling and holds its window (LlamaConfig.cache_len)
+            L = cfg.cache_len(ci, self.max_len)
+            if head_major:
+                shape = (B, cfg.num_key_value_heads, L, cfg.head_dim)
+            else:
+                shape = (B, L, cfg.num_key_value_heads, cfg.head_dim)
             if self.quant_kv:
                 # int8 rows + per-row scale buffer (never on a mesh:
                 # int8wk is refused typed at init)
@@ -1148,8 +1311,8 @@ class LlamaDecoder:
             # the carry never exists gathered, not even at init
             return self.sharding.put_state_field("kc", buf, head_major)
 
-        zeros = lambda: tuple(z()  # noqa: E731
-                              for _ in range(cfg.num_cache_layers))
+        zeros = lambda: tuple(z(ci)  # noqa: E731
+                              for ci in range(cfg.num_cache_layers))
         return zeros(), zeros()
 
     # -- chunked resumable decode -----------------------------------------
@@ -1304,14 +1467,15 @@ class LlamaDecoder:
         its per-token-site twin ``_ring_chunk_step``; ``ring`` the
         program's nine ring operands (``ServingEngine``'s staged
         admissions; read, not consumed), ``None`` for none."""
-        (toks, logits, kc, vc, pos, keys, done, eos, temp, aidx) = entry(
+        (toks, logits, kc, vc, pos, keys, done, eos, temp, aidx,
+         moe) = entry(
             self.params, state.logits, state.kc, state.vc, state.pos,
             state.keys, state.done, state.eos, state.temp,
             state.adapter_idx, *((None,) * 9 if ring is None else ring),
             steps=int(steps), **statics)
         return toks, dataclasses.replace(
             state, logits=logits, kc=kc, vc=vc, pos=pos, keys=keys,
-            done=done, eos=eos, temp=temp, adapter_idx=aidx,
+            done=done, eos=eos, temp=temp, adapter_idx=aidx, moe=moe,
             steps_done=state.steps_done + int(steps))
 
     def _generate_chunked(self, ids, max_new, eos_norm, do_sample,
@@ -1407,6 +1571,12 @@ class LlamaDecoder:
         a wrong draft only costs acceptance length, never correctness."""
         import dataclasses
         cfg, max_len = self.cfg, self.max_len
+        if cfg.has_windows:
+            raise WindowedModelError(
+                "speculative decoding verifies several positions in one "
+                "forward over the cache, which a model with windowed "
+                "layers does not do: its S > 1 forward is a prefill from "
+                "position 0 over fresh keys; decode without draft_model=")
         if draft_quant not in (None, "int8w"):
             raise ValueError(
                 f"draft_quant must be None or 'int8w', got {draft_quant!r}")

@@ -2,6 +2,8 @@
 
 Coverage of the bench.py training configs: Llama (TP/PP/CP hybrid trainers),
 Ouro (looped Llama-style blocks, served by the same decoder),
+AFMoE (routed experts as one chip's share, gated window/full attention,
+served by the same decoder),
 GPT (fused-qkv causal LM), BERT (MLM pretraining), diffusion UNet
 (SD-style), plus vision CNNs in paddle_tpu.vision.models.
 """
@@ -12,6 +14,9 @@ from paddle_tpu.models.llama import (  # noqa: F401
 )
 from paddle_tpu.models.ouro import (  # noqa: F401
     OURO_TINY, OuroConfig, OuroForCausalLM, OuroModel,
+)
+from paddle_tpu.models.afmoe import (  # noqa: F401
+    AFMOE_TINY, AfmoeConfig, AfmoeForCausalLM, AfmoeModel,
 )
 from paddle_tpu.models.gpt import GPT_TINY, GPTConfig, GPTForCausalLM  # noqa: F401
 from paddle_tpu.models.bert import BERT_TINY, BertConfig, BertForMaskedLM  # noqa: F401

@@ -50,6 +50,11 @@ class LlamaConfig:
     # passes over the layer list: one here, a field of a looped model's
     # config (models/ouro.py)
     total_ut_steps = 1
+    # what the embedding's rows are multiplied by, and whether any layer
+    # is windowed: properties of a config that has either (models/afmoe.py)
+    embedding_scale = 1.0
+    has_windows = False
+    routed = False      # whether any feed-forward is routed over experts
 
     @property
     def head_dim(self) -> int:
@@ -60,6 +65,23 @@ class LlamaConfig:
         """KV buffers a decoder holds: each pass over the layers keeps
         its own keys and values."""
         return self.num_hidden_layers * self.total_ut_steps
+
+    # what a layer's attention is, by its index: every layer of this family
+    # rotates its queries and keys and attends over all of the past; a
+    # config with layer kinds (models/afmoe.py) answers per layer
+    def layer_window(self, li: int) -> Optional[int]:
+        """Positions a query of layer ``li`` sees, itself included, or
+        None for all of the past."""
+        return None
+
+    def layer_rope(self, li: int) -> bool:
+        return True
+
+    def cache_len(self, ci: int, max_len: int) -> int:
+        """Positions cache layer ``ci`` holds in a decoder of ``max_len``:
+        a windowed layer keeps a rolling buffer of its window."""
+        w = self.layer_window(ci % self.num_hidden_layers)
+        return max_len if w is None else min(max_len, w)
 
 
 TINY_CONFIG = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
@@ -159,11 +181,13 @@ class LlamaAttention(nn.Layer):
 
 
 class LlamaMLP(nn.Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, width: Optional[int] = None):
+        """SwiGLU of ``width`` (the config's ``intermediate_size``)."""
         super().__init__()
-        self.gate_proj = nn.Linear(config.hidden_size, config.intermediate_size, bias_attr=False)
-        self.up_proj = nn.Linear(config.hidden_size, config.intermediate_size, bias_attr=False)
-        self.down_proj = nn.Linear(config.intermediate_size, config.hidden_size, bias_attr=False)
+        width = config.intermediate_size if width is None else width
+        self.gate_proj = nn.Linear(config.hidden_size, width, bias_attr=False)
+        self.up_proj = nn.Linear(config.hidden_size, width, bias_attr=False)
+        self.down_proj = nn.Linear(width, config.hidden_size, bias_attr=False)
 
     def forward(self, x):
         a = _constrain(F.silu(self.gate_proj(x)) * self.up_proj(x),

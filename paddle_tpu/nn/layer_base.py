@@ -10,6 +10,7 @@ Tensors here; the jit/`to_static` path lifts them into function arguments
 from __future__ import annotations
 
 import collections
+import contextlib
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ import numpy as np
 from paddle_tpu.framework.dtype import convert_dtype, is_floating_point_dtype
 from paddle_tpu.framework.tensor import Parameter, Tensor
 
-__all__ = ["Layer"]
+__all__ = ["Layer", "param_dtype"]
 
 
 class HookRemoveHelper:
@@ -33,10 +34,28 @@ class HookRemoveHelper:
         self._hooks.pop(self._id, None)
 
 
+_BUILD_DTYPE = None
+
+
+@contextlib.contextmanager
+def param_dtype(dtype):
+    """Layers built inside that name no ``dtype`` of their own create
+    their parameters in ``dtype`` (an explicit ``dtype=`` still wins): a
+    model too large to exist in float32 first and be cast after is born in
+    the dtype it runs in."""
+    global _BUILD_DTYPE
+    was, _BUILD_DTYPE = _BUILD_DTYPE, convert_dtype(dtype)
+    try:
+        yield
+    finally:
+        _BUILD_DTYPE = was
+
+
 class Layer:
-    def __init__(self, name_scope: Optional[str] = None, dtype="float32"):
+    def __init__(self, name_scope: Optional[str] = None, dtype=None):
         self.training = True
-        self._dtype = convert_dtype(dtype)
+        self._dtype = (convert_dtype(dtype) or _BUILD_DTYPE
+                       or convert_dtype("float32"))
         self._parameters: "collections.OrderedDict[str, Parameter]" = collections.OrderedDict()
         self._buffers: "collections.OrderedDict[str, Tensor]" = collections.OrderedDict()
         self._sub_layers: "collections.OrderedDict[str, Layer]" = collections.OrderedDict()

@@ -25,7 +25,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import _routing
 
-__all__ = ["supported", "flash_attention_op", "flash_attention_fn"]
+__all__ = ["supported", "flash_attention_op", "flash_attention_fn",
+           "WindowBackwardError"]
+
+
+class WindowBackwardError(NotImplementedError):
+    """A gradient was asked through a windowed flash attention: only the
+    forward kernel knows the band."""
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -38,7 +44,7 @@ _NEG_INF = -1e30
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, block_q, block_k,
-                num_k_blocks, offset=0):
+                num_k_blocks, offset=0, window=None):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -59,6 +65,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
             # offset = sk - sq: bottom-right-aligned causal (KV-cache
             # chunked prefill; query i sees keys <= i + offset)
             mask = (qi * block_q + rows + offset) >= (ki * block_k + cols)
+            if window is not None:
+                # the band: a query sees the last `window` keys, itself
+                # included
+                mask = jnp.logical_and(
+                    mask, (qi * block_q + rows + offset)
+                    - (ki * block_k + cols) < window)
             s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_scr[:, 0:1]                    # (BQ, 1)
@@ -75,7 +87,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    if causal:
+    if causal and window is not None:
+        # also skip the k blocks wholly below the band of this q block
+        @pl.when(jnp.logical_and(
+            ki * block_k < (qi + 1) * block_q + offset,
+            qi * block_q + offset - ((ki + 1) * block_k - 1) < window))
+        def _():
+            _visible()
+    elif causal:
         @pl.when(ki * block_k < (qi + 1) * block_q + offset)
         def _():
             _visible()
@@ -98,7 +117,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       *, scale, causal, offset):
+                       *, scale, causal, offset, window=None):
     """Whole-sequence block: plain softmax attention in VMEM. With one
     (q, k) block the online-softmax merge is pure overhead — no m/l
     scratch round-trips, no acc rescale, no alpha exp. Measured 1.8x the
@@ -111,7 +130,10 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         sq, sk = s.shape
         rows = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (sq, sk), 1)
-        s = jnp.where(rows + offset >= cols, s, _NEG_INF)
+        mask = rows + offset >= cols
+        if window is not None:
+            mask = jnp.logical_and(mask, rows + offset - cols < window)
+        s = jnp.where(mask, s, _NEG_INF)
     m = jnp.max(s, axis=1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=1, keepdims=True)
@@ -127,15 +149,18 @@ def _fwd_single_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     lse_ref[0] = jnp.where(masked_row, _NEG_INF, m + jnp.log(l))
 
 
-def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
+def _fwd(q, k, v, scale, causal, block_q, block_k, interpret, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
     nq = sq // block_q
     nk = sk // block_k
+    # window=None passes no keyword: the kernel's partial, and so the
+    # compiled text of every caller without a window, stays as it was
+    band = {} if window is None else {"window": int(window)}
     if nq == 1 and nk == 1:
         return pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale=scale,
-                              causal=causal, offset=sk - sq),
+                              causal=causal, offset=sk - sq, **band),
             grid=(bh,),
             in_specs=[pl.BlockSpec((1, sq, d), lambda b: (b, 0, 0)),
                       pl.BlockSpec((1, sk, d), lambda b: (b, 0, 0)),
@@ -153,15 +178,26 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
 
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, block_q=block_q,
-        block_k=block_k, num_k_blocks=nk, offset=sk - sq)
+        block_k=block_k, num_k_blocks=nk, offset=sk - sq, **band)
+
+    kv_map = lambda b, i, j: (b, j, 0)  # noqa: E731
+    if window is not None:
+        # a k block outside the band of q block i is skipped by the kernel:
+        # name the nearest block inside it instead, so that the pipeline
+        # sees an index it already holds and fetches nothing
+        def kv_map(b, i, j):
+            lo = jnp.maximum(
+                (i * block_q + (sk - sq) - window + 1) // block_k, 0)
+            hi = ((i + 1) * block_q + (sk - sq) - 1) // block_k
+            return (b, jnp.clip(j, lo, hi), 0)
 
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -179,7 +215,8 @@ def _fwd(q, k, v, scale, causal, block_q, block_k, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="flash_fwd",
+        # a name of its own under a band: a trace tells the two apart
+        name="flash_fwd" if window is None else "flash_fwd_band",
     )(q, k, v)
     return out, lse
 
@@ -356,18 +393,25 @@ def _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k, interpret):
 # custom-vjp wrapper on (bh, s, d) layout
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, _ = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, scale, causal, block_q, block_k, interpret, window=None):
+    out, _ = _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                  window)
     return out
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret):
-    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+               window=None):
+    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k, interpret,
+                    window)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(scale, causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(scale, causal, block_q, block_k, interpret, window, res, do):
+    if window is not None:
+        raise WindowBackwardError(
+            "flash attention with a window has a forward kernel only; "
+            "train through the XLA attention with a band mask")
     q, k, v, out, lse = res
     return _bwd(q, k, v, out, lse, do, scale, causal, block_q, block_k,
                 interpret)
@@ -402,11 +446,15 @@ def _auto_blocks(sq: int, sk: int):
     return bq, bk
 
 
-def supported(q_shape, k_shape, causal: bool) -> bool:
+def supported(q_shape, k_shape, causal: bool, window=None) -> bool:
     """Routing predicate (nn.functional): (B, S, H, D) shapes the kernel
     takes as they are. Ragged sequence lengths, un-repeated KV heads and
-    causal queries with no visible key belong to the XLA sdpa path."""
+    causal queries with no visible key belong to the XLA sdpa path; so
+    does a ``window`` (the last so many keys, the query's own included)
+    that is not causal or not positive."""
     if _routing.auto_partitioned():
+        return False
+    if window is not None and (not causal or int(window) < 1):
         return False
     if len(q_shape) != 4 or len(k_shape) != 4:
         return False
@@ -417,8 +465,13 @@ def supported(q_shape, k_shape, causal: bool) -> bool:
 
 
 def flash_attention_fn(q, k, v, causal: bool = False, scale=None,
-                       block_q: int = None, block_k: int = None):
+                       block_q: int = None, block_k: int = None,
+                       window: int = None):
     """Pure-jax flash attention on paddle layout (B, S, H, D).
+
+    ``window`` (causal only): query i sees keys ``i + sk - sq - window <
+    j <= i + sk - sq``; blocks outside that band are neither computed nor
+    fetched. Forward only: its gradient raises ``WindowBackwardError``.
 
     Falls back to unblocked shapes by shrinking blocks; requires S to be a
     multiple of the (possibly shrunk) block size — callers with ragged
@@ -443,6 +496,8 @@ def flash_attention_fn(q, k, v, causal: bool = False, scale=None,
         raise ValueError("flash_attention: causal requires sk >= sq")
     if k.shape[2] != h:
         raise ValueError("flash_attention: repeat kv heads before the kernel")
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError("flash_attention: a window is causal and >= 1")
     scale = float(scale) if scale is not None else 1.0 / math.sqrt(d)
 
     def to_bh(x):
@@ -451,7 +506,8 @@ def flash_attention_fn(q, k, v, causal: bool = False, scale=None,
 
     qb, kb, vb = to_bh(q), to_bh(k), to_bh(v)
     ob = _flash(qb, kb, vb, scale, bool(causal), block_q, block_k,
-                _routing.use_interpret())
+                _routing.use_interpret(),
+                None if window is None else int(window))
     return jnp.swapaxes(ob.reshape(b, h, sq, d), 1, 2)
 
 

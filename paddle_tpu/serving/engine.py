@@ -201,6 +201,11 @@ class _DecoderBackend:
         self.prompt_buckets = None          # any pow2 bucket compiles
         self.sharding = dec.sharding        # the decoder's mesh governs
         self.head_major = getattr(dec, "_head_major", False)
+        # a model with windowed layers keeps those layers' keys in rolling
+        # buffers: what addresses cache rows by position refuses it
+        self.has_windows = dec.cfg.has_windows
+        self.moe_counts = []    # state.moe of every chunk dispatch since
+        #                         the engine last took them (harvest)
         if mesh is not None:
             want = _as_sharding(mesh)
             if self.sharding is None:
@@ -414,9 +419,12 @@ class _DecoderBackend:
             slot, pos, keys, eos, temp, aidx, _son = self._ring_dev(ring)
             ring = (self._ring_logits, self._ring_kc, self._ring_vc,
                     slot, pos, keys, eos, temp, aidx)
-        return self.dec._advance(
+        toks, st = self.dec._advance(
             self.dec._ring_chunk_decode if rung == "chunk"
             else self.dec._ring_chunk_step, st, steps, ring, **self._kw)
+        if st.moe is not None:
+            self.moe_counts.append(st.moe)
+        return toks, st
 
     def decode_chunk_spec(self, st, chunk_size, ring, K=None):
         """One chunked-speculative dispatch over the serving carry;
@@ -490,6 +498,8 @@ class _BundleBackend:
     StableHLO entries of a bundle exported with ``chunk_sizes=`` — the
     serving process runs no model Python (``decode_mode.chunked``)."""
 
+    has_windows = False    # (an exported program is full attention)
+    moe_counts = ()
     has_ring = False       # bundles carry no ring-staging entries: the
     #                        engine falls back to the host row-scatter
     spec_eng = None
@@ -872,6 +882,14 @@ class ServingEngine:
         self.batch_admission = bool(batch_admission)
         self.prefix_cache = self._resolve_prefix_cache(
             prefix_cache, prefix_cache_bytes, prefix_block_tokens)
+        if self._b.has_windows and self.prefix_cache is not None:
+            from paddle_tpu.inference.generate import WindowedModelError
+            raise WindowedModelError(
+                "the prefix cache loads a slab's rows at their positions "
+                "and prefills the suffix from there; a model with "
+                "windowed layers keeps a position at position % window "
+                "and prefills from 0 only — serve it with "
+                "prefix_cache=False")
         if self._spec_configured and self.prefix_cache is not None:
             raise ValueError(
                 "speculative serving does not compose with the prefix "
@@ -994,14 +1012,54 @@ class ServingEngine:
             "sum over the occupied rows of the row's cache position at "
             "the chunk's start, per chunk dispatch (the KV a chunk's "
             "attention must at least read)")
+        self._c_live_win = r.counter(
+            "serving.chunk.live_window_positions",
+            "sum over the occupied rows of min(the row's cache position, "
+            "the rolling buffers' length) at the chunk's start, per chunk "
+            "dispatch (what a windowed layer's attention reads of a row; "
+            "0 for a model with none)")
+        # what the routing did, from the vector the chunk program of a
+        # model with routed feed-forwards returns beside its tokens
+        self._c_moe_pairs = r.counter(
+            "serving.moe.pairs_held",
+            "token-expert pairs that landed on held experts, summed over "
+            "the chunks' steps and routed layers")
+        self._c_moe_touched = r.counter(
+            "serving.moe.experts_touched",
+            "held experts with at least one token, summed over the "
+            "chunks' steps and routed layers (the expert weights a step "
+            "has to read)")
+        self._g_moe_load = r.gauge(
+            "serving.moe.load_max",
+            "the largest count one held expert took in one step of the "
+            "last chunk")
         # the cache as built, from the carry's own buffers: a looped
-        # model holds its weight layers once per pass
+        # model holds its weight layers once per pass, a windowed layer
+        # a rolling buffer of its window (shorter than max_len or not)
         kc = self.state.kc
         self._cache_layers = (len(kc) if isinstance(kc, tuple)
                               else int(kc.shape[0]))
         self._cache_bytes_per_position = sum(
             x.nbytes for x in jax.tree_util.tree_leaves(
                 (kc, self.state.vc))) // (self.num_slots * self._b.max_len)
+        by_len: Dict[int, int] = {}     # buffer length -> K and V bytes
+        for x in jax.tree_util.tree_leaves((kc, self.state.vc)):
+            if x.ndim == 4:             # a layer's own (B, ...) buffer
+                n = int(x.shape[2 if self._b.head_major else 1])
+                by_len[n] = by_len.get(n, 0) + x.nbytes
+        full = max(by_len, default=self._b.max_len)
+        self._window_len = min(by_len, default=full)
+        self._cache_bytes_full = by_len.get(full, 0) // (
+            self.num_slots * full)
+        self._cache_bytes_window = sum(
+            b // (self.num_slots * n) for n, b in by_len.items() if n < full)
+        r.gauge("serving.cache.bytes_per_position.full",
+                "bytes of K and V one position holds over the cache "
+                "layers that keep all of max_len").set(self._cache_bytes_full)
+        r.gauge("serving.cache.bytes_per_position.window",
+                "bytes of K and V one position holds over the cache "
+                "layers that keep a rolling window shorter than max_len"
+                ).set(self._cache_bytes_window)
         r.gauge("serving.cache.layers",
                 "KV cache layers in the carry (weight layers x passes "
                 "over them)").set(self._cache_layers)
@@ -1475,6 +1533,9 @@ class ServingEngine:
         self._phase_to("dispatch")
         self._h_occ.observe(len(occupied) / self.num_slots)
         self._c_live_kv.inc(sum(slot.kv_pos for _, slot in occupied))
+        if self._cache_bytes_window:
+            self._c_live_win.inc(sum(min(slot.kv_pos, self._window_len)
+                                     for _, slot in occupied))
         toks = self._dispatch_chunk(occupied)
         nv = self._last_nv
         t_chunk_done = time.monotonic()
@@ -1483,8 +1544,15 @@ class ServingEngine:
         # logits. A numerically poisoned row (NaN/Inf) is frozen ALONE
         # and returned partial — one bad row must never take down the
         # whole batch or, worse, migrate its poison into a peer's carry
-        row_finite = np.isfinite(
-            np.asarray(jax.device_get(self.state.logits))).all(axis=-1)
+        logits_h, moe_h = jax.device_get((self.state.logits,
+                                          list(self._b.moe_counts)))
+        row_finite = np.isfinite(np.asarray(logits_h)).all(axis=-1)
+        for pairs, touched, load in moe_h:
+            self._c_moe_pairs.inc(int(pairs))
+            self._c_moe_touched.inc(int(touched))
+            self._g_moe_load.set(int(load))
+        if moe_h:
+            self._b.moe_counts.clear()
         sr = sa = None
         if self._spec_active and self.state.spec_rounds is not None:
             # mirror the carry's per-row cumulative acceptance stats
@@ -3289,7 +3357,13 @@ class ServingEngine:
         ``live_kv_positions_total`` is the cache
         positions live at the start of every chunk dispatched so far (token
         positions, whatever ``cache_layers`` each holds; a position's bytes
-        over all of them are ``cache_bytes_per_position``),
+        over all of them are ``cache_bytes_per_position``: the carry's
+        bytes over slots x max_len, an average where windowed layers keep
+        shorter buffers — ``cache_bytes_per_position_full`` /
+        ``_window`` are the two kinds' own, and
+        ``live_window_positions_total`` the positions live in the rolling
+        buffers), ``moe_*`` what the routing of a model with routed
+        feed-forwards did (``serving.moe.*``; zeros for any other),
         ``compiles`` this process's backend compiles by dispatch site."""
         qd, lat = self._h_qdelay, self._h_latency
         return {
@@ -3310,8 +3384,14 @@ class ServingEngine:
             "step_phase_s": {ph: {"sum": h.sum, "count": h.count}
                              for ph, h in self._h_phase.items()},
             "live_kv_positions_total": int(self._c_live_kv.value),
+            "live_window_positions_total": int(self._c_live_win.value),
             "cache_layers": self._cache_layers,
             "cache_bytes_per_position": self._cache_bytes_per_position,
+            "cache_bytes_per_position_full": self._cache_bytes_full,
+            "cache_bytes_per_position_window": self._cache_bytes_window,
+            "moe_pairs_held_total": int(self._c_moe_pairs.value),
+            "moe_experts_touched_total": int(self._c_moe_touched.value),
+            "moe_load_max": int(self._g_moe_load.value),
             "compiles": obs.compile_counts(),
             "queue_delay_mean_s": qd.mean,
             "queue_delay_p50_s": qd.percentile(50),

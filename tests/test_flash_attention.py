@@ -159,3 +159,63 @@ def test_single_block_kernel_matches_reference(causal, sq, sk):
     for gr, gk in zip(vjp_r(do), vjp_k(do)):
         np.testing.assert_allclose(np.asarray(gk), np.asarray(gr),
                                    rtol=2e-3, atol=2e-3)
+
+
+def _band_ref(q, k, v, window):
+    """Dense banded causal attention: key j visible to query i iff
+    0 <= (i + sk - sq) - j < window."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32)) / np.sqrt(d)
+    dist = (jnp.arange(sq)[:, None] + sk - sq) - jnp.arange(sk)[None, :]
+    s = jnp.where(jnp.logical_and(dist >= 0, dist < window), s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1),
+                      v.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("sq,sk,window,bq,bk", [
+    (256, 256, 64, 64, 64),     # the band spans whole blocks
+    (256, 256, 100, 64, 64),    # ... and cuts through them
+    (256, 256, 1, 64, 64),      # every query sees itself alone
+    (256, 256, 300, 64, 64),    # wider than the sequence: plain causal
+    (128, 256, 70, 64, 64),     # bottom-right aligned, sq < sk
+    (64, 64, 10, 64, 64),       # the single-block kernel
+    (256, 256, 96, 32, 128),    # q and k blocks of different sizes
+])
+def test_banded_forward_matches_a_dense_mask(sq, sk, window, bq, bk):
+    rng = np.random.default_rng(7)
+    q = jnp.asarray(rng.normal(size=(2, sq, 2, 32)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, sk, 2, 32)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, sk, 2, 32)), jnp.float32)
+    out = flash_attention_fn(q, k, v, causal=True, block_q=bq, block_k=bk,
+                             window=window)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_band_ref(q, k, v, window)),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_banded_backward_and_non_causal_window_refuse_typed():
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    x = jnp.ones((1, 128, 2, 32), jnp.float32)
+    with pytest.raises(fa.WindowBackwardError):
+        jax.grad(lambda q: flash_attention_fn(
+            q, x, x, causal=True, window=16).sum())(x)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention_fn(x, x, x, causal=False, window=16)
+    assert fa.supported(x.shape, x.shape, True, window=16)
+    assert not fa.supported(x.shape, x.shape, False, window=16)
+    assert not fa.supported(x.shape, x.shape, True, window=0)
+
+
+def test_without_a_window_the_lowered_text_has_no_band():
+    """The windowless call lowers exactly as before the window existed:
+    no clamp in the k/v index maps, no second comparison in the mask."""
+    x = jnp.ones((1, 256, 2, 32), jnp.float32)
+    plain = jax.jit(lambda q: flash_attention_fn(
+        q, x, x, causal=True, block_q=64, block_k=64)).lower(x).as_text()
+    band = jax.jit(lambda q: flash_attention_fn(
+        q, x, x, causal=True, block_q=64, block_k=64,
+        window=64)).lower(x).as_text()
+    assert plain != band
+    assert "clamp" not in plain

@@ -309,3 +309,25 @@ def test_looped_chunk_program_keeps_every_pass_cache_in_place(tpu):
     assert compiled.memory_analysis().temp_size_in_bytes < 33_554_432
     assert _whole_cache_writers(compiled.as_text(),
                                 {"8,1024,16,128"}) == []
+
+
+def test_banded_flash_forward_compiles_at_the_long_prefill_shape(tpu):
+    """The admission prefill of a windowed model at its longest bucket:
+    8192 positions, 48 heads of 128, a band of 4096 (clamped k/v index
+    maps, a second skip condition); and the routed feed-forward's two
+    grouped matmuls over one chip's 32 experts at 16 rows."""
+    from paddle_tpu.ops.moe import routed_ffn
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_fn
+
+    qkv = [_s(tpu, (1, 8192, 48, 128))] * 3
+    kernels = _kernels(lambda q, k, v: flash_attention_fn(
+        q, k, v, causal=True, window=4096), *qkv)
+    assert kernels == {"flash_fwd_band": 1}
+
+    def ffn(x, router, bias, gate_up, down):
+        return routed_ffn(x, router, bias, gate_up, down, top_k=4,
+                          route_norm=True, route_scale=2.448)[0]
+    compiled = jax.jit(ffn).lower(
+        _s(tpu, (16, 3072)), _s(tpu, (3072, 256)), _s(tpu, (256,)),
+        _s(tpu, (32, 3072, 6144)), _s(tpu, (32, 3072, 3072))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
