@@ -157,10 +157,12 @@ define_flag("decode_quant", "",
             "an equivalent switch")
 define_flag("decode_attention_interpret", False,
             "route eligible decode attention through the Pallas decode "
-            "kernel in INTERPRET mode when not on a TPU backend (off-TPU "
-            "the kernel is normally skipped for the faster XLA form); "
-            "the CPU-harness parity evidence for the kernel-routed "
-            "chunked decode path — never a production switch")
+            "kernel, and the decode step's per-row token-row write "
+            "through kv_row_write, in INTERPRET mode when not on a TPU "
+            "backend (off-TPU the kernels are normally skipped for the "
+            "faster XLA forms); the CPU-harness parity evidence for the "
+            "kernel-routed chunked decode path — never a production "
+            "switch")
 define_flag("decode_fallback", False,
             "serve LlamaDecoder.generate / nn.generation.generate_tokens "
             "through the per-token host loop (one dispatch + one host sync "

@@ -26,8 +26,9 @@ materialization). The Pallas flash kernel covers chunked prefill
 Positions may be a traced scalar (the classic lockstep decode) OR a
 per-row ``(B,)`` vector: speculative decoding accepts a variable number
 of draft tokens per row per round, so each row owns its cache write
-offset, causal mask bound, and rope phase (``_cache_update`` vmaps the
-dynamic-update-slice over the batch in that case).
+offset, causal mask bound, and rope phase (``_cache_write`` vmaps the
+dynamic-update-slice over the batch in that case; one token a row on one
+TPU is ``_cache_update``'s ``kv_row_write`` kernel call).
 """
 
 from __future__ import annotations
@@ -220,11 +221,51 @@ def _mm(x, p, name, sharded=False, aidx=None):
     return out
 
 
-def _cache_update(buf, t, pos, head_major, sharded=False):
+def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
+    """Write a layer's new keys ``kt`` and values ``vt`` into its K and V
+    cache buffers at [pos, pos+S) -> ``(kbuf, vbuf)``. One write, whose
+    form follows what the call can observe (an explicit predicate, no
+    fallback after an error): ONE token a row at per-row positions on one
+    device — the serving chunk's decode step — is one ``kv_row_write``
+    kernel call for both buffers (ops/pallas/kv_row_write.py), where the
+    kernel takes the buffers and the backend is a TPU (or
+    ``flags.decode_attention_interpret``, for the CPU tests); XLA's own
+    lowering of that write on the v5e is a serial loop over the rows,
+    2.8-3.7 us a row a buffer whatever the bytes, 3.5 ms of a 17.2 ms
+    step at 96 rows x 10 buffers where the kernel's 5 calls take 0.3
+    (PERF.md section 6, PR 34). Everything else — a scalar ``pos`` (prefills, the lockstep
+    step), ``S > 1`` at per-row positions (the speculative verify's
+    uneven advance), a mesh, a quantized cache's ``(..., 1)`` scale leaf
+    (its int8 leaf takes the kernel) — is ``_cache_write`` a buffer."""
+    from paddle_tpu.flags import flags as _flags
+    from paddle_tpu.ops.pallas import kv_row_write as _kw
+    from paddle_tpu.quantization.kv_cache import (is_quantized_kv,
+                                                  quantize_kv_rows)
+    rows = (jnp.ndim(pos) == 1 and not sharded
+            and (jax.default_backend() == "tpu"
+                 or _flags.decode_attention_interpret))
+    if rows and is_quantized_kv(kbuf):
+        qk, qv = quantize_kv_rows(kt), quantize_kv_rows(vt)
+        if _kw.supported(kbuf["q"], qk["q"], head_major):
+            kq, vq = _kw.kv_row_write(kbuf["q"], vbuf["q"], qk["q"],
+                                      qv["q"], pos, head_major=head_major)
+            return ({"q": kq, "s": _cache_write(kbuf["s"], qk["s"], pos,
+                                                head_major)},
+                    {"q": vq, "s": _cache_write(vbuf["s"], qv["s"], pos,
+                                                head_major)})
+    elif rows and _kw.supported(kbuf, kt, head_major):
+        return _kw.kv_row_write(kbuf, vbuf, kt, vt, pos,
+                                head_major=head_major)
+    return (_cache_write(kbuf, kt, pos, head_major, sharded),
+            _cache_write(vbuf, vt, pos, head_major, sharded))
+
+
+def _cache_write(buf, t, pos, head_major, sharded=False):
     """Write t into ONE layer's cache buffer at [pos, pos+S). Scalar pos:
     a single dynamic-update-slice. Per-row (B,) pos: the same DUS vmapped
-    over the batch (lowers to scatter — each row lands at its own
-    offset, the speculative-decode requirement). A quantized buffer
+    over the batch (ONE scatter with sorted, unique indices, which the
+    v5e compiler expands into a loop over the rows — each row lands at
+    its own offset, the speculative-decode requirement). A quantized buffer
     (``int8wk``) quantizes the incoming rows by per-row absmax and
     updates the int8 and scale leaves with the SAME index math (the
     scale keeps a last dim of 1, so ranks line up).
@@ -241,10 +282,10 @@ def _cache_update(buf, t, pos, head_major, sharded=False):
                                                   quantize_kv_rows)
     if is_quantized_kv(buf):
         qt = quantize_kv_rows(t)
-        return {"q": _cache_update(buf["q"], qt["q"], pos, head_major,
-                                   sharded),
-                "s": _cache_update(buf["s"], qt["s"], pos, head_major,
-                                   sharded)}
+        return {"q": _cache_write(buf["q"], qt["q"], pos, head_major,
+                                  sharded),
+                "s": _cache_write(buf["s"], qt["s"], pos, head_major,
+                                  sharded)}
     if jnp.ndim(pos) == 1:
         if head_major:     # buf (B, KV, L, D), t (B, KV, S, D)
             f = lambda c, u, p0: jax.lax.dynamic_update_slice(  # noqa: E731
@@ -424,8 +465,8 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
         at = 0
     else:
         at = pos % L if rolling else pos
-    kc_l = _cache_update(kc[ci], kt, at, head_major, sharded)
-    vc_l = _cache_update(vc[ci], vt, at, head_major, sharded)
+    kc_l, vc_l = _cache_update(kc[ci], vc[ci], kt, vt, at, head_major,
+                               sharded)
     kc = kc[:ci] + (kc_l,) + kc[ci + 1:]
     vc = vc[:ci] + (vc_l,) + vc[ci + 1:]
 

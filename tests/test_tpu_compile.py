@@ -113,6 +113,42 @@ def test_decode_attention_compiles(tpu, shape, kv_dtype):
     assert kernels == {"decode_attention": 1}
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("shape,head_major", [
+    ((16, 8, 2048, 128), True),     # mistral7b.serve.*
+    ((96, 8, 1024, 128), True),     # trinity.serve.reason-backlog
+    ((8, 2048, 32, 128), False),    # deepseek7b.serve.backlog
+    ((8, 1024, 16, 128), False),    # ouro2.6b.serve.reason-backlog
+])
+def test_kv_row_write_compiles_at_the_cells_cache_shapes(tpu, shape,
+                                                         head_major, dtype):
+    """One call writes a layer's K row and V row for every sequence, in
+    place: both caches come back aliased (given up by the caller, they
+    are the outputs), and nothing of a cache's size is held beside them."""
+    from paddle_tpu.obs.cost import program_census
+    from paddle_tpu.ops.pallas.kv_row_write import kv_row_write, supported
+
+    B = shape[0]
+    rows = ((B, shape[1], 1, 128) if head_major
+            else (B, 1, shape[2], 128))
+    kc, k = _s(tpu, shape, dtype), _s(tpu, rows, dtype)
+    assert supported(kc, k, head_major)
+    compiled = jax.jit(
+        lambda kc, vc, k, v, at: kv_row_write(kc, vc, k, v, at,
+                                              head_major=head_major),
+        donate_argnums=(0, 1)).lower(
+            kc, kc, k, k, _s(tpu, (B,), jnp.int32)).compile()
+    assert program_census(compiled)["kernels"] == {"kv_row_write": 1}
+    mem = compiled.memory_analysis()
+    cache = 2 * jnp.dtype(dtype).itemsize * B * shape[1] * shape[2] * 128
+    assert mem.alias_size_in_bytes == cache
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert _whole_cache_writers(
+        compiled.as_text(), {",".join(map(str, shape))},
+        entry=True) == []
+
+
 def test_int8_matmul_compiles(tpu):
     from paddle_tpu.ops.pallas.int8_matmul import int8_matmul
 
@@ -155,24 +191,40 @@ def test_kernels_give_way_where_gspmd_would_split_them(monkeypatch):
     assert seen == [False]
 
 
-def _whole_cache_writers(hlo_text, shapes):
-    """Instructions outside the entry computation and outside fusions'
-    bodies whose output has one of ``shapes`` (dims as ``"16,8,2048,128"``)
-    and is materialised: everything but parameters, tuple plumbing,
-    bitcasts and the in-place row update (a ``dynamic-update-slice``, bare
-    or as the root of a fusion, whose update operand is smaller than the
-    smallest of ``shapes``, one layer)."""
+def _whole_cache_writers(hlo_text, shapes, entry=False):
+    """Instructions outside the entry computation (inside it too with
+    ``entry``) and outside fusions' bodies whose output has one of
+    ``shapes`` (dims as ``"16,8,2048,128"``) and is materialised:
+    everything but parameters, tuple plumbing, bitcasts and the in-place
+    row update — a ``dynamic-update-slice``, bare or as the root of a
+    fusion, whose update operand is smaller than the smallest of
+    ``shapes``, one layer; or an output of a kernel's custom call that
+    ``output_to_operand_aliasing`` says IS one of its operands (a kernel
+    output of a cache's shape that is not aliased is a second cache)."""
     import re
     instr = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
                        r"([\w\-]+)\((.*)")
-    comps, fused, cur = {}, set(), None
+    kernel = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = \((.*?)\) "
+                        r"custom-call\((.*)")
+    comps, fused, cur, found = {}, set(), None, []
     for line in hlo_text.splitlines():
         head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
         if head:
             cur = comps.setdefault(head.group(2), {})
-            if head.group(1):
+            if head.group(1) and not entry:
                 comps.pop(head.group(2))     # the entry's copies are not
                 cur = {}                     # the loop's (carry not donated)
+            continue
+        m = kernel.match(line)
+        if m and cur is not None:
+            name, outs, rest = m.groups()
+            aliased = re.search(r"output_to_operand_aliasing=\{(.*?)\}, \w+=",
+                                rest)
+            for i, (_, dims) in enumerate(
+                    re.findall(r"(\w+)\[([\d,]*)\]", outs)):
+                if dims in shapes and not (
+                        aliased and f"{{{i}}}: (" in aliased.group(1)):
+                    found.append(f"{name}: output {i} [{dims}] not aliased")
             continue
         m = instr.match(line)
         if m and cur is not None:
@@ -199,7 +251,6 @@ def _whole_cache_writers(hlo_text, shapes):
         return (op == "dynamic-update-slice"
                 and elems(comp[operands[1]][0]) < min(map(elems, shapes)))
 
-    found = []
     for cname, comp in comps.items():
         if cname in fused:
             continue
@@ -211,8 +262,44 @@ def _whole_cache_writers(hlo_text, shapes):
     return found
 
 
+def _loops_beside(hlo_text, kernel):
+    """``while`` instructions in the computation that holds the
+    ``kernel`` custom calls and in everything it calls; that computation
+    has to be the body of the chunk program's step loop. (XLA's own
+    lowering of the per-row write is a ``while`` over the batch, a
+    buffer: 2 x layers of them a step.)"""
+    import re
+    comps, cur = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    holds = [c for c, lines in comps.items()
+             if any(f"%{kernel}." in ln and "tpu_custom_call" in ln
+                    for ln in lines)]
+    assert len(holds) == 1, holds
+    loop = [ln for lines in comps.values() for ln in lines
+            if f"body=%{holds[0]}," in ln or f"body=%{holds[0]} " in ln]
+    assert len(loop) == 1 and \
+        'op_name="jit(ring_chunk_decode)/while"' in loop[0], loop
+    seen, todo, whiles = set(), [holds[0]], []
+    while todo:
+        c = todo.pop()
+        if c in seen:
+            continue
+        seen.add(c)
+        for ln in comps[c]:
+            if re.search(r"\bwhile\(", ln):
+                whiles.append(f"{c}: {ln.strip()[:120]}")
+            todo += [n for n in re.findall(r"%([\w.\-]+)", ln)
+                     if n in comps]
+    return whiles
+
+
 @pytest.mark.parametrize("kv_heads,slots,layer_shape", [
-    (8, 16, "16,8,2048,128"),     # GQA, head-major, decode_attention kernel
+    (8, 16, "16,8,2048,128"),     # GQA, head-major, both kernels
     (32, 8, "8,2048,32,128"),     # MHA, token-major, XLA attention
 ])
 def test_ring_chunk_program_updates_the_kv_carry_in_place(
@@ -221,16 +308,18 @@ def test_ring_chunk_program_updates_the_kv_carry_in_place(
     heads of 128, ``max_len`` 2048; FFN and vocabulary small) holds no
     copy of a layer's KV buffer: its temporaries stay under one layer's K
     buffer, and inside the 16-step loop nothing outputs a whole layer but
-    the in-place token-row update. (With the carry stacked over layers
-    the same program held a slice of each layer out of the stack and a
-    write of it back, every layer of every step: temporaries of 271 MB
-    and 543 MB.)"""
+    the in-place token-row update: one ``kv_row_write`` call a cache
+    layer, both of its outputs aliased to its cache operands, and no loop
+    over the batch beside it. (With the carry stacked over layers the
+    same program held a slice of each layer out of the stack and a write
+    of it back, every layer of every step: temporaries of 271 MB and
+    543 MB.)"""
     from paddle_tpu.flags import flags
     from paddle_tpu.inference.generate import LlamaDecoder
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.obs.cost import program_census
 
-    # the routing asks for a TPU backend or this flag; the kernel is then
+    # the routing asks for a TPU backend or this flag; the kernels are then
     # compiled, not interpreted (compiled_not_interpreted)
     monkeypatch.setattr(flags, "decode_attention_interpret", True)
     layers, vocab, max_len, steps = 2, 512, 2048, 16
@@ -261,25 +350,31 @@ def test_ring_chunk_program_updates_the_kv_carry_in_place(
         steps=steps, do_sample=False, top_k=None, top_p=None).compile()
 
     kernels = program_census(compiled)["kernels"]
-    assert kernels == ({"decode_attention": layers} if kv_heads == 8
-                       else {})
+    assert kernels == ({"decode_attention": layers, "kv_row_write": layers}
+                       if kv_heads == 8 else {"kv_row_write": layers})
     # one layer's K buffer in the GQA case, half of one in the MHA case
     assert compiled.memory_analysis().temp_size_in_bytes < 67_108_864
     stack_shape = f"{layers},{layer_shape}"
     assert _whole_cache_writers(compiled.as_text(),
                                 {layer_shape, stack_shape}) == []
+    assert _loops_beside(compiled.as_text(), "kv_row_write") == []
 
 
-def test_looped_chunk_program_keeps_every_pass_cache_in_place(tpu):
+def test_looped_chunk_program_keeps_every_pass_cache_in_place(
+        tpu, monkeypatch):
     """A looped model's chunk program (models/ouro.py: 2 weight layers run
     3 times, so 6 cache layers; Ouro-2.6B's attention widths, small FFN and
     vocabulary) writes each pass's token rows into that pass's own buffer
     in place: the passes are unrolled at trace time, so no cache buffer is
     indexed by a traced pass number and none is copied whole inside the
-    step loop (PERF.md section 6, PR 28)."""
+    step loop (PERF.md section 6, PR 28); one ``kv_row_write`` call a
+    cache layer."""
+    from paddle_tpu.flags import flags
     from paddle_tpu.inference.generate import LlamaDecoder
     from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+    from paddle_tpu.obs.cost import program_census
 
+    monkeypatch.setattr(flags, "decode_attention_interpret", True)
     layers, passes, vocab, max_len, slots, steps = 2, 3, 512, 1024, 8, 16
     model = OuroForCausalLM(OuroConfig(
         vocab_size=vocab, hidden_size=2048, intermediate_size=256,
@@ -305,10 +400,13 @@ def test_looped_chunk_program_keeps_every_pass_cache_in_place(tpu):
         rows_i32, rows_f32, None,
         logits, kc, vc, rows_i32, rows_i32, keys, rows_i32, rows_f32, None,
         steps=steps, do_sample=False, top_k=None, top_p=None).compile()
+    assert program_census(compiled)["kernels"] == {
+        "kv_row_write": layers * passes}
     # under one cache buffer (33.5 MB): nothing holds a copy of one
     assert compiled.memory_analysis().temp_size_in_bytes < 33_554_432
     assert _whole_cache_writers(compiled.as_text(),
                                 {"8,1024,16,128"}) == []
+    assert _loops_beside(compiled.as_text(), "kv_row_write") == []
 
 
 def test_banded_flash_forward_compiles_at_the_long_prefill_shape(tpu):
