@@ -195,8 +195,15 @@ def export_decoder_bundle(decoder, out_dir: str,
     import jax
     import jax.numpy as jnp
 
-    os.makedirs(out_dir, exist_ok=True)
     cfg = decoder.cfg
+    if cfg.has_windows:
+        from paddle_tpu.inference.generate import WindowedModelError
+        raise WindowedModelError(
+            "a decoder bundle records ONE cache buffer shape for all "
+            "layers and its serving process rebuilds the carry from it; a "
+            "model with windowed layers holds buffers of several lengths "
+            "or kinds: serve it in process")
+    os.makedirs(out_dir, exist_ok=True)
     p = decoder.params
     # a mesh-built decoder exports PARTITIONED entries: the example args
     # below are committed to their carry placements so jax.export bakes

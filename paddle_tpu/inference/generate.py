@@ -221,6 +221,22 @@ def _mm(x, p, name, sharded=False, aidx=None):
     return out
 
 
+def _decode_kernels(pos, sharded) -> bool:
+    """Whether a decode step's attention over the cache may go through the
+    Pallas kernels (``decode_attention``, ``decode_attention_pair``,
+    ``eva_chunk_pool``), as far as the call itself says — each kernel's
+    ``supported`` then answers for the shapes: ``pos`` a scalar or one
+    position a row, one device (a mesh takes the XLA forms, which shard by
+    propagation), ``flags.use_decode_attention``, and a TPU backend (or
+    ``flags.decode_attention_interpret``, for the CPU tests). One
+    predicate for ``_kv_attention`` and ``_eva_attention``."""
+    from paddle_tpu.flags import flags as _flags
+    return bool(jnp.ndim(pos) <= 1 and not sharded
+                and _flags.use_decode_attention
+                and (jax.default_backend() == "tpu"
+                     or _flags.decode_attention_interpret))
+
+
 def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
     """Write a layer's new keys ``kt`` and values ``vt`` into its K and V
     cache buffers at [pos, pos+S) -> ``(kbuf, vbuf)``. One write, whose
@@ -333,6 +349,13 @@ def _rms(x, w, eps):
             ).astype(x.dtype) * w
 
 
+def _stream_read(h, cfg):
+    """The residual stream as a sub-layer's norm reads it: a float32
+    stream (``cfg.fp32_skip_add``, models/evabyte.py) in the compute
+    dtype, any other as it is."""
+    return h.astype(jnp.dtype(cfg.dtype)) if cfg.fp32_skip_add else h
+
+
 def _prefill_rows(t, true_len, L: int, head_major: bool):
     """The rows a prefill from position 0 leaves in a rolling buffer of
     ``L`` positions, out of its ``S > L`` fresh rows ``t``: slot ``s``
@@ -376,80 +399,24 @@ def _fresh_attention(q, k, v, window, sharded):
     return jnp.einsum("bhqk,bkhd->bqhd", attn, v).reshape(B, S, H * D)
 
 
-def _moe_reduce(c):
-    """(n, 3) routing counts -> (3,): pairs on held experts and held
-    experts touched add up, the largest count one expert took is a
-    maximum."""
-    return jnp.concatenate([jnp.sum(c[:, :2], 0), jnp.max(c[:, 2:], 0)])
-
-
-def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
-                   max_len, sharded=False, aidx=None, true_len=None,
-                   live=None, moe_stats=None):
-    """One decoder block over h (B, S, H), weights of layer ``li``,
-    writing K/V into CACHE layer ``ci`` at [pos, pos+S); attention reads
-    that whole buffer masked to < pos+S with causal alignment to the
-    bottom-right (query i attends to <= pos+i). ``ci`` is ``li`` for a
-    model that makes one pass over its layers; a looped model's pass
-    ``t`` keeps its own keys and values in ``t * num_hidden_layers +
-    li``. Both are trace-time integers. Where the parameters hold
-    ``input_layernorm_2`` / ``post_attention_layernorm_2`` (a sandwich
-    block, models/ouro.py) each sub-layer's output is normed before it
-    joins the residual stream.
-    ``pos``: scalar or per-row (B,) vector. ``sharded`` (trace-time
-    static): the decoder runs under a GSPMD mesh — hand-written Pallas
-    kernels (no partitioning rules) give way to the XLA forms, which
-    shard via sharding propagation. ``aidx`` (B,) i32 routes per-row LoRA
-    deltas through every projection (see ``_mm``).
-
-    What the layer is, the config says at trace time and the parameters
-    by their presence. ``cfg.layer_rope(li)``: whether queries and keys
-    are rotated. ``cfg.layer_window(li)``: a windowed layer's cache
-    buffer is ROLLING — ``cfg.cache_len(ci, max_len)`` positions, a
-    position kept at ``pos % length``; keys are rotated before they are
-    cached and softmax does not care for order, so a decode step attends
-    over the buffer's first ``min(pos + 1, length)`` rows as it does over
-    a plain one. In a model with any windowed layer (``cfg.has_windows``)
-    ``S > 1`` is a prefill FROM POSITION 0: every layer attends over its
-    own fresh keys (``_fresh_attention``) and writes the last
-    ``min(true_len, length)`` of them (``true_len`` (B,), None = S).
-    ``self_attn.q_norm`` / ``k_norm``: per-head RMSNorm on q and k. A
-    fourth column block of the fused q|k|v matrix: an output gate,
-    ``(o * sigmoid(x Wg)) Wo``. ``mlp.router``: a routed feed-forward
-    over this chip's held experts plus a shared expert
-    (``ops/moe.py:routed_ffn``), ``live`` (B, S) bool the rows that reach
-    an expert, its three counts and the chosen experts appended to
-    ``moe_stats``."""
-    B, S, _ = h.shape
-    H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    pre = f"model.layers.{li}."
-    eps = cfg.rms_norm_eps
-
-    x = _rms(h, p[pre + "input_layernorm.weight"], eps)
-    qkv = _mm(x, p, pre + "self_attn.qkv.weight", sharded, aidx)
-    q = qkv[..., :H * D].reshape(B, S, H, D)
-    k = qkv[..., H * D:H * D + KV * D].reshape(B, S, KV, D)
-    gate = None
-    if qkv.shape[-1] > (H + 2 * KV) * D:
-        v = qkv[..., (H + KV) * D:(H + 2 * KV) * D].reshape(B, S, KV, D)
-        gate = qkv[..., (H + 2 * KV) * D:]
-    else:
-        v = qkv[..., H * D + KV * D:].reshape(B, S, KV, D)
-    qn = p.get(pre + "self_attn.q_norm.weight")
-    if qn is not None:
-        q = _rms(q, qn, eps)
-        k = _rms(k, p[pre + "self_attn.k_norm.weight"], eps)
-    if cfg.layer_rope(li):
-        q = _rope_at(q, pos, cfg, p)
-        k = _rope_at(k, pos, cfg, p)
+def _kv_attention(cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
+                  sharded, true_len):
+    """Attention of layer ``li`` through cache layer ``ci``'s one buffer
+    of keys and one of values by position: write the fresh rows, attend
+    over the buffer (``_block_forward`` says how, by the layer's kind).
+    q (B, S, H, D), k, v (B, S, KV, D), rotated -> (out (B, S, H * D),
+    kc, vc)."""
+    B, S, H, D = q.shape
+    KV = k.shape[2]
     rolling = cfg.has_windows
     fresh = rolling and S > 1
     L = cfg.cache_len(ci, max_len)
 
     rep = H // KV
-    head_major = rep > 1   # GQA: (B, KV, L, D) tiles feed the Pallas
-    #                        kernel; MHA keeps token-major (B, L, KV, D),
-    #                        which XLA's fused matvec prefers (measured)
+    # GQA: (B, KV, L, D) tiles feed the Pallas kernel; MHA keeps
+    # token-major (B, L, KV, D), unless the config's attention goes
+    # through the kernel at rep = 1 too: one predicate, the config's
+    head_major = cfg.cache_head_major
     kt = jnp.swapaxes(k, 1, 2) if head_major else k
     vt = jnp.swapaxes(v, 1, 2) if head_major else v
     # each CACHE layer owns its buffer: the token rows are written into
@@ -470,16 +437,11 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     kc = kc[:ci] + (kc_l,) + kc[ci + 1:]
     vc = vc[:ci] + (vc_l,) + vc[ci + 1:]
 
-    from paddle_tpu.flags import flags as _flags
     from paddle_tpu.ops.pallas import decode_attention as _da
     from paddle_tpu.quantization.kv_cache import (dequantize_kv,
                                                   is_quantized_kv)
     quant_kv = is_quantized_kv(kc_l)
-    use_kernel = (head_major and S == 1 and jnp.ndim(pos) <= 1
-                  and not sharded
-                  and _flags.use_decode_attention
-                  and (jax.default_backend() == "tpu"
-                       or _flags.decode_attention_interpret)
+    use_kernel = (head_major and S == 1 and _decode_kernels(pos, sharded)
                   and _da.supported(q[:, 0],
                                     kc_l["q"] if quant_kv else kc_l))
     # per-row qpos: scalar pos broadcasts as (1,1,S,1), vector as (B,1,S,1)
@@ -535,13 +497,214 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
         scores = jnp.where(mask, scores.astype(jnp.float32), -jnp.inf)
         attn = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
         out = jnp.einsum("bhqk,bkhd->bqhd", attn, vv).reshape(B, S, H * D)
+    return out, kc, vc
+
+
+def _eva_attention(p, cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
+                   sharded, true_len):
+    """EVA attention (ops/eva.py) of layer ``li`` through cache layer
+    ``ci``'s TWO leaves, both head-major: buffer ``2 * ci`` the WINDOW
+    leaf, ``W = cfg.cache_len(2 * ci, max_len)`` exact positions, and
+    buffer ``2 * ci + 1`` the SUMMARY leaf, one entry a chunk of ``C``
+    positions. q, k, v (B, S, H, D), rotated. -> (out (B, S, H * D), kc,
+    vc).
+
+    A decode step (``S == 1``; ``pos`` a scalar or per row) follows one
+    rule, from ``pos`` alone: write the token's row at ``pos % W`` — the
+    window leaf is RESET at a window's end, not rolled —, pool the chunk
+    the row lies in from the window leaf into summary ``pos // C`` (an
+    incomplete chunk's entry is written too and written over by every
+    later row of the chunk; no query sees a chunk of its own window, so
+    only the complete one is ever read: at ``pos % C == C - 1``), and
+    attend in one softmax over ``pos % W + 1`` window rows and ``(pos //
+    W) * (W / C)`` summaries: through ``decode_attention_pair`` where the
+    routing takes it (``_decode_kernels``, the one-leaf kernel's
+    predicate too), else XLA's masked form. No branch on a row's position.
+
+    ``S > 1`` is a prefill from position 0 (``cfg.has_windows``): causal
+    attention inside each aligned window and attention over the summaries
+    of the windows before under one softmax, through the
+    ``eva_prefill_attention`` kernel (ops/pallas/eva_attention.py), or
+    ``ops/eva.py``'s masked form where the kernel does not take the
+    shape or the program runs under a mesh. It FILLS both
+    leaves: every chunk's summary, and the rows of the window that holds
+    position ``true_len - 1`` ((B,), None = S)."""
+    from paddle_tpu.ops import eva as _eva
+    from paddle_tpu.ops.pallas import decode_attention as _da
+    from paddle_tpu.ops.pallas import eva_attention as _ea
+    B, S, H, D = q.shape
+    C = cfg.chunk_size
+    W = cfg.cache_len(2 * ci, max_len)
+    pre = f"model.layers.{li}.self_attn."
+    mu, phi = p[pre + "adaptive_mu_k"], p[pre + "adaptive_phi"]
+    wi, si = 2 * ci, 2 * ci + 1
+
+    def put(kc, vc, bi, kb, vb):
+        return (kc[:bi] + (kb,) + kc[bi + 1:],
+                vc[:bi] + (vb,) + vc[bi + 1:])
+
+    if S > 1:
+        # head-major from here on: the layout of the kernel and of both
+        # leaves, so each of q, k, v is transposed once
+        qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
+        Sw = -(-S // W) * W                 # whole windows, zeros after S
+        pad = ((0, 0), (0, 0), (0, Sw - S), (0, 0))
+        # (the barrier: the pooling and the kernel read ONE head-major
+        # copy of k and of v; without it the compiler also wrote a float32
+        # one for the pooling, 537 MB each at 32 x 32768 x 128)
+        kp, vp = jax.lax.optimization_barrier(
+            (jnp.pad(kt, pad), jnp.pad(vt, pad)))
+        ks, vs = _eva.chunk_summaries(kp, vp, mu, phi, C)
+        if not sharded and _ea.supported(Sw, W, W // C):
+            out = _ea.eva_prefill_attention(
+                *(x.reshape(B * H, -1, D)
+                  for x in (jnp.pad(qt, pad), kp, vp, ks, vs)),
+                window=W, per=W // C)
+            out = jnp.swapaxes(out.reshape(B, H, Sw, D)[:, :, :S], 1, 2)
+        else:
+            out = _eva.eva_attention(q, k, v, ks, vs, W, C)
+        # the window leaf: the rows of the window position true_len - 1
+        # lies in (slots past it hold later padding or an earlier
+        # window's rows, masked until decode writes them)
+        if S > W:
+            n = (jnp.full((B,), S, jnp.int32) if true_len is None
+                 else true_len)[:, None]
+            idx = jnp.clip((n - 1) // W * W + jnp.arange(W)[None, :], 0,
+                           S - 1)[:, None, :, None]
+            kt = jnp.take_along_axis(kt, idx, axis=2)
+            vt = jnp.take_along_axis(vt, idx, axis=2)
+        kc, vc = put(kc, vc, wi, *_cache_update(kc[wi], vc[wi], kt, vt, 0,
+                                               True, sharded))
+        kc, vc = put(kc, vc, si, *_cache_update(kc[si], vc[si], ks, vs, 0,
+                                               True, sharded))
+        return out.reshape(B, S, H * D), kc, vc
+
+    at = pos % W
+    kw, vw = _cache_update(kc[wi], vc[wi], jnp.swapaxes(k, 1, 2),
+                           jnp.swapaxes(v, 1, 2), at, True, sharded)
+    kc, vc = put(kc, vc, wi, kw, vw)
+    # the chunk the new row lies in, whole, from the window leaf: through
+    # the pooling kernel where the decode kernel below runs (XLA's gather
+    # of a row's chunk wants the leaf transposed and copies it whole,
+    # every layer of every step: PERF.md section 6, PR 36), else gathered
+    start = at // C * C
+    kernels = _decode_kernels(pos, sharded)
+    if kernels and _ea.pool_supported(kw, C):
+        ks, vs = _ea.eva_chunk_pool(kw, vw, mu, phi, start, chunk=C)
+    else:
+        if jnp.ndim(pos) == 1:
+            cut = jax.vmap(lambda c, s0: jax.lax.dynamic_slice(
+                c, (0, s0, 0), (H, C, D)))
+            kch, vch = cut(kw, start), cut(vw, start)
+        else:
+            kch = jax.lax.dynamic_slice(kw, (0, 0, start, 0), (B, H, C, D))
+            vch = jax.lax.dynamic_slice(vw, (0, 0, start, 0), (B, H, C, D))
+        ks, vs = _eva.chunk_summaries(kch, vch, mu, phi, C)
+    ksb, vsb = _cache_update(kc[si], vc[si], ks, vs, pos // C, True, sharded)
+    kc, vc = put(kc, vc, si, ksb, vsb)
+    n_win, n_sum = at + 1, pos // W * (W // C)
+    if kernels and _da.supported_pair(q[:, 0], kw, ksb):
+        out = _da.decode_attention_pair(q[:, 0], kw, vw, n_win, ksb, vsb,
+                                        n_sum)
+        return out.reshape(B, S, H * D), kc, vc
+    sc = jnp.float32(D) ** -0.5
+
+    def scores(kb, n):
+        s_ = jnp.einsum("bhd,bhld->bhl", q[:, 0], kb).astype(jnp.float32)
+        live = jnp.arange(kb.shape[2])[None, :] < jnp.reshape(n, (-1, 1))
+        return jnp.where(live[:, None, :], s_ * sc, -jnp.inf)
+    pr = jax.nn.softmax(jnp.concatenate(
+        [scores(kw, n_win), scores(ksb, n_sum)], axis=-1), axis=-1
+    ).astype(q.dtype)
+    out = (jnp.einsum("bhl,bhld->bhd", pr[..., :kw.shape[2]], vw)
+           + jnp.einsum("bhl,bhld->bhd", pr[..., kw.shape[2]:], vsb))
+    return out.reshape(B, S, H * D), kc, vc
+
+
+def _moe_reduce(c):
+    """(n, 3) routing counts -> (3,): pairs on held experts and held
+    experts touched add up, the largest count one expert took is a
+    maximum."""
+    return jnp.concatenate([jnp.sum(c[:, :2], 0), jnp.max(c[:, 2:], 0)])
+
+
+def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
+                   max_len, sharded=False, aidx=None, true_len=None,
+                   live=None, moe_stats=None):
+    """One decoder block over h (B, S, H), weights of layer ``li``,
+    writing K/V into CACHE layer ``ci`` at [pos, pos+S); attention reads
+    that whole buffer masked to < pos+S with causal alignment to the
+    bottom-right (query i attends to <= pos+i). ``ci`` is ``li`` for a
+    model that makes one pass over its layers; a looped model's pass
+    ``t`` keeps its own keys and values in ``t * num_hidden_layers +
+    li``. Both are trace-time integers. Where the parameters hold
+    ``input_layernorm_2`` / ``post_attention_layernorm_2`` (a sandwich
+    block, models/ouro.py) each sub-layer's output is normed before it
+    joins the residual stream.
+    ``pos``: scalar or per-row (B,) vector. ``sharded`` (trace-time
+    static): the decoder runs under a GSPMD mesh — hand-written Pallas
+    kernels (no partitioning rules) give way to the XLA forms, which
+    shard via sharding propagation. ``aidx`` (B,) i32 routes per-row LoRA
+    deltas through every projection (see ``_mm``).
+
+    What the layer is, the config says at trace time and the parameters
+    by their presence. ``cfg.layer_rope(li)``: whether queries and keys
+    are rotated. ``cfg.layer_window(li)``: a windowed layer's cache
+    buffer is ROLLING — ``cfg.cache_len(ci, max_len)`` positions, a
+    position kept at ``pos % length``; keys are rotated before they are
+    cached and softmax does not care for order, so a decode step attends
+    over the buffer's first ``min(pos + 1, length)`` rows as it does over
+    a plain one. In a model with any windowed layer (``cfg.has_windows``)
+    ``S > 1`` is a prefill FROM POSITION 0: every layer attends over its
+    own fresh keys (``_fresh_attention``) and writes the last
+    ``min(true_len, length)`` of them (``true_len`` (B,), None = S).
+    ``cfg.eva``: the layer's attention is EVA over a window leaf and a
+    summary leaf (``_eva_attention``), buffers ``2 * ci`` and ``2 * ci +
+    1`` of the carry. ``cfg.fp32_skip_add``: ``h`` is
+    float32 and each sub-layer reads it in the compute dtype.
+    ``self_attn.q_norm`` / ``k_norm``: per-head RMSNorm on q and k. A
+    fourth column block of the fused q|k|v matrix: an output gate,
+    ``(o * sigmoid(x Wg)) Wo``. ``mlp.router``: a routed feed-forward
+    over this chip's held experts plus a shared expert
+    (``ops/moe.py:routed_ffn``), ``live`` (B, S) bool the rows that reach
+    an expert, its three counts and the chosen experts appended to
+    ``moe_stats``."""
+    B, S, _ = h.shape
+    H, KV, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    pre = f"model.layers.{li}."
+    eps = cfg.rms_norm_eps
+
+    x = _rms(_stream_read(h, cfg), p[pre + "input_layernorm.weight"], eps)
+    qkv = _mm(x, p, pre + "self_attn.qkv.weight", sharded, aidx)
+    q = qkv[..., :H * D].reshape(B, S, H, D)
+    k = qkv[..., H * D:H * D + KV * D].reshape(B, S, KV, D)
+    gate = None
+    if qkv.shape[-1] > (H + 2 * KV) * D:
+        v = qkv[..., (H + KV) * D:(H + 2 * KV) * D].reshape(B, S, KV, D)
+        gate = qkv[..., (H + 2 * KV) * D:]
+    else:
+        v = qkv[..., H * D + KV * D:].reshape(B, S, KV, D)
+    qn = p.get(pre + "self_attn.q_norm.weight")
+    if qn is not None:
+        q = _rms(q, qn, eps)
+        k = _rms(k, p[pre + "self_attn.k_norm.weight"], eps)
+    if cfg.layer_rope(li):
+        q = _rope_at(q, pos, cfg, p)
+        k = _rope_at(k, pos, cfg, p)
+    if cfg.eva:
+        out, kc, vc = _eva_attention(p, cfg, li, ci, q, k, v, kc, vc, pos,
+                                     max_len, sharded, true_len)
+    else:
+        out, kc, vc = _kv_attention(cfg, li, ci, q, k, v, kc, vc, pos,
+                                    max_len, sharded, true_len)
     if gate is not None:
         out = out * jax.nn.sigmoid(gate)
     att = _mm(out, p, pre + "self_attn.o_proj.weight", sharded, aidx)
     w2 = p.get(pre + "input_layernorm_2.weight")
     h = h + (att if w2 is None else _rms(att, w2, eps))
 
-    x = _rms(h, p[pre + "post_attention_layernorm.weight"], eps)
+    x = _rms(_stream_read(h, cfg),
+             p[pre + "post_attention_layernorm.weight"], eps)
     router = p.get(pre + "mlp.router.weight")
     ffn = pre + ("mlp." if router is None else "mlp.shared_experts.")
     gu = _mm(x, p, ffn + "gate_up.weight", sharded, aidx)
@@ -560,7 +723,14 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
         if moe_stats is not None:
             moe_stats.append((counts, chosen))
     w2 = p.get(pre + "post_attention_layernorm_2.weight")
-    return h + (mlp if w2 is None else _rms(mlp, w2, eps)), kc, vc
+    h = h + (mlp if w2 is None else _rms(mlp, w2, eps))
+    if cfg.fp32_skip_add and S > 1:
+        # a prefill's float32 stream is WRITTEN at the end of each block:
+        # left to itself the compiler keeps every block's two bf16
+        # addends instead and re-adds them in each reader, 537 MB more a
+        # layer at 32768 x 4096 (AOT, PERF.md section 6, PR 36)
+        h = jax.lax.optimization_barrier(h)
+    return h, kc, vc
 
 
 def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
@@ -583,13 +753,16 @@ def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
     h = p["model.embed_tokens.weight"][ids]
     if cfg.embedding_scale != 1.0:
         h = h * jnp.asarray(cfg.embedding_scale, h.dtype)
+    if cfg.fp32_skip_add:
+        h = h.astype(jnp.float32)        # the residual stream's dtype
     L = cfg.num_hidden_layers
     for t in range(cfg.total_ut_steps):
         for li in range(L):
             h, kc, vc = _block_forward(p, cfg, li, t * L + li, h, kc, vc,
                                        pos, max_len, sharded, aidx,
                                        true_len, live, moe_stats)
-        h = _rms(h, p["model.norm.weight"], cfg.rms_norm_eps)
+        h = _rms(_stream_read(h, cfg), p["model.norm.weight"],
+                 cfg.rms_norm_eps)
     hh = h if return_all else h[:, -1]
     if "head:int8" in p:
         logits = _mm(hh, p, "head", sharded).astype(jnp.float32)
@@ -597,6 +770,10 @@ def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
         head = (p["model.embed_tokens.weight"].T if cfg.tie_word_embeddings
                 else p["lm_head.weight"])
         logits = (hh @ head).astype(jnp.float32)
+    if logits.shape[-1] > cfg.vocab_size:
+        # a head of several vocabularies (head h predicts token t + 1 +
+        # h, models/evabyte.py): the next token is picked from the first
+        logits = logits[..., :cfg.vocab_size]
     return logits, kc, vc
 
 
@@ -625,6 +802,11 @@ def _build_params(model: LlamaForCausalLM, max_len: int,
         raw[ffn + "gate_up.weight"] = jnp.concatenate(
             [raw.pop(ffn + "gate_proj.weight"),
              raw.pop(ffn + "up_proj.weight")], axis=1)
+    if model.config.norm_add_unit_offset:
+        # a norm that multiplies by 1 + w: folded here, so that the
+        # programs' RMSNorm is the one form
+        for name in [n for n in raw if n.endswith("norm.weight")]:
+            raw[name] = raw[name] + jnp.ones((), raw[name].dtype)
     p = {}
     for name, v in raw.items():
         if (weight_dtype == "int8" and v.ndim == 2
@@ -963,6 +1145,11 @@ class LlamaDecoder:
                 "quant='int8wk' does not run on a mesh yet: the int8 KV "
                 "carry's scale buffers have no partition rules; use "
                 "quant='int8w' (weight-only) on a mesh, or drop mesh=")
+        if self.quant_kv and self.cfg.eva:
+            raise WindowedModelError(
+                "quant='int8wk' keeps an int8 K / V buffer by position; a "
+                "model whose cache layer holds a window leaf and a summary "
+                "leaf has no quantized form of either: use quant='int8w'")
         self.params = _build_params(model, max_len, self.weight_dtype)
         if self.sharding is not None:
             self.params = self.sharding.shard_params(self.params)
@@ -973,8 +1160,7 @@ class LlamaDecoder:
         # and _cache_update can reach the mesh for its shard_map
         # lowering)
         shd = self.sharding if self.sharding is not None else False
-        self._head_major = (cfg.num_attention_heads
-                            != cfg.num_key_value_heads)
+        self._head_major = cfg.cache_head_major
         self.trace_count = 0     # python side effect: bumps only on (re)trace
         self.dispatch_count = 0  # one per device program execution
         self._spec_engines = {}  # draft-model state for speculative decode
@@ -1325,15 +1511,20 @@ class LlamaDecoder:
         ``cfg.num_cache_layers`` buffers — one per weight layer per pass
         over the layers, so ``num_hidden_layers`` of them for every model
         but a looped one — head-major ``(B, KV, L, D)`` for GQA,
-        token-major ``(B, L, KV, D)`` for MHA, ``L`` being ``max_len``
-        or, for a windowed layer, its window."""
+        token-major ``(B, L, KV, D)`` for MHA
+        (``LlamaConfig.cache_head_major``), ``L`` being ``max_len`` or,
+        for a windowed layer, its window. An EVA config
+        (``cfg.eva``) gets ``cfg.cache_leaves`` = 2 buffers a cache layer,
+        its window leaf and then its summary leaf: the leaves ride in
+        ``kc`` / ``vc``."""
         cfg = self.cfg if cfg is None else cfg
         dt = jnp.dtype(cfg.dtype)
-        head_major = cfg.num_attention_heads != cfg.num_key_value_heads
+        head_major = cfg.cache_head_major
 
         def z(ci):
-            # each cache layer at its own length: a windowed layer's
-            # buffer is rolling and holds its window (LlamaConfig.cache_len)
+            # each buffer at its own length: a windowed layer's is
+            # rolling and holds its window, an EVA layer holds a window
+            # leaf and a summary leaf (the config's cache_len)
             L = cfg.cache_len(ci, self.max_len)
             if head_major:
                 shape = (B, cfg.num_key_value_heads, L, cfg.head_dim)
@@ -1352,8 +1543,8 @@ class LlamaDecoder:
             # the carry never exists gathered, not even at init
             return self.sharding.put_state_field("kc", buf, head_major)
 
-        zeros = lambda: tuple(z(ci)  # noqa: E731
-                              for ci in range(cfg.num_cache_layers))
+        zeros = lambda: tuple(z(ci) for ci in range(  # noqa: E731
+            cfg.num_cache_layers * cfg.cache_leaves))
         return zeros(), zeros()
 
     # -- chunked resumable decode -----------------------------------------

@@ -4,6 +4,8 @@ Coverage of the bench.py training configs: Llama (TP/PP/CP hybrid trainers),
 Ouro (looped Llama-style blocks, served by the same decoder),
 AFMoE (routed experts as one chip's share, gated window/full attention,
 served by the same decoder),
+EvaByte (EVA chunked linearized attention: a window leaf and a summary leaf
+a cache layer, served by the same decoder),
 GPT (fused-qkv causal LM), BERT (MLM pretraining), diffusion UNet
 (SD-style), plus vision CNNs in paddle_tpu.vision.models.
 """
@@ -17,6 +19,9 @@ from paddle_tpu.models.ouro import (  # noqa: F401
 )
 from paddle_tpu.models.afmoe import (  # noqa: F401
     AFMOE_TINY, AfmoeConfig, AfmoeForCausalLM, AfmoeModel,
+)
+from paddle_tpu.models.evabyte import (  # noqa: F401
+    EVABYTE_TINY, EvabyteConfig, EvabyteForCausalLM, EvabyteModel,
 )
 from paddle_tpu.models.gpt import GPT_TINY, GPTConfig, GPTForCausalLM  # noqa: F401
 from paddle_tpu.models.bert import BERT_TINY, BertConfig, BertForMaskedLM  # noqa: F401

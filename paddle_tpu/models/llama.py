@@ -55,10 +55,25 @@ class LlamaConfig:
     embedding_scale = 1.0
     has_windows = False
     routed = False      # whether any feed-forward is routed over experts
+    # what a layer's attention is over its cache: a softmax over one buffer
+    # of keys (and one of values) by position here; a config whose
+    # attention is EVA (models/evabyte.py) says so — a cache layer is then
+    # a window leaf and a summary leaf, two buffers — and says beside it
+    # that the residual stream is float32 and a norm multiplies by 1 + w
+    eva = False
+    fp32_skip_add = False
+    norm_add_unit_offset = False
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def cache_leaves(self) -> int:
+        """Buffers a cache layer holds in the carry's ``kc`` (and as many
+        in ``vc``): one, or EVA's window leaf and summary leaf, the
+        layer's buffers one after the other."""
+        return 2 if self.eva else 1
 
     @property
     def num_cache_layers(self) -> int:
@@ -76,6 +91,16 @@ class LlamaConfig:
 
     def layer_rope(self, li: int) -> bool:
         return True
+
+    @property
+    def cache_head_major(self) -> bool:
+        """The layout of a cache buffer: head-major ``(B, KV, L, D)``,
+        whose blocks of positions are contiguous tiles for the decode
+        attention kernel, under GQA; token-major ``(B, L, KV, D)`` under
+        MHA, which XLA's fused matvec prefers (measured) and where no
+        kernel reads the cache. A config whose MHA attention does go
+        through the kernel answers True itself (models/evabyte.py)."""
+        return self.num_attention_heads != self.num_key_value_heads
 
     def cache_len(self, ci: int, max_len: int) -> int:
         """Positions cache layer ``ci`` holds in a decoder of ``max_len``:

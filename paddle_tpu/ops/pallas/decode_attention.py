@@ -36,6 +36,12 @@ the whole thing in one pass:
 The block length is the largest one under the caller's ``block_l`` whose
 pipeline buffers fit a scoped-VMEM budget (``_block_len``).
 
+``decode_attention_pair`` is the same pass over TWO cache leaves of a
+layer — a second K / V pair with a live length of its own, merged in the
+one online softmax (models/evabyte.py: a window of exact positions beside
+chunk summaries): the grid's position axis runs over the first leaf's
+blocks and then the second's, each leaf's DMA following its own length.
+
 Layouts: q (B, H, D) one token per sequence; kc/vc (B, KV, L, D) padded
 cache (head-major, so cache blocks are contiguous (L, D) tiles), f32/bf16
 or int8 with (B, KV, L, 1) scales; out (B, H, D). Inference-path only
@@ -54,7 +60,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import _routing
 
-__all__ = ["supported", "decode_attention"]
+__all__ = ["supported", "decode_attention", "supported_pair",
+           "decode_attention_pair"]
 
 
 # What a grid step's pipeline buffers may take of Mosaic's 16 MiB scoped
@@ -207,4 +214,136 @@ def decode_attention(q, kc, vc, pos, block_l: int = _BLOCK_L,
         interpret=_routing.use_interpret(),
         name="decode_attention",
     )(*args)
+    return out.reshape(B, H, D)
+
+
+# ---------------------------------------------------------------------------
+# two leaves a layer
+# ---------------------------------------------------------------------------
+
+def _pair_block_len(block_l: int, L1: int, L2: int, KV: int, D: int,
+                    itemsize: int) -> int:
+    """``_block_len`` for two K / V pairs in flight at once: a block that
+    divides both lengths, the four tiles double-buffered."""
+    per_pos = 2 * 2 * 2 * KV * D * itemsize
+    bl = min(block_l, L1, L2)
+    while bl >= 32:
+        if L1 % bl == 0 and L2 % bl == 0 and bl * per_pos <= _VMEM_BUDGET:
+            return bl
+        bl //= 2
+    return 0
+
+
+def supported_pair(q, k1, k2) -> bool:
+    if q.ndim != 3 or k1.ndim != 4 or k2.ndim != 4:
+        return False
+    B, H, D = q.shape
+    _, KV, L1, _ = k1.shape
+    if k2.shape[:2] != k1.shape[:2] or k2.shape[3] != D \
+            or k1.dtype != k2.dtype or k1.dtype == jnp.int8:
+        return False
+    if H % KV or D % 8 or L1 % 128 or k2.shape[2] % 128:
+        return False
+    return _pair_block_len(_BLOCK_L, L1, k2.shape[2], KV, D,
+                           k1.dtype.itemsize) > 0
+
+
+def _pair_kernel(n1_ref, n2_ref, q_ref, k1_ref, v1_ref, k2_ref, v2_ref,
+                 o_ref, m_scr, l_scr, acc_scr, *, scale, bl, nb1):
+    b = pl.program_id(0)
+    li = pl.program_id(1)
+
+    @pl.when(li == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def block(k_ref, v_ref, start, n_valid):
+        q = q_ref[0].astype(jnp.float32)           # (KV, rep, D)
+        k = k_ref[0].astype(jnp.float32)           # (KV, bl, D)
+        v = v_ref[0].astype(jnp.float32)
+        s = jnp.einsum("grd,gld->grl", q, k,
+                       preferred_element_type=jnp.float32) * scale
+        idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(idx < n_valid, s, -jnp.inf)
+        m_prev = m_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[:, :, :1] = corr * l_scr[:, :, :1] + jnp.sum(
+            p, axis=2, keepdims=True)
+        m_scr[:, :, :1] = m_new
+        acc_scr[...] = corr * acc_scr[...] + jnp.einsum(
+            "grl,gld->grd", p, v, preferred_element_type=jnp.float32)
+
+    n1, n2 = n1_ref[b], n2_ref[b]
+
+    @pl.when(jnp.logical_and(li < nb1, li * bl < n1))
+    def _first():
+        block(k1_ref, v1_ref, li * bl, n1)
+
+    @pl.when(jnp.logical_and(li >= nb1, (li - nb1) * bl < n2))
+    def _second():
+        block(k2_ref, v2_ref, (li - nb1) * bl, n2)
+
+    @pl.when(li == pl.num_programs(1) - 1)
+    def _done():
+        o_ref[0] = (acc_scr[...] / l_scr[:, :, :1]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_l",))
+def decode_attention_pair(q, k1, v1, n1, k2, v2, n2,
+                          block_l: int = _BLOCK_L):
+    """q (B, H, D) over two cache leaves (B, KV, L1, D) and (B, KV, L2,
+    D) with live lengths ``n1`` >= 1 and ``n2`` >= 0 (traced scalars or
+    per-row ``(B,)`` vectors), one softmax over both -> (B, H, D). Blocks
+    past a leaf's live length are neither fetched nor computed, as in
+    ``decode_attention``; while the grid walks one leaf the other's index
+    stands still."""
+    B, H, D = q.shape
+    _, KV, L1, _ = k1.shape
+    L2 = k2.shape[2]
+    rep = H // KV
+    bl = _pair_block_len(block_l, L1, L2, KV, D, k1.dtype.itemsize)
+    if not bl:
+        raise ValueError(
+            f"decode_attention_pair: no block of cache positions under "
+            f"block_l={block_l} divides L1={L1} and L2={L2} and fits VMEM "
+            f"at KV={KV}, D={D} (see supported_pair())")
+    nb1, nb2 = L1 // bl, L2 // bl
+
+    def lengths(n):
+        return jnp.broadcast_to(jnp.asarray(n, jnp.int32).reshape(-1), (B,))
+
+    def row(b, l, n1_ref, n2_ref):
+        return (b, 0, 0, 0)
+
+    def first(b, l, n1_ref, n2_ref):
+        return (b, 0, _live_block(jnp.minimum(l, nb1 - 1), n1_ref[b], bl), 0)
+
+    def second(b, l, n1_ref, n2_ref):
+        return (b, 0, _live_block(jnp.maximum(l - nb1, 0), n2_ref[b], bl), 0)
+
+    out = pl.pallas_call(
+        functools.partial(_pair_kernel, scale=1.0 / math.sqrt(D), bl=bl,
+                          nb1=nb1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, nb1 + nb2),
+            in_specs=[pl.BlockSpec((1, KV, rep, D), row),
+                      pl.BlockSpec((1, KV, bl, D), first),
+                      pl.BlockSpec((1, KV, bl, D), first),
+                      pl.BlockSpec((1, KV, bl, D), second),
+                      pl.BlockSpec((1, KV, bl, D), second)],
+            out_specs=pl.BlockSpec((1, KV, rep, D), row),
+            scratch_shapes=[
+                pltpu.VMEM((KV, rep, 128), jnp.float32),
+                pltpu.VMEM((KV, rep, 128), jnp.float32),
+                pltpu.VMEM((KV, rep, D), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, KV, rep, D), q.dtype),
+        interpret=_routing.use_interpret(),
+        name="decode_attention_pair",
+    )(lengths(n1), lengths(n2), q.reshape(B, KV, rep, D), k1, v1, k2, v2)
     return out.reshape(B, H, D)

@@ -204,6 +204,10 @@ class _DecoderBackend:
         # a model with windowed layers keeps those layers' keys in rolling
         # buffers: what addresses cache rows by position refuses it
         self.has_windows = dec.cfg.has_windows
+        # an EVA model's cache layer is a window leaf and a summary leaf:
+        # the window's rows and the positions a summary stands for
+        self.eva = ((dec.cfg.cache_len(0, dec.max_len), dec.cfg.chunk_size)
+                    if dec.cfg.eva else None)
         self.moe_counts = []    # state.moe of every chunk dispatch since
         #                         the engine last took them (harvest)
         if mesh is not None:
@@ -499,6 +503,7 @@ class _BundleBackend:
     serving process runs no model Python (``decode_mode.chunked``)."""
 
     has_windows = False    # (an exported program is full attention)
+    eva = None
     moe_counts = ()
     has_ring = False       # bundles carry no ring-staging entries: the
     #                        engine falls back to the host row-scatter
@@ -1018,6 +1023,29 @@ class ServingEngine:
             "the rolling buffers' length) at the chunk's start, per chunk "
             "dispatch (what a windowed layer's attention reads of a row; "
             "0 for a model with none)")
+        # a model whose cache layer is a window leaf beside a summary leaf
+        # (models/evabyte.py): live_window_positions counts the window
+        # leaf's live rows (kv_pos % window + 1), and these the rest
+        self._c_live_sum = r.counter(
+            "serving.chunk.live_summary_positions",
+            "sum over the occupied rows of the summaries visible at the "
+            "chunk's start ((kv_pos // window) x (window / chunk)), per "
+            "chunk dispatch")
+        self._c_eva_chunks = r.counter(
+            "serving.eva.chunks_summarised",
+            "chunks whose summary a decode step completed, over the "
+            "occupied rows and the chunks' steps")
+        self._c_eva_ends = r.counter(
+            "serving.eva.window_ends",
+            "window ends decode steps crossed (the window leaf reset, "
+            "window / chunk more summaries visible), over the occupied "
+            "rows and the chunks' steps")
+        self._c_eva_prefill = r.counter(
+            "serving.eva.prefill_windows",
+            "aligned windows the admission prefills covered (a prompt of "
+            "n positions: ceil(n / window))")
+        # bucket -> what its prefills needed (_count_eva_prefill)
+        self._eva_prefill: Dict[int, Dict[str, int]] = {}
         # what the routing did, from the vector the chunk program of a
         # model with routed feed-forwards returns beside its tokens
         self._c_moe_pairs = r.counter(
@@ -1053,6 +1081,31 @@ class ServingEngine:
             self.num_slots * full)
         self._cache_bytes_window = sum(
             b // (self.num_slots * n) for n, b in by_len.items() if n < full)
+        self._cache_bytes_summary = 0
+        self._leaf_kinds = 1 if self._b.eva is None else 2
+        if self._b.eva is not None:
+            # a window leaf (an even buffer of the carry) beside a summary
+            # leaf (the odd one after it) a layer: by the leaf's place, not
+            # by a length the two may share; a leaf's bytes a ROW, over
+            # the layers
+            def row_bytes(bufs):
+                return sum(x.nbytes // (self.num_slots * int(x.shape[2]))
+                           for x in bufs)
+            self._window_len = self._b.eva[0]
+            self._cache_bytes_full = 0
+            self._cache_bytes_window = (row_bytes(kc[0::2])
+                                        + row_bytes(self.state.vc[0::2]))
+            self._cache_bytes_summary = (row_bytes(kc[1::2])
+                                         + row_bytes(self.state.vc[1::2]))
+            self._cache_layers //= 2
+        r.gauge("serving.cache.leaf_kinds",
+                "kinds of leaf a cache layer holds (1: keys and values by "
+                "position; 2: a window of exact positions beside chunk "
+                "summaries)").set(self._leaf_kinds)
+        r.gauge("serving.cache.bytes_per_position.summary",
+                "bytes of K and V one summary entry holds over the cache "
+                "layers (0 for a model without summary leaves)"
+                ).set(self._cache_bytes_summary)
         r.gauge("serving.cache.bytes_per_position.full",
                 "bytes of K and V one position holds over the cache "
                 "layers that keep all of max_len").set(self._cache_bytes_full)
@@ -1533,7 +1586,15 @@ class ServingEngine:
         self._phase_to("dispatch")
         self._h_occ.observe(len(occupied) / self.num_slots)
         self._c_live_kv.inc(sum(slot.kv_pos for _, slot in occupied))
-        if self._cache_bytes_window:
+        if self._b.eva is not None:
+            W, C = self._b.eva
+            T = self.chunk_size
+            at = [slot.kv_pos for _, slot in occupied]
+            self._c_live_win.inc(sum(n % W + 1 for n in at))
+            self._c_live_sum.inc(sum(n // W * (W // C) for n in at))
+            self._c_eva_chunks.inc(sum((n + T) // C - n // C for n in at))
+            self._c_eva_ends.inc(sum((n + T) // W - n // W for n in at))
+        elif self._cache_bytes_window:
             self._c_live_win.inc(sum(min(slot.kv_pos, self._window_len)
                                      for _, slot in occupied))
         toks = self._dispatch_chunk(occupied)
@@ -2396,6 +2457,12 @@ class ServingEngine:
         per-pool accounting the cluster bench asserts on."""
         import jax
 
+        if self._b.has_windows:
+            from paddle_tpu.inference.generate import WindowedModelError
+            raise WindowedModelError(
+                "a prefix slab is a prompt's cache rows by position; a "
+                "model with windowed layers keeps a position at position "
+                "% window: no slab can be cut from its caches")
         prompt = np.asarray(prompt)
         if prompt.ndim == 2 and prompt.shape[0] == 1:
             prompt = prompt[0]
@@ -2601,6 +2668,26 @@ class ServingEngine:
                                 for req, _ in items], np.int32)
         return ids, true_len, pos0, aidxN
 
+    def _count_eva_prefill(self, w: int, true_len) -> None:
+        """What an EVA admission prefill of bucket ``w`` NEEDED, from its
+        rows' true lengths: the aligned windows they reach, and by bucket
+        the rows, their positions and the (query, key) pairs of the
+        windows' causal halves and (query, summary) pairs of the windows
+        before — the padded tail of the bucket is none of them."""
+        if self._b.eva is None:
+            return
+        W, C = self._b.eva
+        acc = self._eva_prefill.setdefault(int(w), dict.fromkeys(
+            ("rows", "positions", "local_pairs", "summary_pairs"), 0))
+        for n in map(int, true_len):
+            full, r = divmod(n, W)
+            self._c_eva_prefill.inc(full + (r > 0))
+            acc["rows"] += 1
+            acc["positions"] += n
+            acc["local_pairs"] += (full * W * (W + 1) + r * (r + 1)) // 2
+            acc["summary_pairs"] += (W // C) * (
+                W * full * (full - 1) // 2 + r * full)
+
     def _admit_group_ring(self, w: int, grp, free, now: float) -> None:
         """ONE ring-staged admission-prefill dispatch for the group
         (plus one draft-cache staging dispatch under speculation): the
@@ -2615,6 +2702,7 @@ class ServingEngine:
         with TraceAnnotation("serving.admit.prefill_enqueue"):
             self._b.ring_admit(ids, true_len, pos0, rows, aidx=aidxN)
             self._c_prefill.inc()
+            self._count_eva_prefill(w, true_len)
             if self._spec_active:
                 self._b.ring_admit_draft(ids, rows)
                 self._c_draft_prefill.inc()
@@ -2727,6 +2815,7 @@ class ServingEngine:
             logitsN, kcN, vcN = self._b.admit_prefill(
                 ids, true_len, pos0, kcN, vcN, aidx=aidxN)
         self._c_prefill.inc()
+        self._count_eva_prefill(w, true_len)
         if N > 1:
             self._c_batched_groups.inc()
             self._c_disp_saved.inc(N - 1)
@@ -3362,8 +3451,15 @@ class ServingEngine:
         shorter buffers — ``cache_bytes_per_position_full`` /
         ``_window`` are the two kinds' own, and
         ``live_window_positions_total`` the positions live in the rolling
-        buffers), ``moe_*`` what the routing of a model with routed
-        feed-forwards did (``serving.moe.*``; zeros for any other),
+        buffers; for an EVA model (``cache_leaf_kinds`` 2) the
+        latter counts the window leaf's live rows,
+        ``live_summary_positions_total`` the visible summaries,
+        ``cache_bytes_per_position_window`` / ``_summary`` a row's bytes of
+        each leaf, and ``eva_*`` the chunks summarised, the window ends
+        crossed, the windows prefilled and, by admission bucket, the rows,
+        positions and attended pairs the prefills needed), ``moe_*`` what
+        the routing of a model with routed feed-forwards did
+        (``serving.moe.*``; zeros for any other),
         ``compiles`` this process's backend compiles by dispatch site."""
         qd, lat = self._h_qdelay, self._h_latency
         return {
@@ -3389,6 +3485,14 @@ class ServingEngine:
             "cache_bytes_per_position": self._cache_bytes_per_position,
             "cache_bytes_per_position_full": self._cache_bytes_full,
             "cache_bytes_per_position_window": self._cache_bytes_window,
+            "cache_bytes_per_position_summary": self._cache_bytes_summary,
+            "cache_leaf_kinds": self._leaf_kinds,
+            "live_summary_positions_total": int(self._c_live_sum.value),
+            "eva_chunks_summarised_total": int(self._c_eva_chunks.value),
+            "eva_window_ends_total": int(self._c_eva_ends.value),
+            "eva_prefill_windows_total": int(self._c_eva_prefill.value),
+            "eva_prefill_by_bucket": {b: dict(v) for b, v
+                                      in self._eva_prefill.items()},
             "moe_pairs_held_total": int(self._c_moe_pairs.value),
             "moe_experts_touched_total": int(self._c_moe_touched.value),
             "moe_load_max": int(self._g_moe_load.value),

@@ -113,6 +113,48 @@ def test_decode_attention_compiles(tpu, shape, kv_dtype):
     assert kernels == {"decode_attention": 1}
 
 
+def test_two_leaf_decode_attention_compiles_at_the_evabyte_cells_shape(tpu):
+    """``decode_attention_pair`` at evabyte.serve.longdoc-backlog's own
+    leaves: 8 slots, 32 MHA heads (one query head a KV head), a window
+    leaf of 2048 rows beside 32768 / 16 summaries; four tiles in flight,
+    so the block of positions halves to 128 to stay inside scoped VMEM."""
+    from paddle_tpu.ops.pallas.decode_attention import (
+        _pair_block_len, decode_attention_pair)
+
+    B, H, L, D = 8, 32, 2048, 128
+    assert _pair_block_len(256, L, L, H, D, 2) == 128
+    q, n = _s(tpu, (B, H, D)), _s(tpu, (B,), jnp.int32)
+    leaf = _s(tpu, (B, H, L, D))
+    assert _kernels(decode_attention_pair, q, leaf, leaf, n, leaf, leaf,
+                    n) == {"decode_attention_pair": 1}
+
+
+def test_eva_chunk_pool_compiles_at_the_evabyte_cells_shape(tpu):
+    """A decode step's chunk summary from the window leaf: 8 slots, 32
+    heads, chunks of 16 rows (one bf16 sublane tile) of 128."""
+    from paddle_tpu.ops.pallas.eva_attention import eva_chunk_pool
+
+    leaf, vec = _s(tpu, (8, 32, 2048, 128)), _s(tpu, (32, 128))
+    assert _kernels(lambda k, v, mu, phi, at: eva_chunk_pool(
+        k, v, mu, phi, at, chunk=16), leaf, leaf, vec, vec,
+        _s(tpu, (8,), jnp.int32)) == {"eva_chunk_pool": 1}
+
+
+@pytest.mark.parametrize("positions", [2048, 6144, 32768])
+def test_eva_prefill_attention_compiles_at_the_cells_buckets(tpu, positions):
+    """The EVA admission prefill's one attention kernel: 32 heads of 128,
+    windows of 2048 and 128 summaries a window — one window (the check's
+    2044 positions), three (its 4100: a summary block of 384) and the
+    longest bucket."""
+    from paddle_tpu.ops.pallas.eva_attention import eva_prefill_attention
+
+    x = _s(tpu, (32, positions, 128))
+    s_ = _s(tpu, (32, positions // 16, 128))
+    assert _kernels(lambda q, k, v, ks, vs: eva_prefill_attention(
+        q, k, v, ks, vs, window=2048, per=128), x, x, x, s_, s_) \
+        == {"eva_prefill_attention": 1}
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bf16", "f32"])
 @pytest.mark.parametrize("shape,head_major", [
