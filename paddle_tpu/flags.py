@@ -79,6 +79,19 @@ class _FlagRegistry:
         except KeyError:
             raise AttributeError(f"undefined flag {name!r}")
 
+    def __setattr__(self, name: str, value: Any) -> None:
+        # ``flags.x = v`` (and a test's ``monkeypatch.setattr(flags, "x",
+        # v)``, teardown included) SETS the flag: stored as a plain
+        # attribute it would shadow the registry for the rest of the
+        # process, and no later ``set_flags`` could reach a reader
+        if name.startswith("_"):
+            object.__setattr__(self, name, value)
+            return
+        try:
+            self._flags[name].set(value)
+        except KeyError:
+            raise AttributeError(f"undefined flag {name!r}")
+
     def get(self, name: str) -> Any:
         return self._flags[name].get()
 

@@ -221,6 +221,17 @@ def _mm(x, p, name, sharded=False, aidx=None):
     return out
 
 
+def _kernel_backend() -> bool:
+    """The backend half of the routing of every Pallas kernel the decoder
+    calls (attention over the cache, the token-row write, a cold
+    prefill's flash forward): a TPU, or ``flags.decode_attention_interpret``
+    for the CPU tests — off the TPU a kernel runs interpreted, which the
+    hundreds of tests that prefill a tiny decoder should not pay for."""
+    from paddle_tpu.flags import flags as _flags
+    return bool(jax.default_backend() == "tpu"
+                or _flags.decode_attention_interpret)
+
+
 def _decode_kernels(pos, sharded) -> bool:
     """Whether a decode step's attention over the cache may go through the
     Pallas kernels (``decode_attention``, ``decode_attention_pair``,
@@ -232,9 +243,7 @@ def _decode_kernels(pos, sharded) -> bool:
     predicate for ``_kv_attention`` and ``_eva_attention``."""
     from paddle_tpu.flags import flags as _flags
     return bool(jnp.ndim(pos) <= 1 and not sharded
-                and _flags.use_decode_attention
-                and (jax.default_backend() == "tpu"
-                     or _flags.decode_attention_interpret))
+                and _flags.use_decode_attention and _kernel_backend())
 
 
 def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
@@ -253,13 +262,10 @@ def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
     step), ``S > 1`` at per-row positions (the speculative verify's
     uneven advance), a mesh, a quantized cache's ``(..., 1)`` scale leaf
     (its int8 leaf takes the kernel) — is ``_cache_write`` a buffer."""
-    from paddle_tpu.flags import flags as _flags
     from paddle_tpu.ops.pallas import kv_row_write as _kw
     from paddle_tpu.quantization.kv_cache import (is_quantized_kv,
                                                   quantize_kv_rows)
-    rows = (jnp.ndim(pos) == 1 and not sharded
-            and (jax.default_backend() == "tpu"
-                 or _flags.decode_attention_interpret))
+    rows = jnp.ndim(pos) == 1 and not sharded and _kernel_backend()
     if rows and is_quantized_kv(kbuf):
         qk, qv = quantize_kv_rows(kt), quantize_kv_rows(vt)
         if _kw.supported(kbuf["q"], qk["q"], head_major):
@@ -373,13 +379,36 @@ def _prefill_rows(t, true_len, L: int, head_major: bool):
     return jnp.take_along_axis(t, idx[:, :, None, None], axis=1)
 
 
+@functools.partial(jax.jit, static_argnames=("window", "interpret"))
+def _flash_prefill(q, k, v, *, window, interpret):
+    """The flash forward of ``_fresh_attention`` as a function of its own:
+    a prefill calls it once a cache layer with the same shapes, and an
+    inner ``jit`` is traced and lowered ONCE a program — as 12 separate
+    ``pallas_call``s Mistral's seven admission buckets took 1.8 s each
+    longer to trace and lower than the kernel-free programs they replace,
+    12.6 s of every serving run's set-up with every program found in the
+    compile cache (PERF.md section 6, PR 37); XLA inlines the calls, so
+    the compiled program is the same. ``interpret`` is part of the
+    trace's key only: the kernel asks ``_routing.use_interpret()`` itself,
+    and a test that patches it must not be served the other mode's
+    trace."""
+    del interpret
+    from paddle_tpu.ops.pallas import flash_attention as _fa
+    return _fa.flash_attention_fn(q, k, v, causal=True, window=window)
+
+
 def _fresh_attention(q, k, v, window, sharded):
     """Causal attention of a prefill from position 0 over its own fresh
     keys (B, S, KV, D), under a band of ``window`` positions where the
     layer has one: blockwise through the flash forward kernel, which
     neither computes nor fetches blocks outside the band and never holds
-    an (S, S) score matrix; XLA's masked attention where the kernel does
-    not take the shape or the program runs under a mesh."""
+    an (S, S) score matrix; XLA's masked attention over the same S keys
+    where the kernel does not take the shape, the program runs under a
+    mesh, or the backend is not the kernels' (``_kernel_backend``: one
+    rule for windowed and plain layers). K and V are repeated to H heads
+    first (a grouped index map in the kernel would spare that: ROADMAP
+    S3)."""
+    from paddle_tpu.ops.pallas import _routing
     from paddle_tpu.ops.pallas import flash_attention as _fa
     B, S, H, D = q.shape
     rep = H // k.shape[2]
@@ -387,8 +416,10 @@ def _fresh_attention(q, k, v, window, sharded):
         k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     if window is not None and window >= S:
         window = None                       # the band is all of the past
-    if not sharded and _fa.supported(q.shape, k.shape, True, window):
-        out = _fa.flash_attention_fn(q, k, v, causal=True, window=window)
+    if (not sharded and _kernel_backend()
+            and _fa.supported(q.shape, k.shape, True, window)):
+        out = _flash_prefill(q, k, v, window=window,
+                             interpret=_routing.use_interpret())
         return out.reshape(B, S, H * D)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(
         jnp.float32(D)).astype(q.dtype)
@@ -400,16 +431,32 @@ def _fresh_attention(q, k, v, window, sharded):
 
 
 def _kv_attention(cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
-                  sharded, true_len):
+                  sharded, true_len, from_zero=False):
     """Attention of layer ``li`` through cache layer ``ci``'s one buffer
-    of keys and one of values by position: write the fresh rows, attend
-    over the buffer (``_block_forward`` says how, by the layer's kind).
-    q (B, S, H, D), k, v (B, S, KV, D), rotated -> (out (B, S, H * D),
-    kc, vc)."""
+    of keys and one of values by position: write the fresh rows, then
+    attend — over what, the call's own facts decide (``_block_forward``
+    says how a layer's kind enters). q (B, S, H, D), k, v (B, S, KV, D),
+    rotated -> (out (B, S, H * D), kc, vc).
+
+    ``S > 1`` FROM POSITION 0 (``from_zero``, a trace-time fact of the
+    entry point, never a test of the traced ``pos``; a model with
+    windowed layers has no other ``S > 1`` forward) attends over the S
+    FRESH keys it brought (``_fresh_attention``): every buffer row from S
+    on is empty and masked, so scores against the whole buffer — (B, H, S,
+    max_len) in float32, 8 x the keys at bucket 256 of ``max_len`` 2048 —
+    are the same sum at several times the bytes (PERF.md section 6, PR
+    37). Everything else attends over the BUFFER: ``S > 1`` at ``pos >
+    0`` (the prefix-cache suffix prefill, whose keys before ``pos`` live
+    only there; the speculative verify), every ``S == 1`` step, and a
+    quantized cache's prefill, which reads back the dequantized rows a
+    decode step will read."""
+    from paddle_tpu.quantization.kv_cache import (dequantize_kv,
+                                                  is_quantized_kv)
     B, S, H, D = q.shape
     KV = k.shape[2]
     rolling = cfg.has_windows
-    fresh = rolling and S > 1
+    quant_kv = is_quantized_kv(kc[ci])
+    fresh = S > 1 and (rolling or (from_zero and not quant_kv))
     L = cfg.cache_len(ci, max_len)
 
     rep = H // KV
@@ -438,9 +485,6 @@ def _kv_attention(cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
     vc = vc[:ci] + (vc_l,) + vc[ci + 1:]
 
     from paddle_tpu.ops.pallas import decode_attention as _da
-    from paddle_tpu.quantization.kv_cache import (dequantize_kv,
-                                                  is_quantized_kv)
-    quant_kv = is_quantized_kv(kc_l)
     use_kernel = (head_major and S == 1 and _decode_kernels(pos, sharded)
                   and _da.supported(q[:, 0],
                                     kc_l["q"] if quant_kv else kc_l))
@@ -630,11 +674,14 @@ def _moe_reduce(c):
 
 def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
                    max_len, sharded=False, aidx=None, true_len=None,
-                   live=None, moe_stats=None):
+                   live=None, moe_stats=None, from_zero=False):
     """One decoder block over h (B, S, H), weights of layer ``li``,
     writing K/V into CACHE layer ``ci`` at [pos, pos+S); attention reads
     that whole buffer masked to < pos+S with causal alignment to the
-    bottom-right (query i attends to <= pos+i). ``ci`` is ``li`` for a
+    bottom-right (query i attends to <= pos+i) — or, where the forward
+    starts at position 0 by construction (``from_zero``, trace-time
+    static) with ``S > 1``, the S fresh keys alone, which are all the
+    buffer then holds (``_kv_attention``). ``ci`` is ``li`` for a
     model that makes one pass over its layers; a looped model's pass
     ``t`` keeps its own keys and values in ``t * num_hidden_layers +
     li``. Both are trace-time integers. Where the parameters hold
@@ -655,9 +702,10 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     cached and softmax does not care for order, so a decode step attends
     over the buffer's first ``min(pos + 1, length)`` rows as it does over
     a plain one. In a model with any windowed layer (``cfg.has_windows``)
-    ``S > 1`` is a prefill FROM POSITION 0: every layer attends over its
-    own fresh keys (``_fresh_attention``) and writes the last
-    ``min(true_len, length)`` of them (``true_len`` (B,), None = S).
+    ``S > 1`` is a prefill FROM POSITION 0 whatever the entry says: every
+    layer attends over its own fresh keys (``_fresh_attention``) and
+    writes the last ``min(true_len, length)`` of them (``true_len`` (B,),
+    None = S).
     ``cfg.eva``: the layer's attention is EVA over a window leaf and a
     summary leaf (``_eva_attention``), buffers ``2 * ci`` and ``2 * ci +
     1`` of the carry. ``cfg.fp32_skip_add``: ``h`` is
@@ -696,7 +744,7 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
                                      max_len, sharded, true_len)
     else:
         out, kc, vc = _kv_attention(cfg, li, ci, q, k, v, kc, vc, pos,
-                                    max_len, sharded, true_len)
+                                    max_len, sharded, true_len, from_zero)
     if gate is not None:
         out = out * jax.nn.sigmoid(gate)
     att = _mm(out, p, pre + "self_attn.o_proj.weight", sharded, aidx)
@@ -735,13 +783,29 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
 
 def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
                     return_all: bool = False, sharded: bool = False,
-                    aidx=None, true_len=None, live=None, moe_stats=None):
-    """ids (B, S) -> logits (B, V) of the LAST position — or of ALL S
-    positions (B, S, V) with ``return_all=True`` (speculative verify
-    scores every drafted position in one batched forward) — plus the
-    updated caches. ``pos``: scalar or per-row (B,) vector. ``aidx``
-    (B,) i32: per-row LoRA adapter index (projections only — the head
-    stays base).
+                    aidx=None, true_len=None, live=None, moe_stats=None,
+                    from_zero: bool = False):
+    """ids (B, S) -> logits (B, V) plus the updated caches: of the LAST
+    position, or, for right-padded rows of ``true_len`` (B,) tokens each
+    (the admission prefills), of position ``true_len - 1`` — the row is
+    gathered out of the residual stream BEFORE the last final norm and
+    the head, so neither runs over the S - 1 positions nobody samples
+    from (the (B, S, V) logits: 0.42 GB in bfloat16 at bucket 2048 of a
+    102 400-row head). ``return_all=True`` gives all S positions (B, S,
+    V): the speculative verify alone, which scores every drafted
+    position in one batched forward. ``pos``: scalar or per-row (B,)
+    vector. ``aidx`` (B,) i32: per-row LoRA adapter index (projections
+    only — the head stays base).
+
+    ``from_zero`` (trace-time static, set by the entry point): this
+    forward starts at position 0 BY CONSTRUCTION — the solo and draft
+    prefills, which pass the literal 0, and the ring admission, whose
+    traced ``pos`` the engine always fills with zeros: the entry knows,
+    the tracer does not. With ``S > 1`` its attention is over its own S
+    fresh keys instead of the cache buffer (``_kv_attention``). Entries
+    that may start anywhere (``admit_prefill``'s per-row offsets, the
+    speculative verify, every step) leave it False and attend over the
+    buffer.
 
     The layer list is run ``cfg.total_ut_steps`` times (once for every
     model but a looped one), each pass over its own cache layers and
@@ -760,7 +824,13 @@ def _forward_cached(p, cfg: LlamaConfig, ids, kc, vc, pos, max_len,
         for li in range(L):
             h, kc, vc = _block_forward(p, cfg, li, t * L + li, h, kc, vc,
                                        pos, max_len, sharded, aidx,
-                                       true_len, live, moe_stats)
+                                       true_len, live, moe_stats, from_zero)
+        if (t == cfg.total_ut_steps - 1 and true_len is not None
+                and not return_all):
+            # only the last pass's norm: an earlier pass's output feeds
+            # the next pass whole
+            h = jnp.take_along_axis(h, (true_len - 1)[:, None, None],
+                                    axis=1)                 # (B, 1, hidden)
         h = _rms(_stream_read(h, cfg), p["model.norm.weight"],
                  cfg.rms_norm_eps)
     hh = h if return_all else h[:, -1]
@@ -1175,7 +1245,8 @@ class LlamaDecoder:
         def prefill(p, ids, kc, vc):
             self.trace_count += 1
             logits, kc, vc = _forward_cached(p, cfg, ids, kc, vc, 0,
-                                             max_len, sharded=shd)
+                                             max_len, sharded=shd,
+                                             from_zero=True)
             return pin(logits=logits, kc=kc, vc=vc)
 
         def step(p, ids, kc, vc, pos):
@@ -1226,21 +1297,19 @@ class LlamaDecoder:
             return jnp.concatenate([jnp.moveaxis(toks, 0, 1),
                                     last[:, None]], axis=1)
 
-        def admit_rows(p, ids, kc, vc, true_len, pos0, aidx):
+        def admit_rows(p, ids, kc, vc, true_len, pos0, aidx,
+                       from_zero=False):
             """The traced body both admission entries share: forward the
-            right-padded rows at their cache offsets and take each row's
-            logits at position ``true_len - 1`` of its bucket."""
+            right-padded rows at their cache offsets; each row's logits
+            are those of position ``true_len - 1`` of its bucket, the
+            one row the head runs over (``_forward_cached``)."""
             # the padded tail reaches no routed expert, and is not
             # written into a rolling buffer
             live = (jnp.arange(ids.shape[1])[None, :] < true_len[:, None]
                     if routed else None)
-            logits_all, kc, vc = _forward_cached(
-                p, cfg, ids, kc, vc, pos0, max_len, return_all=True,
-                sharded=shd, aidx=aidx,
-                true_len=true_len if cfg.has_windows else None, live=live)
-            logits = jnp.take_along_axis(
-                logits_all, (true_len - 1)[:, None, None], axis=1)[:, 0]
-            return logits, kc, vc
+            return _forward_cached(
+                p, cfg, ids, kc, vc, pos0, max_len, sharded=shd, aidx=aidx,
+                true_len=true_len, live=live, from_zero=from_zero)
 
         def admit_prefill(p, ids, kc, vc, true_len, pos0, aidx=None):
             """Length-bucketed admission prefill: ``ids`` is a batch of
@@ -1260,7 +1329,14 @@ class LlamaDecoder:
             offsets keep their prefixes independent). ``aidx`` (B,) i32
             or None: each admitted row's prompt prefills through ITS
             adapter's deltas, so the cached prefix KV matches what a
-            dense per-tenant model would have produced."""
+            dense per-tenant model would have produced.
+
+            Its attention is over the cache BUFFER (all ``max_len`` rows,
+            masked past ``pos0 + i``): a row's keys before ``pos0`` are
+            in the buffer and nowhere else, and ``pos0`` is a traced
+            vector, so this entry cannot know a start of 0. A cold
+            admission that should not pay for the empty rows goes through
+            ``ring_admit_prefill``."""
             self.trace_count += 1
             logits, kc, vc = admit_rows(p, ids, kc, vc, true_len, pos0,
                                         aidx)
@@ -1280,10 +1356,21 @@ class LlamaDecoder:
             DONATED: the scatter writes the admitted rows in place and
             the returned ring is the one passed in, which the caller
             must not touch again (``kc``/``vc``, the empty pair the
-            rows prefill from, are not)."""
+            rows prefill from, are not).
+
+            Every ring admission is COLD: the engine stages a request
+            here from position 0 (``_admit_group_ring`` packs ``(req,
+            0)``; a prefix-cache suffix goes through ``admit_prefill``),
+            so ``pos0`` is all zeros by construction and ``kc``/``vc``
+            hold nothing. The entry says so (``from_zero``) and the rows
+            attend over their own S fresh keys, not over ``max_len``
+            rows of which ``max_len - S`` are empty: ``flash_fwd`` once a
+            layer on one device, XLA's (S, S) masked form under a mesh
+            and where the kernel declines; a quantized cache (``int8wk``)
+            keeps the buffer (``_kv_attention``)."""
             self.trace_count += 1
             logits, kc, vc = admit_rows(p, ids, kc, vc, true_len, pos0,
-                                        aidx)
+                                        aidx, from_zero=True)
             ring_logits = ring_logits.at[ring_idx].set(logits,
                                                        mode="drop")
             ring_kc = _row_scatter(ring_kc, kc, ring_idx)
@@ -1859,7 +1946,7 @@ class LlamaDecoder:
         def draft_prefill(dp_, ids, dkc, dvc):
             self.trace_count += 1
             return _forward_cached(dp_, dcfg, ids, dkc, dvc, 0, max_len,
-                                   sharded=shd)
+                                   sharded=shd, from_zero=True)
 
         def spec_round(p, dp_, tok, pos, key, done, kc, vc, dkc, dvc,
                        eos_id, temperature, K: int, do_sample: bool,
@@ -2075,7 +2162,8 @@ class LlamaDecoder:
             group — the speculative analog of ``ring_admit_prefill``)."""
             self.trace_count += 1
             _, dkc, dvc = _forward_cached(dp_, dcfg, ids, dkc, dvc, 0,
-                                          max_len, sharded=shd)
+                                          max_len, sharded=shd,
+                                          from_zero=True)
             ring_dkc = _row_scatter(ring_dkc, dkc, ring_idx)
             ring_dvc = _row_scatter(ring_dvc, dvc, ring_idx)
             return pin(dkc=ring_dkc, dvc=ring_dvc)

@@ -478,21 +478,25 @@ def test_parameters_and_pooling_vectors_as_the_configuration_says():
 
 
 # the lowered texts of the accepted configurations' chunk program (4 steps,
-# 2 rows) and bucket-128 admission prefill at max_len 128, taken on the
-# parent commit (fde7c2a): the two-leaf path is chosen at trace time by the
-# config, so these programs do not move. (The compiled texts of the six,
-# their source tables and metadata dropped, were compared by hand on both
-# trees and are identical too: CHANGES.md, PR 36. They carry the host's
-# core count, so the test holds the lowered ones.)
-# THIS IS PR 36'S EVIDENCE, NOT A CONTRACT ON THE COMPILER'S TEXT: a PR
+# 2 rows) and bucket-128 admission prefill at max_len 128. The chunk
+# programs' are those of the parent of PR 36 (fde7c2a) still: the two-leaf
+# path is chosen at trace time by the config, and no PR since has meant to
+# move them. The prefills' were RETAKEN by PR 37 on its own tree, which
+# meant to change them: ``admit_prefill`` gathers each row at ``true_len -
+# 1`` before the last final norm and the head where it made all S rows of
+# logits (its attention is still buffer-wide, or over the fresh keys for a
+# windowed model, now in XLA's form off the TPU: one routing rule for the
+# flash forward, ``generate._kernel_backend``);
+# tests/test_prefill_from_zero.py holds the new route's logits to the old.
+# THIS IS EVIDENCE, NOT A CONTRACT ON THE COMPILER'S TEXT: a PR
 # that means to change the shared block (or a JAX upgrade) retakes the
 # hashes on its own tree and says so, or replaces this test with the
 # logits-parity tests that already hold these configurations
 # (tests/test_llama.py, test_afmoe.py, test_ouro.py).
 _TEXTS = {
-    "llama": ("e3fe9ce2b00457d9", "6d2a4601c3494fb0"),
-    "afmoe": ("f72d6133ef314834", "0ec14ec94c701dce"),
-    "ouro": ("b99dbdde1522714b", "a77799718e47720d"),
+    "llama": ("e3fe9ce2b00457d9", "a978c6f63fa34a6f"),
+    "afmoe": ("f72d6133ef314834", "3a9bceaaa23385d0"),
+    "ouro": ("b99dbdde1522714b", "9db49cc19739a076"),
 }
 
 

@@ -42,6 +42,15 @@ def tpu():
     cc.reset_cache()
 
 
+@pytest.fixture
+def decoder_kernels_routed():
+    """The decoder's kernel routing asks for a TPU backend or this flag."""
+    import paddle_tpu as paddle
+    paddle.set_flags({"decode_attention_interpret": True})
+    yield
+    paddle.set_flags({"decode_attention_interpret": False})
+
+
 @pytest.fixture(autouse=True)
 def compiled_not_interpreted(monkeypatch):
     from paddle_tpu.ops.pallas import _routing
@@ -345,7 +354,7 @@ def _loops_beside(hlo_text, kernel):
     (32, 8, "8,2048,32,128"),     # MHA, token-major, XLA attention
 ])
 def test_ring_chunk_program_updates_the_kv_carry_in_place(
-        tpu, monkeypatch, kv_heads, slots, layer_shape):
+        tpu, decoder_kernels_routed, kv_heads, slots, layer_shape):
     """The serving chunk program at 7B attention widths (hidden 4096, 32
     heads of 128, ``max_len`` 2048; FFN and vocabulary small) holds no
     copy of a layer's KV buffer: its temporaries stay under one layer's K
@@ -356,14 +365,13 @@ def test_ring_chunk_program_updates_the_kv_carry_in_place(
     same program held a slice of each layer out of the stack and a write
     of it back, every layer of every step: temporaries of 271 MB and
     543 MB.)"""
-    from paddle_tpu.flags import flags
     from paddle_tpu.inference.generate import LlamaDecoder
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     from paddle_tpu.obs.cost import program_census
 
-    # the routing asks for a TPU backend or this flag; the kernels are then
-    # compiled, not interpreted (compiled_not_interpreted)
-    monkeypatch.setattr(flags, "decode_attention_interpret", True)
+    # the routing asks for a TPU backend or the flag (decoder_kernels_routed);
+    # the kernels are then compiled, not interpreted
+    # (compiled_not_interpreted)
     layers, vocab, max_len, steps = 2, 512, 2048, 16
     model = LlamaForCausalLM(LlamaConfig(
         vocab_size=vocab, hidden_size=4096, intermediate_size=256,
@@ -403,7 +411,7 @@ def test_ring_chunk_program_updates_the_kv_carry_in_place(
 
 
 def test_looped_chunk_program_keeps_every_pass_cache_in_place(
-        tpu, monkeypatch):
+        tpu, decoder_kernels_routed):
     """A looped model's chunk program (models/ouro.py: 2 weight layers run
     3 times, so 6 cache layers; Ouro-2.6B's attention widths, small FFN and
     vocabulary) writes each pass's token rows into that pass's own buffer
@@ -411,12 +419,10 @@ def test_looped_chunk_program_keeps_every_pass_cache_in_place(
     indexed by a traced pass number and none is copied whole inside the
     step loop (PERF.md section 6, PR 28); one ``kv_row_write`` call a
     cache layer."""
-    from paddle_tpu.flags import flags
     from paddle_tpu.inference.generate import LlamaDecoder
     from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
     from paddle_tpu.obs.cost import program_census
 
-    monkeypatch.setattr(flags, "decode_attention_interpret", True)
     layers, passes, vocab, max_len, slots, steps = 2, 3, 512, 1024, 8, 16
     model = OuroForCausalLM(OuroConfig(
         vocab_size=vocab, hidden_size=2048, intermediate_size=256,
