@@ -10,6 +10,10 @@ IO and bench:
   harvest. A span or a phase is also a ``jax.profiler.TraceAnnotation``
   of the same name: a host event on the device trace's own clock
   whenever a profiler session is live, so no merge step is needed;
+  ``DeviceTimeline``, the always-on primitive behind the engine's
+  ``fed.chunk`` / ``fed.prefill`` / ``starved.<phase>`` / ``no_work``
+  seconds: one part open at a time, marked where an enqueue and a
+  blocking read return, tiling wall time;
 - :mod:`~paddle_tpu.obs.metrics` — typed metrics registry (counters /
   gauges / explicit-bucket histograms) with snapshot + Prometheus text
   export;
@@ -33,16 +37,23 @@ disabled path is a single enabled check per instrumented call (guarded
 by an overhead test). ``tools/trace_report.py`` renders an exported
 trace into per-phase / per-request summary tables.
 
-What an operator reads: ``ServingEngine.metrics()`` (``step_phase_s``,
-``live_kv_positions_total``, ``ttft_from_submit_*``, ``compiles``
-beside the older keys), the same instruments on
-``/metrics``, and ``serving.step.*`` / ``serving.admit.*`` in any
-profiler trace of a serving run. Device time itself is the
-benchmark's to measure (``benchmark/harness/trace.py``).
+What an operator reads: ``ServingEngine.metrics()`` (``step_phase_s``
+with each phase's ``max``, ``device_timeline_s`` — the seconds of
+``fed.chunk``, ``fed.prefill``, ``starved.admit`` / ``.dispatch`` /
+``.harvest`` / ``.outside`` and ``no_work``, which tile wall time —,
+``device_timeline_n``, ``device_timeline_long`` — the intervals of a
+second or more, each also a WARNING on the logger
+``paddle_tpu.serving`` —, ``live_kv_positions_total``,
+``ttft_from_submit_*``, ``compiles`` beside the older keys), the same
+instruments on ``/metrics`` (``serving.device.*``), and
+``serving.step.*`` / ``serving.admit.*`` in any profiler trace of a
+serving run. Device time itself is the benchmark's to measure
+(``benchmark/harness/trace.py``): the timeline says what the host
+knows, not what ran.
 """
 
 from paddle_tpu.obs.trace import (  # noqa: F401
-    Span, Tracer, obs_enabled, phase, span, tracer,
+    DeviceTimeline, Span, Tracer, obs_enabled, phase, span, tracer,
 )
 from paddle_tpu.obs.metrics import (  # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, metrics,
@@ -60,6 +71,7 @@ from paddle_tpu.obs.flight import (  # noqa: F401
 
 __all__ = [
     "Span", "Tracer", "tracer", "span", "phase", "obs_enabled",
+    "DeviceTimeline",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "metrics",
     "dispatch_cost", "site_costs", "clear_cost_cache",
     "device_peak_flops", "mfu", "program_census",

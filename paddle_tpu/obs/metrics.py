@@ -114,7 +114,7 @@ class Histogram:
     it, honest-best-effort beyond (``samples_dropped`` says when)."""
 
     __slots__ = ("name", "help", "buckets", "_counts", "_sum", "_count",
-                 "_samples", "samples_dropped", "_lock")
+                 "_max", "_samples", "samples_dropped", "_lock")
 
     def __init__(self, name: str, help_: str = "",
                  buckets: Optional[Sequence[float]] = None):
@@ -127,6 +127,7 @@ class Histogram:
         self._counts = [0] * (len(bs) + 1)   # +inf tail
         self._sum = 0.0
         self._count = 0
+        self._max = 0.0
         self._samples: collections.deque = collections.deque(
             maxlen=_SAMPLE_CAP)
         self.samples_dropped = 0
@@ -144,6 +145,8 @@ class Histogram:
             self._counts[i] += 1
             self._sum += v
             self._count += 1
+            if v > self._max or self._count == 1:
+                self._max = v
             if len(self._samples) == _SAMPLE_CAP:
                 self.samples_dropped += 1
             self._samples.append(v)
@@ -159,6 +162,12 @@ class Histogram:
     @property
     def mean(self) -> float:
         return self._sum / self._count if self._count else 0.0
+
+    @property
+    def max(self) -> float:
+        """The largest observation so far (0.0 before the first): what
+        the reservoir forgets and the buckets blur."""
+        return self._max
 
     def percentile(self, q: float) -> float:
         """q in [0, 100] over the raw-sample reservoir. An EMPTY

@@ -32,6 +32,7 @@ loadable) and ``export_jsonl`` (one span dict per line — the
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
@@ -40,7 +41,8 @@ from typing import Callable, Dict, List, Optional
 
 from jax.profiler import TraceAnnotation
 
-__all__ = ["Span", "Tracer", "tracer", "span", "phase", "obs_enabled"]
+__all__ = ["Span", "Tracer", "tracer", "span", "phase", "obs_enabled",
+           "DeviceTimeline", "LONG_INTERVAL_S"]
 
 
 def obs_enabled() -> bool:
@@ -331,9 +333,11 @@ class phase:
     ``observe(seconds)``) on exit, raised or not, and, with obs enabled,
     records the nested span in the ring as :func:`span` does. Disabled
     cost: one annotation enter/exit with no session live, two clock
-    reads and one observe."""
+    reads and one observe. ``t0`` and ``t1`` are those two readings: a
+    caller that marks something else at the same boundary (the engine's
+    :class:`DeviceTimeline`) takes them and reads no clock of its own."""
 
-    __slots__ = ("name", "hist", "_ann", "_span", "_t0")
+    __slots__ = ("name", "hist", "_ann", "_span", "t0", "t1")
 
     def __init__(self, name: str, hist):
         self.name = name
@@ -346,11 +350,124 @@ class phase:
                      else _NULL)
         self._ann.__enter__()
         self._span.__enter__()
-        self._t0 = time.monotonic()
+        self.t0 = time.monotonic()
         return self
 
     def __exit__(self, etype, exc, tb):
-        self.hist.observe(time.monotonic() - self._t0)
+        self.t1 = time.monotonic()
+        self.hist.observe(self.t1 - self.t0)
         self._span.__exit__(etype, exc, tb)
         self._ann.__exit__(None, None, None)
         return False
+
+
+# an interval of one part of a DeviceTimeline that lasts this long is a
+# stall: no chunk, prefill or host phase of any served configuration
+# comes near it (the longest prefill measured is 0.45 s)
+LONG_INTERVAL_S = 1.0
+_LONG_KEPT = 32
+
+
+class DeviceTimeline:
+    """The device's timeline as the HOST knows it: at every instant one
+    part is open, and each closed stretch of ``clock()`` seconds is added
+    to that part's counter (``counters[part].inc(seconds)``).
+
+    The host knows two things and marks them where they happen. An
+    enqueue has returned: ``fed(kind)`` — the device has work from now
+    on (``fed.<kind>``). A blocking read behind everything enqueued has
+    returned: ``drained()`` — the device is empty until the next
+    ``fed``, and the time goes to where the host is, ``host(where)``:
+    ``starved.<where>``, or ``no_work`` while ``where`` is None (nothing
+    submitted is unfinished, so nobody is kept waiting). An enqueue with
+    no blocking read after it simply stays fed until the next read, and
+    ``fed`` while fed closes that interval and opens its own kind. The
+    timeline cannot see a device that ran dry before the read returned,
+    gaps between the ops of a program, or the launch of one: a device
+    trace can; what it gives is every run's, traced or not.
+
+    Every boundary is one clock reading (``now``: a reading the caller
+    already took at that boundary), so the parts tile wall time from
+    construction: the counters' sum after ``flush()`` is the seconds
+    since then. An interval of ``LONG_INTERVAL_S`` or more in any part
+    but ``no_work`` (an idle server is not stalled, and its idle
+    stretches would push the real ones out) is kept in ``long`` — the
+    newest 32, ``{serial, part, seconds}``, ``serial`` the count of
+    intervals closed so far — and logged once at WARNING on ``log``.
+    ``intervals`` counts the closed intervals by part."""
+
+    __slots__ = ("_counters", "_clock", "_log", "_lock", "_fed", "_host",
+                 "_t_open", "_t_acc", "serial", "intervals", "long")
+
+    def __init__(self, counters, host: Optional[str] = None, log=None,
+                 clock: Callable[[], float] = time.monotonic):
+        self._counters = counters
+        self._clock = clock
+        self._log = log
+        self._lock = threading.Lock()
+        self._fed: Optional[str] = None     # kind of the newest enqueue
+        self._host = host                   # where the host is; None: idle
+        # the open interval began at _t_open; its seconds up to _t_acc
+        # are in the counter already (flush moves _t_acc alone, so a
+        # scrape in the middle of a stall does not cut it in two)
+        self._t_open = self._t_acc = clock()
+        self.serial = 0
+        self.intervals: Dict[str, int] = collections.Counter()
+        self.long: collections.deque = collections.deque(maxlen=_LONG_KEPT)
+
+    @property
+    def part(self) -> str:
+        if self._fed is not None:
+            return "fed." + self._fed
+        return "no_work" if self._host is None else "starved." + self._host
+
+    def _flush(self, now: float) -> None:
+        # a reading taken before another thread's flush is not after it
+        if now > self._t_acc:
+            self._counters[self.part].inc(now - self._t_acc)
+            self._t_acc = now
+
+    def _close(self, now: Optional[float]) -> None:
+        now = self._clock() if now is None else now
+        part = self.part
+        self._flush(now)
+        self.serial += 1
+        self.intervals[part] += 1
+        seconds = now - self._t_open
+        if seconds >= LONG_INTERVAL_S and part != "no_work":
+            self.long.append({"serial": self.serial, "part": part,
+                              "seconds": seconds})
+            if self._log is not None:
+                self._log.warning(
+                    "device timeline: %.3f s in %s (interval %d)",
+                    seconds, part, self.serial)
+        self._t_open = max(now, self._t_acc)
+
+    def fed(self, kind: str, now: Optional[float] = None) -> None:
+        """An enqueue of ``kind`` has returned."""
+        with self._lock:
+            self._close(now)
+            self._fed = kind
+
+    def drained(self, now: Optional[float] = None) -> None:
+        """A blocking read behind everything enqueued has returned."""
+        with self._lock:
+            if self._fed is not None:
+                self._close(now)
+                self._fed = None
+
+    def host(self, where: Optional[str],
+             now: Optional[float] = None) -> None:
+        """The host is ``where`` from now on (None: nothing submitted is
+        unfinished). Splits an interval only while the device is empty:
+        a fed device is fed wherever the host is."""
+        with self._lock:
+            if where != self._host:
+                if self._fed is None:
+                    self._close(now)
+                self._host = where
+
+    def flush(self, now: Optional[float] = None) -> None:
+        """Bring the open part's counter up to ``now``."""
+        with self._lock:
+            self._flush(self._clock() if now is None else now)

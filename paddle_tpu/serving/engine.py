@@ -79,6 +79,7 @@ import dataclasses
 import hashlib
 import io
 import json
+import logging
 import os
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -1012,6 +1013,39 @@ class ServingEngine:
                                "row-key readback behind the prefill "
                                "(device time), per readback"))}
         self._open_phase = None
+        # the device's timeline as this host knows it (obs.DeviceTimeline):
+        # fed from the return of an enqueue to the end of the blocking
+        # read that waits it out, starved between that read and the next
+        # enqueue — by the phase of step() the host is in, or outside
+        # step() with work unfinished — and no_work with none. The parts
+        # tile wall time; a second or more in one interval is a stall
+        self._c_timeline = {
+            part: r.counter(name, what) for part, name, what in (
+                ("fed.chunk", "serving.device.fed_s.chunk",
+                 "seconds from the return of a chunk's enqueue to the "
+                 "end of the blocking read of its tokens"),
+                ("fed.prefill", "serving.device.fed_s.prefill",
+                 "seconds from the return of an admission prefill's "
+                 "enqueue to the end of the read that waits it out (the "
+                 "row-key readback) or to the next enqueue"),
+                ("starved.admit", "serving.device.starved_s.admit",
+                 "seconds the device was known empty with the host in "
+                 "step()'s admit phase"),
+                ("starved.dispatch", "serving.device.starved_s.dispatch",
+                 "seconds the device was known empty with the host in "
+                 "step()'s dispatch phase"),
+                ("starved.harvest", "serving.device.starved_s.harvest",
+                 "seconds the device was known empty with the host in "
+                 "step()'s harvest phase"),
+                ("starved.outside", "serving.device.starved_s.outside",
+                 "seconds the device was known empty between two step() "
+                 "calls with work submitted and unfinished (the "
+                 "caller's time)"),
+                ("no_work", "serving.device.no_work_s",
+                 "seconds the device was known empty with nothing "
+                 "submitted that was unfinished"))}
+        self._timeline = obs.DeviceTimeline(
+            self._c_timeline, log=logging.getLogger("paddle_tpu.serving"))
         self._c_live_kv = r.counter(
             "serving.chunk.live_kv_positions",
             "sum over the occupied rows of the row's cache position at "
@@ -1488,11 +1522,12 @@ class ServingEngine:
                     f"deadline; shed at submit")
         rid = self._next_id
         self._next_id += 1
+        now = time.monotonic()
         req = Request(
             id=rid, prompt=prompt, max_new_tokens=int(max_new_tokens),
             eos_token_id=_normalize_eos(eos_token_id),
             temperature=float(temperature), seed=int(seed),
-            priority=int(priority), submit_time=time.monotonic(),
+            priority=int(priority), submit_time=now,
             latency_class=str(latency_class),
             slo_ttft_s=slo_ttft_s, slo_latency_s=slo_latency_s,
             deadline_s=deadline_s,
@@ -1516,6 +1551,7 @@ class ServingEngine:
         if on_tokens is not None:
             self._stream_cb[rid] = on_tokens
         self.scheduler.push(req)
+        self._mark_between_steps(now)
         self._g_qdepth.set(len(self.scheduler))
         obs.tracer.event("serving.request.queued", request=rid,
                          prompt_len=len(prompt),
@@ -1552,13 +1588,38 @@ class ServingEngine:
         """Close the running step's open phase and open ``name`` (None:
         close only). The phases follow each other without a gap, so
         their histograms tile ``step()``: admit -> dispatch -> wait ->
-        harvest; the degradation rungs go back to dispatch."""
+        harvest; the degradation rungs go back to dispatch. The device
+        timeline moves on the same clock reading: the end of ``wait`` is
+        the end of the blocking read behind the chunk (device known
+        empty), and ``wait`` itself is no place of the host's — it is
+        blocked on a fed device."""
         ph, self._open_phase = self._open_phase, None
+        now = None
         if ph is not None:
             ph.__exit__(None, None, None)
+            now = ph.t1
         if name is not None:
             self._open_phase = obs.phase(
                 "serving.step." + name, self._h_phase[name]).__enter__()
+            now = self._open_phase.t0
+        if name != "wait":
+            self._timeline.host(
+                self._between_steps() if name is None else name, now)
+        if ph is not None and ph.hist is self._h_phase["wait"]:
+            self._timeline.drained(now)
+
+    def _between_steps(self) -> Optional[str]:
+        """Where the device timeline has the host outside ``step()``:
+        ``outside`` with work submitted and unfinished, None with none."""
+        return "outside" if (len(self.scheduler)
+                             or self.scheduler.slots.occupied()) else None
+
+    def _mark_between_steps(self, now: Optional[float] = None) -> None:
+        """Work came or went outside ``step()`` (submit, restore,
+        absorb_rows, extract_rows): from here the caller keeps the device
+        waiting, or nobody does."""
+        if self._open_phase is None:
+            self._timeline.host(self._between_steps(), now)
 
     def _step(self, now: float) -> List[Tuple[int, Any]]:
         if self.adapter_store is not None and \
@@ -2087,6 +2148,7 @@ class ServingEngine:
                 self._req_from_meta(qm, npz[f"queue{j}_prompt"], now))
         self._next_id = int(meta["next_id"])
         self._g_qdepth.set(len(self.scheduler))
+        self._mark_between_steps()
         obs.tracer.event("serving.restore", path=path,
                          in_flight=len(meta["slots"]),
                          queued=len(meta["queue"]))
@@ -2282,6 +2344,7 @@ class ServingEngine:
         if rows:
             self._freeze_rows(rows)
         self._g_qdepth.set(len(self.scheduler))
+        self._mark_between_steps()
         meta = {
             "kind": "paddle_tpu.row_migration", "version": 1,
             "rows": len(inflight), "quant": self._b.quant,
@@ -2437,6 +2500,7 @@ class ServingEngine:
             self.scheduler.push(req)
             mapping[old_id] = req.id
         self._g_qdepth.set(len(self.scheduler))
+        self._mark_between_steps()
         self._c_migrated_in.inc(len(mapping))
         obs.tracer.event("serving.migrate.absorb", in_flight=n,
                          queued=len(meta["queue"]))
@@ -2701,6 +2765,7 @@ class ServingEngine:
         ev0 = self._b.event_count()
         with TraceAnnotation("serving.admit.prefill_enqueue"):
             self._b.ring_admit(ids, true_len, pos0, rows, aidx=aidxN)
+            self._timeline.fed("prefill")
             self._c_prefill.inc()
             self._count_eva_prefill(w, true_len)
             if self._spec_active:
@@ -2715,8 +2780,9 @@ class ServingEngine:
             # arrays, and the readback waits out the prefill enqueued
             # above: device time inside admit, kept apart as admit_wait
             with obs.phase("serving.admit.row_key",
-                           self._h_phase["admit_wait"]):
+                           self._h_phase["admit_wait"]) as read:
                 key1 = np.asarray(self._row_key(req))
+            self._timeline.drained(read.t1)
             self._ring_meta[rows[j]] = {
                 "slot": slot_idx, "pos": len(req.prompt),
                 "key": np.asarray(key1, np.uint32),
@@ -2814,6 +2880,7 @@ class ServingEngine:
         with TraceAnnotation("serving.admit.prefill_enqueue"):
             logitsN, kcN, vcN = self._b.admit_prefill(
                 ids, true_len, pos0, kcN, vcN, aidx=aidxN)
+            self._timeline.fed("prefill")
         self._c_prefill.inc()
         self._count_eva_prefill(w, true_len)
         if N > 1:
@@ -2939,6 +3006,7 @@ class ServingEngine:
                         f"serving.{self.replica_tag}.chunk")
                 toks, nv, self.state = self._b.decode_chunk_spec(
                     self.state, self.chunk_size, ring, K=self._k_now)
+                self._timeline.fed("chunk")
                 self._c_chunk.inc()
                 self._c_slot_steps.inc(self.num_slots * self.chunk_size)
                 self._ring_drained(n_staged)
@@ -2985,6 +3053,7 @@ class ServingEngine:
                     f"serving.{self.replica_tag}.chunk")
             toks, self.state = self._b.decode(self.state, self.chunk_size,
                                               ring)
+            self._timeline.fed("chunk")
             self._c_chunk.inc()
             self._c_slot_steps.inc(self.num_slots * self.chunk_size)
             self._ring_drained(n_staged)
@@ -3041,6 +3110,7 @@ class ServingEngine:
                         f"serving.{self.replica_tag}.step")
                 toks1, self.state = self._b.decode(self.state, 1, ring,
                                                    rung="step")
+                self._timeline.fed("chunk")
                 if s == 0 and n_staged:
                     self._ring_drained(n_staged)
                     ring, _ = self._ring_args()   # now empty
@@ -3441,8 +3511,24 @@ class ServingEngine:
         ``step()``: ``admit``, ``dispatch``, ``wait`` and ``harvest``
         tile it (``admit``'s count is the steps so far), and
         ``admit_wait`` is the part of ``admit`` spent blocked on the
-        row-key readback. ``wait`` and ``admit_wait`` are device time;
+        row-key readback; ``max`` is the phase's longest interval.
+        ``wait`` and ``admit_wait`` are device time;
         the host's own is admit - admit_wait + dispatch + harvest.
+        ``device_timeline_s`` is the device's timeline as the host knows
+        it (:class:`paddle_tpu.obs.DeviceTimeline`), the seconds of each
+        part up to this call: ``fed.chunk`` / ``fed.prefill`` from the
+        return of an enqueue to the end of the blocking read that waits
+        it out, ``starved.admit`` / ``.dispatch`` / ``.harvest`` the
+        device known empty with the host in that phase of ``step()``,
+        ``starved.outside`` empty between two ``step()`` calls with work
+        unfinished, ``no_work`` with none. The parts tile wall time since
+        the engine was built, so the difference of their sum between two
+        calls is the seconds between them. ``device_timeline_n`` counts
+        the closed intervals of each part, and ``device_timeline_long``
+        holds the newest 32 that lasted a second or more (``serial``,
+        ``part``, ``seconds``; each also logged at WARNING on
+        ``paddle_tpu.serving``) — ``no_work`` left out, an idle server
+        is not stalled.
         ``live_kv_positions_total`` is the cache
         positions live at the start of every chunk dispatched so far (token
         positions, whatever ``cache_layers`` each holds; a position's bytes
@@ -3462,6 +3548,7 @@ class ServingEngine:
         (``serving.moe.*``; zeros for any other),
         ``compiles`` this process's backend compiles by dispatch site."""
         qd, lat = self._h_qdelay, self._h_latency
+        self._timeline.flush()
         return {
             "num_slots": self.num_slots,
             "chunk_size": self.chunk_size,
@@ -3477,8 +3564,14 @@ class ServingEngine:
             # ALL rows compute every chunk step, occupied or not — the
             # honest denominator for useful-token occupancy comparisons
             "slot_steps_total": int(self._c_slot_steps.value),
-            "step_phase_s": {ph: {"sum": h.sum, "count": h.count}
+            "step_phase_s": {ph: {"sum": h.sum, "count": h.count,
+                                  "max": h.max}
                              for ph, h in self._h_phase.items()},
+            "device_timeline_s": {part: c.value for part, c
+                                  in self._c_timeline.items()},
+            "device_timeline_n": {part: self._timeline.intervals[part]
+                                  for part in self._c_timeline},
+            "device_timeline_long": list(self._timeline.long),
             "live_kv_positions_total": int(self._c_live_kv.value),
             "live_window_positions_total": int(self._c_live_win.value),
             "cache_layers": self._cache_layers,

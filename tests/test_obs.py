@@ -14,6 +14,11 @@ The load-bearing properties:
   overhead contract that lets the instrumentation live on hot paths);
 - ``phase`` always feeds its histogram, records a span only with obs
   on, and, like ``span``, shows up once in a live profiler trace;
+- ``DeviceTimeline`` holds one part at a time and its parts tile the
+  clock: fed from an enqueue's return to the read that waits it out,
+  starved where the host is in between, split at the host's phase
+  boundaries only while the device is empty; a second in one interval
+  is kept and logged once;
 - serving latency math is time.monotonic end-to-end (a scheduler-level
   push stamps the submit time itself).
 """
@@ -175,6 +180,172 @@ def test_phase_feeds_its_histogram_and_records_only_when_enabled(enabled):
         assert "error" in spans[2].attrs     # as obs.span records it
     finally:
         set_flags({"obs_enabled": False})
+
+
+# -- the device timeline, alone, on a clock the test moves -------------------
+
+class _Seconds:
+    """What a counter of seconds has to be: ``inc(seconds)``."""
+
+    def __init__(self):
+        self.value = 0.0
+
+    def inc(self, s):
+        assert s >= 0
+        self.value += s
+
+
+PARTS = ("fed.chunk", "fed.prefill", "starved.admit", "starved.dispatch",
+         "starved.harvest", "starved.outside", "no_work")
+
+
+def _timeline(log=None):
+    now = [100.0]
+    counters = {p: _Seconds() for p in PARTS}
+    tl = obs.DeviceTimeline(counters, log=log, clock=lambda: now[0])
+
+    def at(t=None):                 # move the clock (None: leave it)
+        if t is not None:
+            now[0] = 100.0 + t
+        return now[0]
+
+    def seconds():
+        return {p: round(c.value, 9) for p, c in counters.items()
+                if c.value}
+    return tl, at, seconds
+
+
+def test_device_timeline_holds_one_part_and_tiles_the_clock():
+    tl, at, seconds = _timeline()
+    assert tl.part == "no_work"
+    tl.host("outside", at(1.0))            # a submit
+    tl.host("admit", at(1.5))              # step() begins
+    tl.fed("prefill", at(1.7))             # the prefill's enqueue returned
+    tl.drained(at(2.4))                    # the row-key readback returned
+    tl.host("dispatch", at(2.5))
+    tl.fed("chunk", at(2.6))
+    tl.host("harvest", at(4.0))            # wait ends: one reading for
+    tl.drained(at())                       # the phase and the read
+    tl.host(None, at(4.3))                 # step() ends, nothing left
+    assert tl.part == "no_work"
+    tl.flush(at(5.0))
+    got = seconds()
+    assert got == {"no_work": 1.7, "starved.outside": 0.5,
+                   "starved.admit": 0.3, "fed.prefill": 0.7,
+                   "starved.dispatch": 0.1, "fed.chunk": 1.4,
+                   "starved.harvest": 0.3}
+    assert sum(got.values()) == pytest.approx(5.0, abs=1e-9)
+    # one interval each, but admit's two (before and after the prefill)
+    assert tl.intervals["starved.admit"] == 2 and tl.serial == 8
+    assert tl.intervals["fed.chunk"] == tl.intervals["fed.prefill"] == 1
+
+
+def test_device_timeline_splits_at_a_phase_boundary_only_while_empty():
+    tl, at, seconds = _timeline()
+    tl.host("admit", at(0.0))
+    tl.host("dispatch", at(0.25))          # empty: admit | dispatch
+    tl.fed("chunk", at(0.5))
+    tl.host("harvest", at(2.0))            # fed: the chunk is not cut
+    tl.host("admit", at(3.0))
+    assert tl.part == "fed.chunk" and tl.intervals["fed.chunk"] == 0
+    tl.drained(at(3.5))
+    assert tl.part == "starved.admit"
+    tl.flush(at(4.0))
+    assert seconds() == {"starved.admit": 0.75, "starved.dispatch": 0.25,
+                         "fed.chunk": 3.0}
+
+
+def test_device_timeline_double_marks_are_harmless():
+    tl, at, seconds = _timeline()
+    tl.host("admit", at(0.0))
+    tl.drained(at(0.1))                    # empty already: nothing
+    tl.drained(at(0.2))
+    assert tl.serial == 1 and tl.part == "starved.admit"
+    tl.fed("prefill", at(1.0))
+    tl.fed("prefill", at(2.0))             # closes one, opens its own
+    tl.fed("chunk", at(2.5))               # and so does another kind
+    tl.host("admit", at(2.75))
+    tl.host("admit", at(2.8))
+    tl.drained(at(3.0))
+    tl.drained(at(3.5))
+    tl.flush(at(4.0))
+    assert tl.intervals["fed.prefill"] == 2 and tl.intervals["fed.chunk"] == 1
+    assert seconds() == {"starved.admit": 2.0, "fed.prefill": 1.5,
+                         "fed.chunk": 0.5}
+
+
+def test_device_timeline_keeps_and_logs_a_long_interval_once(caplog):
+    import logging
+    log = logging.getLogger("paddle_tpu.test_timeline")
+    tl, at, seconds = _timeline(log)
+    with caplog.at_level(logging.WARNING, logger=log.name):
+        tl.host("harvest", at(3.0))        # 3 s of no_work: idle, no stall
+        tl.flush(at(3.4))                  # a scrape inside the interval
+        tl.flush(at(3.9))                  # does not cut it in two
+        tl.host("outside", at(4.2))        # 1.2 s in starved.harvest
+        tl.fed("chunk", at(4.3))
+        tl.drained(at(5.2))                # 0.9 s: not long
+        assert tl.long[-1] == {"serial": 2, "part": "starved.harvest",
+                               "seconds": pytest.approx(1.2)}
+        assert len(tl.long) == 1 and len(caplog.records) == 1
+        assert "starved.harvest" in caplog.records[0].getMessage()
+        for k in range(40):                # the newest 32 are kept
+            tl.fed("prefill", at(10.0 + 2 * k))
+    assert len(tl.long) == 32 and tl.long[-1]["part"] == "fed.prefill"
+    assert [e["serial"] for e in tl.long] == list(range(13, 45))
+    assert obs.trace.LONG_INTERVAL_S == 1.0
+    assert sum(seconds().values()) == pytest.approx(88.0)
+
+
+def test_device_timeline_tiles_under_a_scraping_thread():
+    """``metrics()`` flushes from whatever thread scrapes while the engine
+    thread marks: no second is lost or counted twice, no counter goes
+    back (a lost update would break the tiling)."""
+    import sys
+    import threading
+    counters = {p: _Seconds() for p in PARTS}
+    t_made = time.monotonic()
+    tl = obs.DeviceTimeline(counters, host="outside")
+    stop = threading.Event()
+
+    def scrape():
+        while not stop.is_set():
+            tl.flush()
+    threads = [threading.Thread(target=scrape) for _ in range(8)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        end = time.monotonic() + 0.2
+        while time.monotonic() < end:
+            tl.host("admit", time.monotonic())
+            tl.fed("prefill")
+            tl.drained(time.monotonic())
+            tl.host("dispatch")
+            tl.fed("chunk")
+            tl.host("harvest")
+            tl.drained()
+            tl.host("outside")
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=10)
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads)
+    tl.flush()
+    wall = time.monotonic() - t_made
+    total = sum(c.value for c in counters.values())
+    assert total <= wall and total == pytest.approx(wall, abs=2e-3)
+    assert tl.intervals["fed.chunk"] == tl.intervals["fed.prefill"] > 10
+
+
+def test_histogram_keeps_its_largest_observation():
+    h = MetricsRegistry().histogram("m")
+    assert h.max == 0.0
+    for v in (0.25, 3.5, 0.5):
+        h.observe(v)
+    assert h.max == 3.5 and h.count == 3
 
 
 def test_phase_disabled_path_bounded_cost():

@@ -10,6 +10,11 @@ The load-bearing properties:
   inside the caller's, and the counts taken at the same boundaries are
   right: live KV positions per chunk, a request's first token from
   submit, backend compiles by dispatch site;
+- the device's timeline as the engine knows it tiles wall time between
+  two ``metrics()`` calls, puts a host delay in the part it fell in
+  (a callback's in ``starved.harvest``, the caller's in
+  ``starved.outside``, an idle engine's in ``no_work``), counts one fed
+  interval a dispatch, and keeps and logs an interval of a second;
 - TTFT/TPOT histograms and per-class SLO violation counters are
   correct on a deterministic serve run;
 - the flight recorder dumps a postmortem JSON (spans + resilience
@@ -217,6 +222,162 @@ def test_live_kv_positions_match_a_hand_count(served):
     rows = m["occupancy_mean"] * m["occupancy_samples"] * m["num_slots"]
     assert rows == pytest.approx(7)
     assert served["m0"]["live_kv_positions_total"] == 0
+
+
+# -- the device's timeline as the engine knows it ----------------------------
+
+STARVED = ("starved.admit", "starved.dispatch", "starved.harvest",
+           "starved.outside")
+
+
+def _timeline_delta(m0, m1):
+    return {p: m1["device_timeline_s"][p] - m0["device_timeline_s"][p]
+            for p in m1["device_timeline_s"]}
+
+
+@pytest.fixture(params=["ring", "host_scatter"])
+def warm(request, dec):
+    """An engine of each admission mode that has served once: nothing
+    compiles from here on."""
+    eng = _engine(dec, request.param)
+    eng.submit(np.arange(3) % 64, 6)
+    eng.drain()
+    return eng
+
+
+def test_device_timeline_tiles_wall_time(warm):
+    import time
+    reads = []
+
+    def clock():                 # metrics() brings the open part up to
+        reads.append(time.monotonic())      # a reading of this clock
+        return reads[-1]
+    warm._timeline._clock = clock
+    m0 = warm.metrics()
+    t0 = reads[-1]
+    assert list(m0["device_timeline_s"]) == [
+        "fed.chunk", "fed.prefill", *STARVED, "no_work"]
+    for i, (P, N) in enumerate(SERVED):
+        warm.submit(np.arange(P) % 64, N, seed=i)
+        time.sleep(0.002)
+    while len(warm.scheduler) or warm.scheduler.slots.occupied():
+        warm.step()
+        time.sleep(0.001)
+    m1 = warm.metrics()
+    d = _timeline_delta(m0, m1)
+    assert all(v >= 0 for v in d.values()) and d["fed.chunk"] > 0
+    assert sum(d.values()) == pytest.approx(reads[-1] - t0, rel=1e-6)
+    # the registry a Prometheus scrape reads carries the same seconds
+    txt = warm.registry.to_prometheus()
+    for name in ("serving_device_fed_s_chunk", "serving_device_no_work_s",
+                 "serving_device_starved_s_outside"):
+        assert f"# TYPE {name} counter" in txt, name
+
+
+def test_a_delay_lands_in_the_part_it_fell_in(warm):
+    """0.05 s slept in a token callback is the harvest phase's, between
+    two steps the caller's, and with nothing submitted nobody's."""
+    import time
+    calls = []
+
+    def slow(rid, new, final):
+        calls.append(final)
+        time.sleep(0.05)
+    m0 = warm.metrics()
+    warm.submit(np.arange(3) % 64, 8, on_tokens=slow)
+    warm.drain()
+    m1 = warm.metrics()
+    d = _timeline_delta(m0, m1)
+    chunks = m1["chunk_dispatches"] - m0["chunk_dispatches"]
+    assert chunks == 2 and calls == [False, True]
+    assert d["starved.harvest"] >= 0.05 * chunks
+    assert all(d[p] < 0.04 for p in STARVED if p != "starved.harvest"), d
+    assert d["no_work"] < 0.04
+    assert m1["step_phase_s"]["harvest"]["max"] >= 0.05
+    # the caller's time: a request waits while nobody steps
+    warm.submit(np.arange(3) % 64, 4)
+    time.sleep(0.05)
+    warm.drain()
+    m2 = warm.metrics()
+    d = _timeline_delta(m1, m2)
+    assert d["starved.outside"] >= 0.05 and d["no_work"] < 0.04
+    assert all(d[p] < 0.04 for p in STARVED if p != "starved.outside"), d
+    # and nobody's: nothing is submitted that is unfinished
+    time.sleep(0.05)
+    warm.step()                              # an idle step changes nothing
+    d = _timeline_delta(m2, warm.metrics())
+    assert d["no_work"] >= 0.05
+    assert all(d[p] < 0.04 for p in STARVED), d
+    assert d["fed.chunk"] == d["fed.prefill"] == 0
+
+
+def test_one_fed_interval_a_dispatch(served):
+    m = served["m1"]
+    n = m["device_timeline_n"]
+    assert n["fed.prefill"] == m["prefill_dispatches"] == len(SERVED)
+    assert n["fed.chunk"] == m["chunk_dispatches"]
+    assert served["m0"]["device_timeline_n"]["fed.chunk"] == 0
+    # every phase's longest interval is one of its intervals
+    for ph, v in m["step_phase_s"].items():
+        if v["count"]:
+            assert v["sum"] / v["count"] <= v["max"] <= v["sum"], ph
+
+
+@pytest.mark.faults
+def test_per_token_rung_is_one_fed_interval_a_step(dec):
+    from paddle_tpu.runtime.resilience import fault_injector
+    eng = _engine(dec, "ring")
+    set_flags({"resilience_backoff_s": 0.0})
+    fault_injector.configure([{"kind": "dispatch_error",
+                               "site": "decode.chunk", "call": 1,
+                               "times": 1000}])
+    try:
+        eng.submit(np.arange(3) % 64, 8)
+        eng.drain()
+    finally:
+        fault_injector.clear()
+        set_flags({"resilience_backoff_s": 0.5})
+    m = eng.metrics()
+    assert m["chunk_dispatches"] == 0 and m["step_dispatches"] == 8
+    assert m["device_timeline_n"]["fed.chunk"] == 8
+    assert m["device_timeline_n"]["fed.prefill"] == 1
+    # between two steps of the rung the host is back in dispatch
+    assert m["device_timeline_n"]["starved.dispatch"] == 8
+
+
+def test_an_interval_of_a_second_is_kept_and_logged_once(
+        warm, monkeypatch, caplog):
+    """A clock the test moves (no sleep of a second): 2 s pass inside a
+    token callback, so one harvest interval is a stall."""
+    import logging
+    import time
+    import types
+
+    import paddle_tpu.obs.trace as trace_mod
+    ahead = [0.0]
+    shim = types.SimpleNamespace(
+        monotonic=lambda: time.monotonic() + ahead[0],
+        monotonic_ns=lambda: time.monotonic_ns() + int(ahead[0] * 1e9))
+    monkeypatch.setattr(trace_mod, "time", shim)
+    warm._timeline._clock = shim.monotonic
+
+    def stall(rid, new, final):
+        if not final:
+            ahead[0] += 2.0
+    assert warm.metrics()["device_timeline_long"] == []
+    warm.submit(np.arange(3) % 64, 8, on_tokens=stall)
+    with caplog.at_level(logging.WARNING, logger="paddle_tpu.serving"):
+        warm.drain()
+        m = warm.metrics()
+        m_again = warm.metrics()
+    (kept,) = m["device_timeline_long"]
+    assert kept["part"] == "starved.harvest" and 2.0 <= kept["seconds"] < 3.0
+    assert kept["serial"] <= sum(m["device_timeline_n"].values())
+    assert m_again["device_timeline_long"] == [kept]
+    said = [r for r in caplog.records if "device timeline" in r.getMessage()]
+    assert len(said) == 1 and "starved.harvest" in said[0].getMessage()
+    assert m["step_phase_s"]["harvest"]["max"] >= 2.0
+    assert m["step_phase_s"]["wait"]["max"] < 1.0
 
 
 @pytest.mark.parametrize("mesh", [None, "tp:2"])
