@@ -4,9 +4,9 @@ Capability analog of the reference's decode stack —
 paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu
 (block-table KV cache attention) and the fused generation ops — in the
 TPU-native form: a PURE functional forward with a statically-shaped KV
-cache — token-major ``(B, max_len, KV, D)`` for MHA, head-major
-``(B, KV, max_len, D)`` for GQA (the decode-kernel layout); one buffer
-per cache layer (a weight layer, times the passes of a looped model:
+cache — head-major ``(B, KV, max_len, D)``, GQA and MHA alike (the
+decode-kernel layout); one buffer per cache layer (a weight layer,
+times the passes of a looped model:
 ``LlamaConfig.num_cache_layers``), so that a step writes its token rows
 into each buffer in place (one array stacked over layers cost a copy of
 the whole cache out of and back into the stack every step, 35 % of a
@@ -17,11 +17,12 @@ ONE cached-compile XLA program (no recompiles across steps; static shapes
 are what the MXU wants). Block tables are unnecessary: XLA owns memory, and
 a padded dense cache + position mask is the layout it tiles best.
 
-Decode attention: MHA runs XLA's masked dense read (a bandwidth-bound
-matvec it fuses well); GQA routes through the Pallas decode-attention
-kernel (ops/pallas/decode_attention.py — no repeated-KV
-materialization). The Pallas flash kernel covers chunked prefill
-(bottom-right-aligned causal, sq != sk).
+Decode attention: GQA and MHA (``rep = 1``) route through the Pallas
+decode-attention kernel (ops/pallas/decode_attention.py — no repeated-KV
+materialization, only a row's live positions read) where the routing
+takes it; else XLA's masked dense read over the whole buffer. The Pallas
+flash kernel covers chunked prefill (bottom-right-aligned causal,
+sq != sk).
 
 Positions may be a traced scalar (the classic lockstep decode) OR a
 per-row ``(B,)`` vector: speculative decoding accepts a variable number
@@ -460,9 +461,8 @@ def _kv_attention(cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
     L = cfg.cache_len(ci, max_len)
 
     rep = H // KV
-    # GQA: (B, KV, L, D) tiles feed the Pallas kernel; MHA keeps
-    # token-major (B, L, KV, D), unless the config's attention goes
-    # through the kernel at rep = 1 too: one predicate, the config's
+    # (B, KV, L, D) tiles feed the Pallas kernel, GQA and MHA (rep = 1)
+    # alike: the layout is the config's one predicate
     head_major = cfg.cache_head_major
     kt = jnp.swapaxes(k, 1, 2) if head_major else k
     vt = jnp.swapaxes(v, 1, 2) if head_major else v
@@ -500,7 +500,7 @@ def _kv_attention(cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
     if fresh:
         out = _fresh_attention(q, k, v, cfg.layer_window(li), sharded)
     elif use_kernel:
-        # one-kernel GQA cache attention (block_multi_head_attention
+        # one-kernel cache attention, GQA or MHA (block_multi_head_attention
         # capability): no repeated-KV materialization, online softmax,
         # cache blocks past a row's valid prefix neither fetched nor
         # computed; ``pos`` may be per-row (the chunked serving path,
@@ -1597,8 +1597,7 @@ class LlamaDecoder:
         """Zeroed K and V caches for ``B`` rows: per cache a tuple of
         ``cfg.num_cache_layers`` buffers — one per weight layer per pass
         over the layers, so ``num_hidden_layers`` of them for every model
-        but a looped one — head-major ``(B, KV, L, D)`` for GQA,
-        token-major ``(B, L, KV, D)`` for MHA
+        but a looped one — head-major ``(B, KV, L, D)``
         (``LlamaConfig.cache_head_major``), ``L`` being ``max_len`` or,
         for a windowed layer, its window. An EVA config
         (``cfg.eva``) gets ``cfg.cache_leaves`` = 2 buffers a cache layer,

@@ -71,7 +71,6 @@ class EvabyteConfig(LlamaConfig):
     fp32_skip_add = True
     has_windows = True      # S > 1 is a prefill from position 0; what
     #                         addresses cache rows by position refuses it
-    cache_head_major = True  # the decode kernel's layout, at rep = 1 too
 
     def __post_init__(self):
         if self.attention_class != "eva":

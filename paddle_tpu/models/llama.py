@@ -96,11 +96,12 @@ class LlamaConfig:
     def cache_head_major(self) -> bool:
         """The layout of a cache buffer: head-major ``(B, KV, L, D)``,
         whose blocks of positions are contiguous tiles for the decode
-        attention kernel, under GQA; token-major ``(B, L, KV, D)`` under
-        MHA, which XLA's fused matvec prefers (measured) and where no
-        kernel reads the cache. A config whose MHA attention does go
-        through the kernel answers True itself (models/evabyte.py)."""
-        return self.num_attention_heads != self.num_key_value_heads
+        attention kernel, which reads only a row's live positions — under
+        GQA and under MHA alike (``rep = H // KV`` query heads a KV head,
+        1 for MHA). Which program reads the cache is the routing's
+        (``generate._decode_kernels``, each kernel's ``supported``), not
+        the layout's."""
+        return True
 
     def cache_len(self, ci: int, max_len: int) -> int:
         """Positions cache layer ``ci`` holds in a decoder of ``max_len``:

@@ -16,14 +16,15 @@ serving cells' own cache shapes: PERF.md section 6, PR 34):
   position ``at[b]`` of row ``b`` and nothing else of the caches moves,
 - both caches are aliased in and out (``input_output_aliases``): the
   buffers stay where they are, as a donated carry's must,
-- token-major ``(B, L, KV, D)`` (MHA): a position is a whole ``(KV, D)``
-  slab, so the block is that slab and the body copies the new row into
-  it; the cache is never read (its input stays in HBM, ``pl.ANY``),
-- head-major ``(B, KV, L, D)`` (GQA; a rolling buffer is the same with
-  the caller's ``pos % L``): a position is ONE ROW of a packed sublane
-  tile, so the block is the tile of ``T`` positions around it (16 / 8 /
-  32 for 2- / 4- / 1-byte dtypes), read, the row at ``at[b] % T``
-  replaced by a select, and written back.
+- token-major ``(B, L, KV, D)`` (no decoder builds it: ROADMAP D15): a
+  position is a whole ``(KV, D)`` slab, so the block is that slab and
+  the body copies the new row into it; the cache is never read (its
+  input stays in HBM, ``pl.ANY``),
+- head-major ``(B, KV, L, D)`` (every decoder's cache; a rolling buffer
+  is the same with the caller's ``pos % L``): a position is ONE ROW of a
+  packed sublane tile, so the block is the tile of ``T`` positions around
+  it (16 / 8 / 32 for 2- / 4- / 1-byte dtypes), read, the row at
+  ``at[b] % T`` replaced by a select, and written back.
 
 ``at`` is clamped to ``[0, L - 1]``, which is what the scatter's clip
 mode does (a row past its budget sits at ``max_len - 1``). The bytes

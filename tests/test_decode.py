@@ -271,7 +271,7 @@ def test_beam_search_eos_freezes_beams():
 @pytest.mark.parametrize("kv_heads", [2, 4], ids=["gqa", "mha"])
 def test_serving_carry_and_ring_are_one_buffer_per_layer(kv_heads):
     """The KV carry and the admission ring hold one 4-D buffer per layer
-    (head-major for GQA, token-major for MHA) through a whole serve —
+    (head-major, GQA and MHA alike since PR 39) through a whole serve —
     six requests through two slots, so admissions land mid-stream and the
     rows of a chunk sit at different cache positions — and every request
     gets the tokens of the per-token reference."""
@@ -287,8 +287,7 @@ def test_serving_carry_and_ring_are_one_buffer_per_layer(kv_heads):
                                    for p, new in reqs])
 
     slots, D = 2, cfg.head_dim
-    per_layer = ((slots, kv_heads, 48, D) if kv_heads < 4     # head-major
-                 else (slots, 48, kv_heads, D))               # token-major
+    per_layer = (slots, kv_heads, 48, D)                      # head-major
     eng = ServingEngine(dec, num_slots=slots, chunk_size=4)
     rids = [eng.submit(p, new) for p, new in reqs]
     got, uneven = {}, False

@@ -343,37 +343,101 @@ def test_decode_attention_block_follows_the_vmem_budget(case):
     assert supported(q, kc) == (_block_len(256, L, KV, D, itemsize, quant) > 0)
 
 
-def test_decode_attention_chunked_path_parity():
-    """The chunked decode path routes the SAME decode-attention kernel
-    (per-row pos — no second kernel entry point) behind
-    FLAGS_use_decode_attention: with the flag on (interpret mode off-TPU
-    via FLAGS_decode_attention_interpret) and off, the chunked GQA
-    decode emits identical tokens, fp32 and int8wk alike."""
-    import paddle_tpu as paddle
+def _parity_decoder(family, quant=None):
+    """Tiny decoders of the three cache families the decode kernel reads:
+    GQA (2 KV heads for 4), MHA (4 for 4: ``rep = 1``, head-major since
+    PR 39) and a looped model (OURO_TINY: MHA, 2 layers x 4 passes = 8
+    cache layers). max_len 128: the kernel's ``L % 128 == 0`` bound.
+    Flags are read at trace time: build one per flag setting."""
     from paddle_tpu.inference.generate import LlamaDecoder
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.models.ouro import OURO_TINY, OuroForCausalLM
 
-    cfg = LlamaConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
-                      num_hidden_layers=2, num_attention_heads=4,
-                      num_key_value_heads=2,   # GQA -> kernel-eligible
-                      max_position_embeddings=256)
     paddle.seed(9)
-    model = LlamaForCausalLM(cfg)
-    ids = np.random.default_rng(5).integers(0, 64, (2, 4))
-    for quant in (None, "int8wk"):
-        paddle.set_flags({"use_decode_attention": True,
+    if family == "looped":
+        model = OuroForCausalLM(OURO_TINY)
+    else:
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=64, hidden_size=32, intermediate_size=64,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=2 if family == "gqa" else 4,
+            max_position_embeddings=256))
+    return model, lambda: LlamaDecoder(model, max_len=128, quant=quant)
+
+
+@pytest.mark.parametrize("family,quant", [
+    ("gqa", None), ("gqa", "int8wk"), ("mha", None), ("looped", None)])
+def test_decode_attention_chunked_path_parity(family, quant):
+    """The chunked decode path routes the SAME decode-attention kernel
+    (per-row pos — no second kernel entry point) behind
+    FLAGS_use_decode_attention, GQA and (since PR 39, whose MHA cache is
+    head-major) MHA at ``rep = 1`` and a looped model's pass caches alike:
+    with the flag on (interpret mode off-TPU via
+    FLAGS_decode_attention_interpret) and off, the chunked decode emits
+    identical tokens and a carry's logits within the kernel's tolerance."""
+    model, build = _parity_decoder(family, quant)
+    V = model.config.vocab_size
+    ids = np.random.default_rng(5).integers(0, V, (2, 4))
+    got = {}
+    for on in (True, False):
+        paddle.set_flags({"use_decode_attention": on,
                           "decode_attention_interpret": True})
         try:
-            # max_len 128: the kernel's L % 128 == 0 eligibility bound
-            dec_on = LlamaDecoder(model, max_len=128, quant=quant)
-            on = np.asarray(dec_on.generate(ids, 8, chunk_size=3))
-            paddle.set_flags({"use_decode_attention": False})
-            dec_off = LlamaDecoder(model, max_len=128, quant=quant)
-            off = np.asarray(dec_off.generate(ids, 8, chunk_size=3))
+            dec = build()
+            toks = np.asarray(dec.generate(ids, 8, chunk_size=3))
+            _, st = dec.decode_chunk(dec.init_decode_state(ids), 5)
+            got[on] = toks, np.asarray(st.logits)
         finally:
             paddle.set_flags({"use_decode_attention": True,
                               "decode_attention_interpret": False})
-        np.testing.assert_array_equal(on, off, err_msg=f"quant={quant}")
+    np.testing.assert_array_equal(got[True][0], got[False][0],
+                                  err_msg=f"{family} quant={quant}")
+    np.testing.assert_allclose(got[True][1], got[False][1],
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("family", ["gqa", "mha", "looped"])
+def test_chunk_program_holds_decode_attention_once_a_cache_layer(
+        family, monkeypatch):
+    """What shows that the route engages (decided at trace time, so in
+    every step of a program or in none): the chunk program calls
+    ``decode_attention`` once per cache layer with the interpret flag on
+    (2 / 2 / 8), and not at all with ``use_decode_attention`` off.
+    Counted where the program is traced, by wrapping the kernel."""
+    from paddle_tpu.ops.pallas import decode_attention as da
+
+    calls = []
+    real = da.decode_attention
+
+    def counted(*a, **k):
+        calls.append(a[1].shape)
+        return real(*a, **k)
+
+    monkeypatch.setattr(da, "decode_attention", counted)
+    model, build = _parity_decoder(family)
+    cfg = model.config
+    B = 2
+    for on, want in ((True, cfg.num_cache_layers), (False, 0)):
+        calls.clear()
+        paddle.set_flags({"use_decode_attention": on,
+                          "decode_attention_interpret": True})
+        try:
+            dec = build()
+            kc, vc = dec._empty_cache(B)
+            z = jnp.zeros
+            dec._ring_chunk_decode._jitted.lower(
+                dec.params, z((B, cfg.vocab_size), jnp.float32), kc, vc,
+                z((B,), jnp.int32), z((B, 2), jnp.uint32),
+                z((B,), jnp.bool_), z((B,), jnp.int32),
+                z((B,), jnp.float32), None, *(None,) * 9, steps=4,
+                do_sample=False, top_k=None, top_p=None)
+        finally:
+            paddle.set_flags({"use_decode_attention": True,
+                              "decode_attention_interpret": False})
+        assert len(calls) == want, (family, on, calls)
+        # every call reads a head-major (B, KV, max_len, D) buffer
+        assert set(calls) <= {(B, cfg.num_key_value_heads, 128,
+                               cfg.head_dim)}
 
 
 def test_group_norm_silu_fused_matches_unfused():

@@ -196,19 +196,21 @@ def _serve(model, quant=None):
 def test_decoder_serves_the_same_with_the_kernel_and_without(
         kernel_calls, kind):
     """Greedy tokens and the carry's caches, leaf for leaf, with the
-    row write routed to the kernel and with the predicate false: GQA
-    (head-major), MHA (token-major), a looped model's layers x passes
-    buffers, a windowed model's rolling buffers (window 8: every row
-    wraps), and an int8 cache, whose int8 leaf takes the kernel and whose
-    scale leaf keeps XLA's scatter."""
+    row write routed to the kernel and with the predicate false: GQA,
+    MHA (head-major too since PR 39: every decoder's cache is), a looped
+    model's layers x passes buffers, a windowed model's rolling buffers
+    (window 8: every row wraps), and an int8 cache, whose int8 leaf takes
+    the kernel and whose scale leaf keeps XLA's scatter. The token-major
+    form has no decoder caller left; test_other_writes_keep_xlas_form
+    and the kernel's own cases hold it."""
     model = _model(kind)
     quant = "int8wk" if kind.endswith("int8wk") else None
     toks_on, caches_on, dec = _serve(model, quant)
     layers = dec.cfg.num_cache_layers
-    head_major = dec.cfg.num_attention_heads != dec.cfg.num_key_value_heads
-    # once a cache layer in each traced chunk program (steps 4 and fewer)
+    # once a cache layer in each traced chunk program (steps 4 and fewer),
+    # head-major whatever the heads
     assert kernel_calls and len(kernel_calls) % layers == 0
-    assert set(kernel_calls) == {head_major}
+    assert set(kernel_calls) == {True}
     if kind == "windowed":
         assert {b.shape[2] for b in dec._empty_cache(1)[0]} == {8, 64}
     n = len(kernel_calls)

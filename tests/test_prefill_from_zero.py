@@ -116,7 +116,8 @@ def test_ring_admission_equals_solo_prefill_and_the_old_route(dec):
         np.testing.assert_allclose(got[j], np.asarray(solo[0]), atol=2e-5)
         assert got[j].argmax() == int(np.asarray(solo[0]).argmax())
     # the buffers: a row's first true_len positions, every cache layer
-    tokens = 1 if dec.cfg.cache_head_major else 0     # axis, within a row
+    assert dec.cfg.cache_head_major          # every family, since PR 39
+    tokens = 1                               # positions' axis of (KV, L, D)
     for new, ref in zip(rkc + rvc, okc + ovc):
         for j, n in enumerate(lens):
             a, b = (np.take(np.asarray(x[j]), range(n), axis=tokens)
@@ -252,8 +253,9 @@ def test_suffix_prefill_attends_over_the_buffer_and_matches_a_cold_prefill(
 
 # the speculative round's lowered text (K = 3, one row, 'skip:1' draft) on
 # the parent commit (cb49b35): draft steps and the verify's S = K + 1
-# forward at pos > 0 are none of this PR's entries
-_SPEC_ROUND_TEXT = {"gqa": "38d62f7e126e1d8b", "mha": "a299eb70fd981d61"}
+# forward at pos > 0 are none of PR 37's entries. "mha" RETAKEN by PR 39
+# on its own tree: the MHA caches it reads and writes are head-major now
+_SPEC_ROUND_TEXT = {"gqa": "38d62f7e126e1d8b", "mha": "1da5c487e8b15d69"}
 
 
 def _spec_round_text(dec):
@@ -306,11 +308,17 @@ def test_a_quantized_cache_keeps_its_prefill():
 
 # the lowered text of each configuration's chunk program (4 steps, 2 rows,
 # max_len 128) on the parent commit (cb49b35); the first, third and fourth
-# are the ones tests/test_evabyte.py has held since PR 36
+# are the ones tests/test_evabyte.py has held since PR 36. "mha" and
+# "looped" were RETAKEN by PR 39 on its own tree, which meant to move them:
+# an MHA cache is head-major now (LlamaConfig.cache_head_major), so its
+# buffers, its row write and XLA's attention over it are laid out as GQA's
+# (off the TPU the decode kernel is not routed: this is the XLA form).
+# "gqa", "afmoe" and "evabyte" are the parent's still: the programs of the
+# GQA, AFMoE and EvaByte cells did not move by a byte
 _CHUNK_TEXT = {
     "gqa": "e3fe9ce2b00457d9",
-    "mha": "2a7154bdfbedfb5e",
-    "looped": "b99dbdde1522714b",
+    "mha": "0f68522e3360948c",
+    "looped": "3f1a2be5c433c459",
     "afmoe": "f72d6133ef314834",
     "evabyte": "356c1db56d7bbd60",
 }

@@ -200,6 +200,34 @@ def test_snapshot_restore_bitexact_fp32(dec, tmp_path):
     _run_snapshot_roundtrip(dec, tmp_path, "fp32")
 
 
+def test_snapshot_restore_keeps_the_mha_carry_head_major(dec, tmp_path):
+    """An MHA decoder's carry is head-major since PR 39, ``(B, KV, L, D)``
+    as GQA's (``LlamaConfig.cache_head_major``): a mid-flight snapshot of
+    it restores into a fresh engine's carry of that layout, every layer,
+    and the rows continue bit-exactly."""
+    cfg = dec.cfg
+    assert cfg.num_key_value_heads == cfg.num_attention_heads
+    head_major = (2, cfg.num_key_value_heads, 64, cfg.head_dim)
+    reqs, solo = _workload(dec, n=3, seed=13, budgets=(12, 16))
+    eng = ServingEngine(dec, num_slots=2, chunk_size=4)
+    ids = [eng.submit(p, b) for p, b in reqs]
+    got = dict(eng.step())
+    assert [b.shape for b in eng.state.kc + eng.state.vc] == \
+        [head_major] * (2 * cfg.num_hidden_layers)
+    sdir = str(tmp_path / "snap_mha")
+    eng.snapshot(sdir)
+    fresh = ServingEngine(dec, num_slots=2, chunk_size=4)
+    assert fresh.restore(sdir)["in_flight"] >= 1
+    assert [b.shape for b in fresh.state.kc + fresh.state.vc] == \
+        [head_major] * (2 * cfg.num_hidden_layers)
+    for a, b in zip(eng.state.kc + eng.state.vc,
+                    fresh.state.kc + fresh.state.vc):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    got.update(fresh.drain())
+    for i, rid in enumerate(ids):
+        np.testing.assert_array_equal(np.asarray(got[rid]), solo[i])
+
+
 def test_snapshot_restore_bitexact_int8wk(model, tmp_path):
     """Same round-trip over the quantized int8 KV carry: the {"q","s"}
     leaves flatten/restore like any other pytree."""

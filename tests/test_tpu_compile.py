@@ -351,7 +351,7 @@ def _loops_beside(hlo_text, kernel):
 
 @pytest.mark.parametrize("kv_heads,slots,layer_shape", [
     (8, 16, "16,8,2048,128"),     # GQA, head-major, both kernels
-    (32, 8, "8,2048,32,128"),     # MHA, token-major, XLA attention
+    (32, 8, "8,32,2048,128"),     # MHA, head-major (PR 39), both kernels
 ])
 def test_ring_chunk_program_updates_the_kv_carry_in_place(
         tpu, decoder_kernels_routed, kv_heads, slots, layer_shape):
@@ -400,9 +400,8 @@ def test_ring_chunk_program_updates_the_kv_carry_in_place(
         steps=steps, do_sample=False, top_k=None, top_p=None).compile()
 
     kernels = program_census(compiled)["kernels"]
-    assert kernels == ({"decode_attention": layers, "kv_row_write": layers}
-                       if kv_heads == 8 else {"kv_row_write": layers})
-    # one layer's K buffer in the GQA case, half of one in the MHA case
+    assert kernels == {"decode_attention": layers, "kv_row_write": layers}
+    # under one layer's K buffer (67 MB in the GQA case, 134 MB in the MHA)
     assert compiled.memory_analysis().temp_size_in_bytes < 67_108_864
     stack_shape = f"{layers},{layer_shape}"
     assert _whole_cache_writers(compiled.as_text(),
@@ -417,8 +416,9 @@ def test_looped_chunk_program_keeps_every_pass_cache_in_place(
     vocabulary) writes each pass's token rows into that pass's own buffer
     in place: the passes are unrolled at trace time, so no cache buffer is
     indexed by a traced pass number and none is copied whole inside the
-    step loop (PERF.md section 6, PR 28); one ``kv_row_write`` call a
-    cache layer."""
+    step loop (PERF.md section 6, PR 28); one ``kv_row_write`` call and,
+    since the cache went head-major (PR 39), one ``decode_attention`` call
+    a cache layer."""
     from paddle_tpu.inference.generate import LlamaDecoder
     from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
     from paddle_tpu.obs.cost import program_census
@@ -437,7 +437,7 @@ def test_looped_chunk_program_keeps_every_pass_cache_in_place(
 
     kc, vc = on_chip(jax.eval_shape(lambda: dec._empty_cache(slots)))
     assert len(kc) == layers * passes
-    assert kc[0].shape == (slots, max_len, 16, 128)
+    assert kc[0].shape == (slots, 16, max_len, 128)     # head-major, PR 39
     logits = _s(tpu, (slots, vocab), jnp.float32)
     rows_i32, rows_f32 = (_s(tpu, (slots,), dt)
                           for dt in (jnp.int32, jnp.float32))
@@ -449,12 +449,34 @@ def test_looped_chunk_program_keeps_every_pass_cache_in_place(
         logits, kc, vc, rows_i32, rows_i32, keys, rows_i32, rows_f32, None,
         steps=steps, do_sample=False, top_k=None, top_p=None).compile()
     assert program_census(compiled)["kernels"] == {
-        "kv_row_write": layers * passes}
+        "decode_attention": layers * passes, "kv_row_write": layers * passes}
     # under one cache buffer (33.5 MB): nothing holds a copy of one
     assert compiled.memory_analysis().temp_size_in_bytes < 33_554_432
-    assert _whole_cache_writers(compiled.as_text(),
-                                {"8,1024,16,128"}) == []
-    assert _loops_beside(compiled.as_text(), "kv_row_write") == []
+    text = compiled.as_text()
+    writers = _whole_cache_writers(text, {"8,16,1024,128"})
+    # what is left may only be the compiler's memory-space assignment
+    # keeping a buffer in on-chip memory (S(1)) across the two kernel calls
+    # that read it — at this tiny FFN much of that memory is free; at
+    # Ouro's own widths it moves one buffer of 48 where the token-major
+    # parent moved six (AOT, PR 39: PERF.md section 4)
+    assert [w for w in writers if not _on_chip_move(text, w)] == []
+    assert _loops_beside(text, "kv_row_write") == []
+
+
+def _on_chip_move(hlo_text, writer):
+    """Whether ``writer`` (an entry of ``_whole_cache_writers``) is a
+    buffer's move into on-chip memory (a ``ConcatBitcast`` of prefetched
+    slices whose value lives in memory space 1) or back out of it (the
+    ``copy-done`` of a ``copy-start`` from memory space 1)."""
+    import re
+    name = writer.split(": ")[1].split(" = ")[0]
+    start = name.replace("copy-done", "copy-start")
+    line = next(ln for ln in hlo_text.splitlines()
+                if re.match(rf"\s*(?:ROOT )?%?{re.escape(start)} = ", ln))
+    if name.startswith("copy-done"):
+        return "S(1)}" in line.split(" copy-start(")[0]
+    return ('custom_call_target="ConcatBitcast"' in line
+            and "S(1)}" in line.split(" custom-call(")[0])
 
 
 def test_banded_flash_forward_compiles_at_the_long_prefill_shape(tpu):
