@@ -222,17 +222,6 @@ def _mm(x, p, name, sharded=False, aidx=None):
     return out
 
 
-def _kernel_backend() -> bool:
-    """The backend half of the routing of every Pallas kernel the decoder
-    calls (attention over the cache, the token-row write, a cold
-    prefill's flash forward): a TPU, or ``flags.decode_attention_interpret``
-    for the CPU tests — off the TPU a kernel runs interpreted, which the
-    hundreds of tests that prefill a tiny decoder should not pay for."""
-    from paddle_tpu.flags import flags as _flags
-    return bool(jax.default_backend() == "tpu"
-                or _flags.decode_attention_interpret)
-
-
 def _decode_kernels(pos, sharded) -> bool:
     """Whether a decode step's attention over the cache may go through the
     Pallas kernels (``decode_attention``, ``decode_attention_pair``,
@@ -243,8 +232,9 @@ def _decode_kernels(pos, sharded) -> bool:
     ``flags.decode_attention_interpret``, for the CPU tests). One
     predicate for ``_kv_attention`` and ``_eva_attention``."""
     from paddle_tpu.flags import flags as _flags
+    from paddle_tpu.ops.pallas import _routing
     return bool(jnp.ndim(pos) <= 1 and not sharded
-                and _flags.use_decode_attention and _kernel_backend())
+                and _flags.use_decode_attention and _routing.kernel_backend())
 
 
 def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
@@ -263,10 +253,11 @@ def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
     step), ``S > 1`` at per-row positions (the speculative verify's
     uneven advance), a mesh, a quantized cache's ``(..., 1)`` scale leaf
     (its int8 leaf takes the kernel) — is ``_cache_write`` a buffer."""
+    from paddle_tpu.ops.pallas import _routing
     from paddle_tpu.ops.pallas import kv_row_write as _kw
     from paddle_tpu.quantization.kv_cache import (is_quantized_kv,
                                                   quantize_kv_rows)
-    rows = jnp.ndim(pos) == 1 and not sharded and _kernel_backend()
+    rows = jnp.ndim(pos) == 1 and not sharded and _routing.kernel_backend()
     if rows and is_quantized_kv(kbuf):
         qk, qv = quantize_kv_rows(kt), quantize_kv_rows(vt)
         if _kw.supported(kbuf["q"], qk["q"], head_major):
@@ -405,7 +396,7 @@ def _fresh_attention(q, k, v, window, sharded):
     neither computes nor fetches blocks outside the band and never holds
     an (S, S) score matrix; XLA's masked attention over the same S keys
     where the kernel does not take the shape, the program runs under a
-    mesh, or the backend is not the kernels' (``_kernel_backend``: one
+    mesh, or the backend is not the kernels' (``kernel_backend``: one
     rule for windowed and plain layers). K and V are repeated to H heads
     first (a grouped index map in the kernel would spare that: ROADMAP
     S3)."""
@@ -417,7 +408,7 @@ def _fresh_attention(q, k, v, window, sharded):
         k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
     if window is not None and window >= S:
         window = None                       # the band is all of the past
-    if (not sharded and _kernel_backend()
+    if (not sharded and _routing.kernel_backend()
             and _fa.supported(q.shape, k.shape, True, window)):
         out = _flash_prefill(q, k, v, window=window,
                              interpret=_routing.use_interpret())
@@ -766,7 +757,8 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
             p[pre + "mlp.experts_gate_up"], p[pre + "mlp.experts_down"],
             top_k=cfg.num_experts_per_tok, route_norm=cfg.route_norm,
             route_scale=cfg.route_scale, expert_offset=cfg.expert_offset,
-            live=None if live is None else live.reshape(B * S))
+            live=None if live is None else live.reshape(B * S),
+            sharded=bool(sharded))
         mlp = mlp + routed.reshape(B, S, -1)
         if moe_stats is not None:
             moe_stats.append((counts, chosen))
@@ -1632,6 +1624,23 @@ class LlamaDecoder:
         zeros = lambda: tuple(z(ci) for ci in range(  # noqa: E731
             cfg.num_cache_layers * cfg.cache_leaves))
         return zeros(), zeros()
+
+    def moe_ffn_kernel_layers(self, batch: int) -> int:
+        """Routed layers whose experts' feed-forward a decode step of
+        ``batch`` rows runs through the grouped-FFN kernel, over the
+        passes (``ops/moe.py:kernel_route`` at the step's ``batch x
+        top_k`` sorted rows: a trace-time fact of the shapes, the backend
+        and the mesh); 0 for a model without routed layers."""
+        from paddle_tpu.ops.moe import kernel_route
+        cfg, p = self.cfg, self.params
+        n = 0
+        for li in range(cfg.num_hidden_layers):
+            pre = f"model.layers.{li}.mlp."
+            if pre + "router.weight" in p:
+                gu, dn = p[pre + "experts_gate_up"], p[pre + "experts_down"]
+                n += kernel_route(batch * cfg.num_experts_per_tok, gu, dn,
+                                  gu.dtype, self.sharding is not None)
+        return n * cfg.total_ut_steps
 
     # -- chunked resumable decode -----------------------------------------
     def init_decode_state(self, input_ids, eos_token_id=None,

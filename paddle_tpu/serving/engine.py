@@ -211,6 +211,10 @@ class _DecoderBackend:
                     if dec.cfg.eva else None)
         self.moe_counts = []    # state.moe of every chunk dispatch since
         #                         the engine last took them (harvest)
+        # routed layers whose feed-forward the chunk program runs through
+        # the grouped-FFN kernel (a trace-time fact: ops/moe.py)
+        self.moe_ffn_kernel_layers = dec.moe_ffn_kernel_layers(
+            self.num_slots)
         if mesh is not None:
             want = _as_sharding(mesh)
             if self.sharding is None:
@@ -506,6 +510,7 @@ class _BundleBackend:
     has_windows = False    # (an exported program is full attention)
     eva = None
     moe_counts = ()
+    moe_ffn_kernel_layers = 0
     has_ring = False       # bundles carry no ring-staging entries: the
     #                        engine falls back to the host row-scatter
     spec_eng = None
@@ -1095,6 +1100,12 @@ class ServingEngine:
             "serving.moe.load_max",
             "the largest count one held expert took in one step of the "
             "last chunk")
+        self._moe_ffn_kernel_layers = int(self._b.moe_ffn_kernel_layers)
+        r.gauge("serving.moe.ffn_kernel_layers",
+                "routed layers whose experts' feed-forward the chunk "
+                "program runs through the grouped-FFN kernel (0 where "
+                "XLA's ragged_dot pair runs)"
+                ).set(self._moe_ffn_kernel_layers)
         # the cache as built, from the carry's own buffers: a looped
         # model holds its weight layers once per pass, a windowed layer
         # a rolling buffer of its window (shorter than max_len or not)
@@ -3545,7 +3556,9 @@ class ServingEngine:
         crossed, the windows prefilled and, by admission bucket, the rows,
         positions and attended pairs the prefills needed), ``moe_*`` what
         the routing of a model with routed feed-forwards did
-        (``serving.moe.*``; zeros for any other),
+        (``serving.moe.*``; zeros for any other) and
+        ``moe_ffn_kernel_layers`` how many of its routed layers the chunk
+        program runs through the grouped-FFN kernel,
         ``compiles`` this process's backend compiles by dispatch site."""
         qd, lat = self._h_qdelay, self._h_latency
         self._timeline.flush()
@@ -3589,6 +3602,7 @@ class ServingEngine:
             "moe_pairs_held_total": int(self._c_moe_pairs.value),
             "moe_experts_touched_total": int(self._c_moe_touched.value),
             "moe_load_max": int(self._g_moe_load.value),
+            "moe_ffn_kernel_layers": self._moe_ffn_kernel_layers,
             "compiles": obs.compile_counts(),
             "queue_delay_mean_s": qd.mean,
             "queue_delay_p50_s": qd.percentile(50),

@@ -486,7 +486,7 @@ def test_parameters_and_pooling_vectors_as_the_configuration_says():
 # 1`` before the last final norm and the head where it made all S rows of
 # logits (its attention is still buffer-wide, or over the fresh keys for a
 # windowed model, now in XLA's form off the TPU: one routing rule for the
-# flash forward, ``generate._kernel_backend``);
+# flash forward, ``_routing.kernel_backend``);
 # tests/test_prefill_from_zero.py holds the new route's logits to the old.
 # THIS IS EVIDENCE, NOT A CONTRACT ON THE COMPILER'S TEXT: a PR
 # that means to change the shared block (or a JAX upgrade) retakes the

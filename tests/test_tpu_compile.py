@@ -479,11 +479,18 @@ def _on_chip_move(hlo_text, writer):
             and "S(1)}" in line.split(" custom-call(")[0])
 
 
-def test_banded_flash_forward_compiles_at_the_long_prefill_shape(tpu):
+def test_banded_flash_forward_compiles_at_the_long_prefill_shape(
+        tpu, decoder_kernels_routed):
     """The admission prefill of a windowed model at its longest bucket:
     8192 positions, 48 heads of 128, a band of 4096 (clamped k/v index
-    maps, a second skip condition); and the routed feed-forward's two
-    grouped matmuls over one chip's 32 experts at 16 rows."""
+    maps, a second skip condition); and the routed feed-forward over one
+    chip's 32 experts at the two serving sections' 16 and 96 rows: one
+    grouped-FFN kernel call (ops/pallas/grouped_ffn.py, its scoped VMEM
+    asked for as stated there) and temporaries under 16 MiB; off the
+    kernels' backend XLA's two ``ragged-dot`` calls and no kernel, their
+    temporaries under 16 MiB too."""
+    import paddle_tpu as paddle
+    from paddle_tpu.obs.cost import program_census
     from paddle_tpu.ops.moe import routed_ffn
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_fn
 
@@ -495,7 +502,48 @@ def test_banded_flash_forward_compiles_at_the_long_prefill_shape(tpu):
     def ffn(x, router, bias, gate_up, down):
         return routed_ffn(x, router, bias, gate_up, down, top_k=4,
                           route_norm=True, route_scale=2.448)[0]
-    compiled = jax.jit(ffn).lower(
-        _s(tpu, (16, 3072)), _s(tpu, (3072, 256)), _s(tpu, (256,)),
-        _s(tpu, (32, 3072, 6144)), _s(tpu, (32, 3072, 3072))).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20
+
+    def compiled(rows):           # a fresh trace: the route is read there
+        return jax.jit(lambda *a: ffn(*a)).lower(
+            _s(tpu, (rows, 3072)), _s(tpu, (3072, 256)), _s(tpu, (256,)),
+            _s(tpu, (32, 3072, 6144)), _s(tpu, (32, 3072, 3072))).compile()
+
+    for rows in (16, 96):
+        c = compiled(rows)
+        assert program_census(c)["kernels"] == {"grouped_ffn": 1}
+        assert c.memory_analysis().temp_size_in_bytes < 16 << 20
+    paddle.set_flags({"decode_attention_interpret": False})
+    for rows in (16, 96):
+        c = compiled(rows)
+        assert "grouped_ffn" not in program_census(c)["kernels"]
+        assert c.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+def test_grouped_ffn_compiles_for_a_chip_of_half_the_vmem(tpu, monkeypatch):
+    """On a chip of 64 MiB of VMEM a core (a described v5p) the kernel's
+    plan is taken from that capacity: the decode batch's 384 sorted rows
+    through one chip's 32 experts at the published widths, and the most
+    rows the plan takes, compile under the smaller scoped limit; a
+    bucket-512 prefill's 2048 rows (which a 128 MiB plan would take, and
+    which overflow this chip's VMEM) are left to XLA's pair."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu.obs.cost import program_census
+    from paddle_tpu.ops.pallas import grouped_ffn as gf
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5p:2x2x1")
+    except Exception as e:
+        pytest.skip(f"cannot describe a v5p topology here: {e}")
+    monkeypatch.setattr(gf, "_vmem_capacity", lambda: 64 << 20)
+    v5p = SingleDeviceSharding(topo.devices[0])
+    gu, dn = _s(v5p, (32, 3072, 6144)), _s(v5p, (32, 3072, 3072))
+    assert not gf.supported(2048, gu, dn, jnp.bfloat16)
+    most = max(r for r in range(128, 2048, 128)
+               if gf.supported(r, gu, dn, jnp.bfloat16))
+    for rows in (384, most):
+        c = jax.jit(gf.grouped_ffn).lower(
+            _s(v5p, (rows, 3072)), gu, dn,
+            _s(v5p, (32,), jnp.int32)).compile()
+        assert program_census(c)["kernels"] == {"grouped_ffn": 1}
