@@ -196,6 +196,12 @@ def export_decoder_bundle(decoder, out_dir: str,
     import jax.numpy as jnp
 
     cfg = decoder.cfg
+    if cfg.latent:
+        from paddle_tpu.inference.generate import LatentCacheError
+        raise LatentCacheError(
+            "a decoder bundle records one K / V buffer shape for all "
+            "layers; a model with latent attention caches a latent and a "
+            "rope key of two widths: serve it in process")
     if cfg.has_windows:
         from paddle_tpu.inference.generate import WindowedModelError
         raise WindowedModelError(

@@ -42,10 +42,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM, _rope_tables
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 
 __all__ = ["LlamaDecoder", "DecodeState", "LoopedDraftError",
-           "WindowedModelError"]
+           "WindowedModelError", "LatentCacheError"]
 
 
 class LoopedDraftError(ValueError):
@@ -59,6 +59,14 @@ class WindowedModelError(ValueError):
     speculative verify, a prefill that starts past position 0) was asked
     of a model with windowed layers, whose rolling buffers keep a
     position at ``position % window``."""
+
+
+class LatentCacheError(ValueError):
+    """A path that holds keys and values per KV head (the prefix cache's
+    slabs, an int8 cache, the speculative verify, a decoder bundle, the
+    mesh's partition rules) was asked of a model with multi-head latent
+    attention, whose cache layer is a compressed latent and one rotated
+    key part (models/axk1.py)."""
 
 
 class ConsumedStateError(RuntimeError):
@@ -155,7 +163,7 @@ def _rope_at(x, pos, cfg, p):
     rope scaling changes — and no per-step exp/pow work. ``pos`` may be a
     scalar or a per-row (B,) vector (speculative rows advance unevenly)."""
     S = x.shape[1]
-    d2 = cfg.head_dim // 2
+    d2 = p["rope.cos"].shape[1]
     if jnp.ndim(pos) == 1:
         idx = pos[:, None] + jnp.arange(S)                  # (B, S)
         cos = jnp.take(p["rope.cos"], idx, axis=0).astype(x.dtype)
@@ -167,7 +175,11 @@ def _rope_at(x, pos, cfg, p):
         sin = jax.lax.dynamic_slice(p["rope.sin"], (pos, 0),
                                     (S, d2)).astype(x.dtype)
         cos, sin = cos[None, :, None, :], sin[None, :, None, :]
-    x1, x2 = x[..., :d2], x[..., d2:]
+    if cfg.rope_interleaved:
+        # pairs (2i, 2i + 1) rotate together, and come out as halves
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+    else:
+        x1, x2 = x[..., :d2], x[..., d2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
@@ -267,7 +279,8 @@ def _cache_update(kbuf, vbuf, kt, vt, pos, head_major, sharded=False):
                                                 head_major)},
                     {"q": vq, "s": _cache_write(vbuf["s"], qv["s"], pos,
                                                 head_major)})
-    elif rows and _kw.supported(kbuf, kt, head_major):
+    elif (rows and _kw.supported(kbuf, kt, head_major)
+          and _kw.supported(vbuf, vt, head_major)):
         return _kw.kv_row_write(kbuf, vbuf, kt, vt, pos,
                                 head_major=head_major)
     return (_cache_write(kbuf, kt, pos, head_major, sharded),
@@ -371,8 +384,9 @@ def _prefill_rows(t, true_len, L: int, head_major: bool):
     return jnp.take_along_axis(t, idx[:, :, None, None], axis=1)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def _flash_prefill(q, k, v, *, window, interpret):
+@functools.partial(jax.jit, static_argnames=("window", "interpret",
+                                             "scale"))
+def _flash_prefill(q, k, v, *, window, interpret, scale=None):
     """The flash forward of ``_fresh_attention`` as a function of its own:
     a prefill calls it once a cache layer with the same shapes, and an
     inner ``jit`` is traced and lowered ONCE a program — as 12 separate
@@ -383,10 +397,11 @@ def _flash_prefill(q, k, v, *, window, interpret):
     the compiled program is the same. ``interpret`` is part of the
     trace's key only: the kernel asks ``_routing.use_interpret()`` itself,
     and a test that patches it must not be served the other mode's
-    trace."""
+    trace. ``scale``: the softmax's, ``head_dim ** -0.5`` if None."""
     del interpret
     from paddle_tpu.ops.pallas import flash_attention as _fa
-    return _fa.flash_attention_fn(q, k, v, causal=True, window=window)
+    return _fa.flash_attention_fn(q, k, v, causal=True, window=window,
+                                  scale=scale)
 
 
 def _fresh_attention(q, k, v, window, sharded):
@@ -656,6 +671,134 @@ def _eva_attention(p, cfg, li: int, ci: int, q, k, v, kc, vc, pos, max_len,
     return out.reshape(B, S, H * D), kc, vc
 
 
+# bytes of one (B, S, heads, width) tensor of a latent prefill's expanded
+# attention past which its heads go in groups (_latent_attention)
+_HEAD_GROUP_BYTES = 128 << 20
+
+
+def _head_group(bytes_per_head: int, heads: int) -> int:
+    """Heads a group: all where a tensor of all of them fits
+    ``_HEAD_GROUP_BYTES``, else ``heads`` halved until it does (or is
+    odd)."""
+    g = heads
+    while g % 2 == 0 and g * bytes_per_head > _HEAD_GROUP_BYTES:
+        g //= 2
+    return g
+
+
+def _latent_attention(p, cfg, li: int, ci: int, x, kc, vc, pos, sharded,
+                      from_zero):
+    """Multi-head latent attention (models/axk1.py) of layer ``li`` over
+    its normed input ``x`` (B, S, hidden), through cache layer ``ci``'s
+    two leaves, head-major with one "head": buffer ``ci`` of ``kc`` the
+    normed latent ``c_kv`` (B, 1, L, kv_lora_rank), of ``vc`` the rotated
+    key part ``k_pe`` shared by every head (B, 1, L, rope_cache_width:
+    ``qk_rope_head_dim`` values, zeros to a lane tile). -> (out (B, S,
+    heads * v_head_dim), kc, vc).
+
+    Two forms of one function, chosen by what the call is:
+
+    - ``S > 1`` FROM POSITION 0 (``from_zero``) is EXPANDED: per-head keys
+      ``[c_kv W_UK ; k_pe]`` and values ``c_kv W_UV`` of the S fresh
+      positions, causal attention over them through the flash forward
+      kernel where it routes (values zero-padded to the key width, the
+      padding's output dropped), else XLA's masked form;
+    - everything else (a decode step; ``S > 1`` at ``pos > 0``) is
+      ABSORBED: each head's query is taken into latent space, ``q~ = W_UK
+      q_nope``, scored against the latent and the rope key over the
+      buffer, and the weighted latent brought back through ``W_UV``; a
+      decode step through the ``mla_decode_attention`` kernel
+      (ops/pallas/mla_decode_attention.py: each live latent block read
+      once for all heads) where the routing takes it, else XLA's masked
+      form over the buffer.
+
+    The softmax scale is the config's (YaRN's ``mscale`` squared)."""
+    from paddle_tpu.ops.pallas import _routing
+    from paddle_tpu.ops.pallas import flash_attention as _fa
+    from paddle_tpu.ops.pallas import mla_decode_attention as _mla
+    B, S, _ = x.shape
+    H, dn, dr, dv = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim, cfg.v_head_dim)
+    Q, R, eps = cfg.q_lora_rank, cfg.kv_lora_rank, cfg.rms_norm_eps
+    scale = cfg.softmax_scale
+    pre = f"model.layers.{li}.self_attn."
+    # one matmul for the query's bottleneck and the latent with its key
+    a = _mm(x, p, pre + "qa_kva.weight", sharded)
+    q = _mm(_rms(a[..., :Q], p[pre + "q_a_layernorm.weight"], eps), p,
+            pre + "q_b_proj.weight", sharded).reshape(B, S, H, dn + dr)
+    ckv = _rms(a[..., Q:Q + R], p[pre + "kv_a_layernorm.weight"], eps)
+    kpe = _rope_at(a[..., None, Q + R:], pos, cfg, p)        # (B, S, 1, dr)
+    qn, qp = q[..., :dn], _rope_at(q[..., dn:], pos, cfg, p)
+    wkv = p[pre + "kv_b_proj.weight"].reshape(R, H, dn + dv)
+    pad = cfg.rope_cache_width - dr
+    fresh = S > 1 and from_zero
+    kc_l, vc_l = _cache_update(
+        kc[ci], vc[ci], ckv[:, None],
+        jnp.pad(jnp.swapaxes(kpe, 1, 2), ((0, 0),) * 3 + ((0, pad),)),
+        0 if fresh else pos, True, sharded)
+    kc = kc[:ci] + (kc_l,) + kc[ci + 1:]
+    vc = vc[:ci] + (vc_l,) + vc[ci + 1:]
+    if fresh:
+        qf = jnp.concatenate([qn, qp], -1)
+        # a length the flash forward does not tile (a prompt prefilled
+        # unbucketed) runs it over zeros to whole 512-position blocks: the
+        # appended keys lie after every true query, so the causal mask
+        # hides them, and the appended queries' rows are dropped
+        Sp = S if _fa.supported(qf.shape, qf.shape, True) \
+            else -(-S // 512) * 512
+        flash = (not sharded and _routing.kernel_backend()
+                 and _fa.supported((B, Sp, H, dn + dr), (B, Sp, H, dn + dr),
+                                   True))
+        tail = ((0, 0), (0, Sp - S), (0, 0), (0, 0))
+
+        def grow(t):
+            return jnp.pad(t, tail) if Sp > S else t
+        outs = []
+        # heads in groups whose (S, heads, width) tensors stay under
+        # _HEAD_GROUP_BYTES: each group's expanded keys and values, the
+        # flash call's copies of them and its float32 log-sum-exp (padded
+        # to 128 lanes) are alive for that group alone
+        G = _head_group(B * Sp * (dn + dr) * x.dtype.itemsize, H)
+        for g0 in range(0, H, G):
+            kv = jnp.einsum("bsr,rhd->bshd", ckv, wkv[:, g0:g0 + G])
+            k = jnp.concatenate([kv[..., :dn],
+                                 jnp.broadcast_to(kpe, (B, S, G, dr))], -1)
+            v, q_g = kv[..., dn:], qf[:, :, g0:g0 + G]
+            if flash:
+                vp = jnp.pad(grow(v), ((0, 0),) * 3 + ((0, dn + dr - dv),))
+                o = _flash_prefill(grow(q_g), grow(k), vp, window=None,
+                                   scale=scale,
+                                   interpret=_routing.use_interpret())
+                outs.append((o[:, :S] if Sp > S else o)[..., :dv])
+                continue
+            sc = jnp.einsum("bqhd,bkhd->bhqk", q_g, k,
+                            preferred_element_type=jnp.float32) * scale
+            sc = jnp.where(jnp.tril(jnp.ones((S, S), bool)), sc, -jnp.inf)
+            pr = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
+            outs.append(jnp.einsum("bhqk,bkhd->bqhd", pr, v))
+        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, 2)
+        return out.reshape(B, S, H * dv), kc, vc
+    qa = jnp.einsum("bshd,rhd->bshr", qn, wkv[..., :dn])     # (B, S, H, R)
+    qp = jnp.pad(qp, ((0, 0),) * 3 + ((0, pad),))
+    if (S == 1 and _decode_kernels(pos, sharded)
+            and _mla.supported(qa[:, 0], kc_l, vc_l)):
+        o = _mla.mla_decode_attention(qa[:, 0], qp[:, 0], kc_l, vc_l,
+                                      pos + 1, scale=scale)[:, None]
+    else:
+        sc = (jnp.einsum("bshr,blr->bhsl", qa, kc_l[:, 0],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bshd,bld->bhsl", qp, vc_l[:, 0],
+                           preferred_element_type=jnp.float32)) * scale
+        qpos = (jnp.reshape(pos, (-1, 1, 1, 1))
+                + jnp.arange(S)[None, None, :, None])
+        kpos = jnp.arange(kc_l.shape[2])[None, None, None, :]
+        sc = jnp.where(kpos <= qpos, sc, -jnp.inf)
+        pr = jax.nn.softmax(sc, axis=-1).astype(x.dtype)
+        o = jnp.einsum("bhsl,blr->bshr", pr, kc_l[:, 0])
+    out = jnp.einsum("bshr,rhd->bshd", o, wkv[..., dn:])
+    return out.reshape(B, S, H * dv), kc, vc
+
+
 def _moe_reduce(c):
     """(n, 3) routing counts -> (3,): pairs on held experts and held
     experts touched add up, the largest count one expert took is a
@@ -699,7 +842,9 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     None = S).
     ``cfg.eva``: the layer's attention is EVA over a window leaf and a
     summary leaf (``_eva_attention``), buffers ``2 * ci`` and ``2 * ci +
-    1`` of the carry. ``cfg.fp32_skip_add``: ``h`` is
+    1`` of the carry. ``cfg.latent``: it is multi-head latent attention
+    (``_latent_attention``), buffer ``ci`` of ``kc`` the latent and of
+    ``vc`` the rope key. ``cfg.fp32_skip_add``: ``h`` is
     float32 and each sub-layer reads it in the compute dtype.
     ``self_attn.q_norm`` / ``k_norm``: per-head RMSNorm on q and k. A
     fourth column block of the fused q|k|v matrix: an output gate,
@@ -714,28 +859,33 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
     eps = cfg.rms_norm_eps
 
     x = _rms(_stream_read(h, cfg), p[pre + "input_layernorm.weight"], eps)
-    qkv = _mm(x, p, pre + "self_attn.qkv.weight", sharded, aidx)
-    q = qkv[..., :H * D].reshape(B, S, H, D)
-    k = qkv[..., H * D:H * D + KV * D].reshape(B, S, KV, D)
     gate = None
-    if qkv.shape[-1] > (H + 2 * KV) * D:
-        v = qkv[..., (H + KV) * D:(H + 2 * KV) * D].reshape(B, S, KV, D)
-        gate = qkv[..., (H + 2 * KV) * D:]
+    if cfg.latent:
+        out, kc, vc = _latent_attention(p, cfg, li, ci, x, kc, vc, pos,
+                                        sharded, from_zero)
     else:
-        v = qkv[..., H * D + KV * D:].reshape(B, S, KV, D)
-    qn = p.get(pre + "self_attn.q_norm.weight")
-    if qn is not None:
-        q = _rms(q, qn, eps)
-        k = _rms(k, p[pre + "self_attn.k_norm.weight"], eps)
-    if cfg.layer_rope(li):
-        q = _rope_at(q, pos, cfg, p)
-        k = _rope_at(k, pos, cfg, p)
-    if cfg.eva:
-        out, kc, vc = _eva_attention(p, cfg, li, ci, q, k, v, kc, vc, pos,
-                                     max_len, sharded, true_len)
-    else:
-        out, kc, vc = _kv_attention(cfg, li, ci, q, k, v, kc, vc, pos,
-                                    max_len, sharded, true_len, from_zero)
+        qkv = _mm(x, p, pre + "self_attn.qkv.weight", sharded, aidx)
+        q = qkv[..., :H * D].reshape(B, S, H, D)
+        k = qkv[..., H * D:H * D + KV * D].reshape(B, S, KV, D)
+        if qkv.shape[-1] > (H + 2 * KV) * D:
+            v = qkv[..., (H + KV) * D:(H + 2 * KV) * D].reshape(B, S, KV, D)
+            gate = qkv[..., (H + 2 * KV) * D:]
+        else:
+            v = qkv[..., H * D + KV * D:].reshape(B, S, KV, D)
+        qn = p.get(pre + "self_attn.q_norm.weight")
+        if qn is not None:
+            q = _rms(q, qn, eps)
+            k = _rms(k, p[pre + "self_attn.k_norm.weight"], eps)
+        if cfg.layer_rope(li):
+            q = _rope_at(q, pos, cfg, p)
+            k = _rope_at(k, pos, cfg, p)
+        if cfg.eva:
+            out, kc, vc = _eva_attention(p, cfg, li, ci, q, k, v, kc, vc,
+                                         pos, max_len, sharded, true_len)
+        else:
+            out, kc, vc = _kv_attention(cfg, li, ci, q, k, v, kc, vc, pos,
+                                        max_len, sharded, true_len,
+                                        from_zero)
     if gate is not None:
         out = out * jax.nn.sigmoid(gate)
     att = _mm(out, p, pre + "self_attn.o_proj.weight", sharded, aidx)
@@ -758,7 +908,8 @@ def _block_forward(p, cfg: LlamaConfig, li: int, ci: int, h, kc, vc, pos,
             top_k=cfg.num_experts_per_tok, route_norm=cfg.route_norm,
             route_scale=cfg.route_scale, expert_offset=cfg.expert_offset,
             live=None if live is None else live.reshape(B * S),
-            sharded=bool(sharded))
+            sharded=bool(sharded), n_group=cfg.n_group,
+            topk_group=cfg.topk_group)
         mlp = mlp + routed.reshape(B, S, -1)
         if moe_stats is not None:
             moe_stats.append((counts, chosen))
@@ -853,12 +1004,21 @@ def _build_params(model: LlamaForCausalLM, max_len: int,
     # reference: a second copy of them would not fit beside the first
     for li in range(model.config.num_hidden_layers):
         pre = f"model.layers.{li}."
-        qkv = [raw.pop(pre + "self_attn.q_proj.weight"),
-               raw.pop(pre + "self_attn.k_proj.weight"),
-               raw.pop(pre + "self_attn.v_proj.weight")]
-        if pre + "self_attn.gate_proj.weight" in raw:
-            qkv.append(raw.pop(pre + "self_attn.gate_proj.weight"))
-        raw[pre + "self_attn.qkv.weight"] = jnp.concatenate(qkv, axis=1)
+        if model.config.latent:
+            # latent attention: the query's bottleneck and the latent
+            # with its rope key in one matmul
+            raw[pre + "self_attn.qa_kva.weight"] = jnp.concatenate(
+                [raw.pop(pre + "self_attn.q_a_proj.weight"),
+                 raw.pop(pre + "self_attn.kv_a_proj_with_mqa.weight")],
+                axis=1)
+        else:
+            qkv = [raw.pop(pre + "self_attn.q_proj.weight"),
+                   raw.pop(pre + "self_attn.k_proj.weight"),
+                   raw.pop(pre + "self_attn.v_proj.weight")]
+            if pre + "self_attn.gate_proj.weight" in raw:
+                qkv.append(raw.pop(pre + "self_attn.gate_proj.weight"))
+            raw[pre + "self_attn.qkv.weight"] = jnp.concatenate(qkv,
+                                                                axis=1)
         ffn = pre + ("mlp." if pre + "mlp.gate_proj.weight" in raw
                      else "mlp.shared_experts.")
         raw[ffn + "gate_up.weight"] = jnp.concatenate(
@@ -893,9 +1053,8 @@ def _build_params(model: LlamaForCausalLM, max_len: int,
         p["head:int8"] = q.value
         p["head:scale"] = scale.value
     # precomputed rope tables for the whole cache window
-    cos, sin = _rope_tables(max_len, model.config.head_dim,
-                            model.config.rope_theta,
-                            jnp.dtype(model.config.dtype), offset=0)
+    cos, sin = model.config.rope_tables(max_len,
+                                        jnp.dtype(model.config.dtype))
     p["rope.cos"], p["rope.sin"] = cos, sin
     return p
 
@@ -1207,6 +1366,13 @@ class LlamaDecoder:
                 "quant='int8wk' does not run on a mesh yet: the int8 KV "
                 "carry's scale buffers have no partition rules; use "
                 "quant='int8w' (weight-only) on a mesh, or drop mesh=")
+        if self.cfg.latent and (self.quant or self.sharding is not None):
+            raise LatentCacheError(
+                "a model with latent attention holds a latent and a rope "
+                "key a cache layer: an int8 cache, int8 weights beside "
+                "the latent's up-projection and the mesh's partition "
+                "rules are all of keys and values per head — build the "
+                "decoder without quant= and mesh=")
         if self.quant_kv and self.cfg.eva:
             raise WindowedModelError(
                 "quant='int8wk' keeps an int8 K / V buffer by position; a "
@@ -1594,16 +1760,21 @@ class LlamaDecoder:
         for a windowed layer, its window. An EVA config
         (``cfg.eva``) gets ``cfg.cache_leaves`` = 2 buffers a cache layer,
         its window leaf and then its summary leaf: the leaves ride in
-        ``kc`` / ``vc``."""
+        ``kc`` / ``vc``. A latent config (``cfg.latent``) gets in ``kc``
+        its layers' latents ``(B, 1, L, kv_lora_rank)`` and in ``vc``
+        their rope keys ``(B, 1, L, rope_cache_width)``."""
         cfg = self.cfg if cfg is None else cfg
         dt = jnp.dtype(cfg.dtype)
         head_major = cfg.cache_head_major
 
-        def z(ci):
+        def z(ci, width=None):
             # each buffer at its own length: a windowed layer's is
             # rolling and holds its window, an EVA layer holds a window
             # leaf and a summary leaf (the config's cache_len)
             L = cfg.cache_len(ci, self.max_len)
+            if width is not None:
+                # a latent layer's leaf: one "head" of its own width
+                return jnp.zeros((B, 1, L, width), dt)
             if head_major:
                 shape = (B, cfg.num_key_value_heads, L, cfg.head_dim)
             else:
@@ -1621,6 +1792,10 @@ class LlamaDecoder:
             # the carry never exists gathered, not even at init
             return self.sharding.put_state_field("kc", buf, head_major)
 
+        if cfg.latent:
+            n = cfg.num_cache_layers
+            return (tuple(z(ci, cfg.kv_lora_rank) for ci in range(n)),
+                    tuple(z(ci, cfg.rope_cache_width) for ci in range(n)))
         zeros = lambda: tuple(z(ci) for ci in range(  # noqa: E731
             cfg.num_cache_layers * cfg.cache_leaves))
         return zeros(), zeros()
@@ -1898,6 +2073,12 @@ class LlamaDecoder:
         a wrong draft only costs acceptance length, never correctness."""
         import dataclasses
         cfg, max_len = self.cfg, self.max_len
+        if cfg.latent:
+            raise LatentCacheError(
+                "speculative decoding drafts through a second cache and "
+                "verifies over the target's keys and values per head; a "
+                "model with latent attention has neither: decode without "
+                "draft_model=")
         if cfg.has_windows:
             raise WindowedModelError(
                 "speculative decoding verifies several positions in one "

@@ -6,6 +6,8 @@ AFMoE (routed experts as one chip's share, gated window/full attention,
 served by the same decoder),
 EvaByte (EVA chunked linearized attention: a window leaf and a summary leaf
 a cache layer, served by the same decoder),
+A.X-K1 (multi-head latent attention over a latent cache, group-limited
+sigmoid routing as one chip's share, served by the same decoder),
 GPT (fused-qkv causal LM), BERT (MLM pretraining), diffusion UNet
 (SD-style), plus vision CNNs in paddle_tpu.vision.models.
 """
@@ -22,6 +24,9 @@ from paddle_tpu.models.afmoe import (  # noqa: F401
 )
 from paddle_tpu.models.evabyte import (  # noqa: F401
     EVABYTE_TINY, EvabyteConfig, EvabyteForCausalLM, EvabyteModel,
+)
+from paddle_tpu.models.axk1 import (  # noqa: F401
+    AXK1_TINY, AxK1Config, AxK1ForCausalLM, AxK1Model,
 )
 from paddle_tpu.models.gpt import GPT_TINY, GPTConfig, GPTForCausalLM  # noqa: F401
 from paddle_tpu.models.bert import BERT_TINY, BertConfig, BertForMaskedLM  # noqa: F401

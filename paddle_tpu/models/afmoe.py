@@ -188,9 +188,11 @@ class AfmoeAttention(LlamaAttention):
 _ROUTED_OPS: dict = {}
 
 
-def _routed_ffn_op(top_k, route_norm, route_scale, expert_offset):
+def _routed_ffn_op(top_k, route_norm, route_scale, expert_offset,
+                   n_group=1, topk_group=1):
     """The tape op over ``ops/moe.py:routed_ffn`` (its output alone)."""
-    key = (top_k, route_norm, route_scale, expert_offset)
+    key = (top_k, route_norm, route_scale, expert_offset, n_group,
+           topk_group)
     if key not in _ROUTED_OPS:
         from paddle_tpu.ops.moe import routed_ffn
 
@@ -198,15 +200,17 @@ def _routed_ffn_op(top_k, route_norm, route_scale, expert_offset):
             return routed_ffn(x, router_w, expert_bias, w_gate_up, w_down,
                               top_k=top_k, route_norm=route_norm,
                               route_scale=route_scale,
-                              expert_offset=expert_offset)[0]
+                              expert_offset=expert_offset, n_group=n_group,
+                              topk_group=topk_group)[0]
         opdef = OpDef(f"afmoe_routed_ffn<{top_k},{expert_offset}>", impl)
         _ROUTED_OPS[key] = lambda *args: apply_op(opdef, args, {})
     return _ROUTED_OPS[key]
 
 
 class AfmoeMoE(nn.Layer):
-    """Router over ``num_experts``, the held experts' stacks, the shared
-    expert. ``expert_bias`` is a buffer that selects and does not weigh
+    """Router over ``num_experts`` (in ``n_group`` groups of which
+    ``topk_group`` are kept: models/axk1.py), the held experts' stacks,
+    the shared expert. ``expert_bias`` is a buffer that selects and does not weigh
     (trained by a bias update outside the forward pass; zero here)."""
 
     def __init__(self, config: AfmoeConfig):
@@ -233,7 +237,7 @@ class AfmoeMoE(nn.Layer):
         B, S, H = x.shape
         routed = _routed_ffn_op(
             cfg.num_experts_per_tok, cfg.route_norm, cfg.route_scale,
-            cfg.expert_offset)(
+            cfg.expert_offset, cfg.n_group, cfg.topk_group)(
             paddle.reshape(x, [B * S, H]), self.router.weight,
             self.expert_bias, self.experts_gate_up, self.experts_down)
         out = paddle.reshape(routed, [B, S, H])

@@ -55,6 +55,16 @@ class LlamaConfig:
     embedding_scale = 1.0
     has_windows = False
     routed = False      # whether any feed-forward is routed over experts
+    # a routed feed-forward's group limit (ops/moe.py:sigmoid_topk_route):
+    # one group, no limit, here and in every config that does not say
+    n_group = 1
+    topk_group = 1
+    # what a cache layer holds: keys and values per KV head here; a config
+    # whose attention is multi-head LATENT attention (models/axk1.py) says
+    # so — a cache layer is then a compressed latent and one rotated key
+    # part shared by all heads — and rotates interleaved pairs
+    latent = False
+    rope_interleaved = False
     # what a layer's attention is over its cache: a softmax over one buffer
     # of keys (and one of values) by position here; a config whose
     # attention is EVA (models/evabyte.py) says so — a cache layer is then
@@ -102,6 +112,12 @@ class LlamaConfig:
         (``generate._decode_kernels``, each kernel's ``supported``), not
         the layout's."""
         return True
+
+    def rope_tables(self, max_len: int, dtype):
+        """cos / sin ``(max_len, rotated width / 2)`` of positions ``[0,
+        max_len)``, which the decoder precomputes once: the width a head
+        rotates and the frequencies are the config's."""
+        return _rope_tables(max_len, self.head_dim, self.rope_theta, dtype)
 
     def cache_len(self, ci: int, max_len: int) -> int:
         """Positions cache layer ``ci`` holds in a decoder of ``max_len``:

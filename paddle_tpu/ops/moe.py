@@ -92,39 +92,60 @@ def grouped_ffn(x, w_gate_up, w_down, group_sizes, sharded=False):
 
 
 def sigmoid_topk_route(x, router_w, expert_bias, top_k: int,
-                       route_norm: bool, route_scale: float):
+                       route_norm: bool, route_scale: float,
+                       n_group: int = 1, topk_group: int = 1):
     """Scores ``sigmoid(x @ router_w)`` in float32 over every expert the
     router knows; the ``top_k`` of ``score + expert_bias`` are chosen (the
     bias selects only; ties go to the lowest index, as ``lax.top_k``
     does); the chosen scores, normalised over the chosen if
     ``route_norm``, times ``route_scale``.
+
+    GROUP-LIMITED (``n_group > 1``, DeepSeek-V3's ``MoEGate``): the
+    experts fall into ``n_group`` equal groups in index order, a group
+    scores the sum of its two largest ``score + expert_bias``, and the
+    ``top_k`` are chosen among the experts of the ``topk_group`` best
+    groups only. ``n_group = 1`` traces none of it.
     Returns ``(weights (T, top_k) float32, chosen (T, top_k) int32)``."""
     s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
                                router_w.astype(jnp.float32),
                                precision=lax.Precision.HIGHEST))
-    _, select = lax.top_k(s + expert_bias.astype(jnp.float32), top_k)
+    choice = s + expert_bias.astype(jnp.float32)
+    if n_group > 1:
+        g = choice.reshape(choice.shape[:-1] + (n_group, -1))
+        _, best = lax.top_k(jnp.sum(lax.top_k(g, 2)[0], -1), topk_group)
+        kept = jnp.any(best[..., None] == jnp.arange(n_group), -2)
+        choice = jnp.where(kept[..., None], g, -jnp.inf).reshape(
+            choice.shape)
+    _, select = lax.top_k(choice, top_k)
     top = jnp.take_along_axis(s, select, axis=-1)
     if route_norm:
         top = top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
     return top * route_scale, select.astype(jnp.int32)
 
 
-def routed_ffn(x, router_w, expert_bias, w_gate_up, w_down, *, top_k: int,
-               route_norm: bool, route_scale: float, expert_offset: int = 0,
-               live=None, sharded=False):
-    """The held experts' part of a routed SwiGLU feed-forward over tokens
-    ``x`` (T, H). ``w_gate_up`` (held, H, 2F) and ``w_down`` (held, F, H)
-    are the experts ``[expert_offset, expert_offset + held)`` of the
-    ``router_w.shape[1]`` the router scores. ``live`` (T,) bool: rows that
-    are not (a padded tail, a frozen slot) reach no expert.
+# the bytes of a (token-expert pairs, hidden) buffer a routed feed-forward
+# may gather at once; past it the tokens go in slabs (``routed_ffn``)
+_PAIR_BYTES = 256 << 20
 
-    Returns ``(y (T, H), stats (3,) int32, chosen (T, top_k))``: pairs
-    that landed on held experts, held experts with at least one, the
-    largest count one took; and what the router chose."""
+
+def _slab_tokens(T: int, top_k: int, H: int, itemsize: int) -> int:
+    """Tokens a slab of ``routed_ffn``: all ``T`` where their pairs' rows
+    fit ``_PAIR_BYTES``, else the largest power of two that does."""
+    if T * top_k * H * itemsize <= _PAIR_BYTES:
+        return T
+    step = 1
+    while 2 * step * top_k * H * itemsize <= _PAIR_BYTES:
+        step *= 2
+    return step
+
+
+def _held_part(x, w, sel, w_gate_up, w_down, expert_offset, live, sharded):
+    """The held experts' weighted sum (T, H) float32 over tokens ``x``
+    with routing weights ``w`` and choices ``sel`` (T, top_k), the rows a
+    held expert took (held,) and their sum."""
     T, H = x.shape
+    top_k = sel.shape[1]
     held = w_gate_up.shape[0]
-    w, sel = sigmoid_topk_route(x, router_w, expert_bias, top_k, route_norm,
-                                route_scale)
     local = sel - expert_offset
     here = jnp.logical_and(local >= 0, local < held)
     if live is not None:
@@ -138,5 +159,42 @@ def routed_ffn(x, router_w, expert_bias, w_gate_up, w_down, *, top_k: int,
     y = jnp.where((jnp.arange(T * top_k) < n_here)[:, None], y, 0)
     y = y[jnp.argsort(order)].reshape(T, top_k, H)
     out = jnp.sum(y.astype(jnp.float32) * w[..., None], axis=1)
+    return out, sizes, n_here
+
+
+def routed_ffn(x, router_w, expert_bias, w_gate_up, w_down, *, top_k: int,
+               route_norm: bool, route_scale: float, expert_offset: int = 0,
+               live=None, sharded=False, n_group: int = 1,
+               topk_group: int = 1):
+    """The held experts' part of a routed SwiGLU feed-forward over tokens
+    ``x`` (T, H). ``w_gate_up`` (held, H, 2F) and ``w_down`` (held, F, H)
+    are the experts ``[expert_offset, expert_offset + held)`` of the
+    ``router_w.shape[1]`` the router scores, over ``n_group`` groups of
+    which ``topk_group`` are kept (``sigmoid_topk_route``). ``live`` (T,)
+    bool: rows that are not (a padded tail, a frozen slot) reach no
+    expert.
+
+    Returns ``(y (T, H), stats (3,) int32, chosen (T, top_k))``: pairs
+    that landed on held experts, held experts with at least one, the
+    largest count one took; and what the router chose."""
+    T, H = x.shape
+    w, sel = sigmoid_topk_route(x, router_w, expert_bias, top_k, route_norm,
+                                route_scale, n_group, topk_group)
+    step = _slab_tokens(T, top_k, H, x.dtype.itemsize)
+    if step >= T:
+        out, sizes, n_here = _held_part(x, w, sel, w_gate_up, w_down,
+                                        expert_offset, live, sharded)
+    else:
+        # a long prefill's gathered pairs in slabs of tokens: each slab's
+        # (pairs, hidden) buffers are alive alone, not T x top_k rows of
+        # them at once (1.9 GB a buffer at 16384 tokens x 8 x 7168)
+        parts = [_held_part(
+            x[i:i + step], w[i:i + step], sel[i:i + step], w_gate_up,
+            w_down, expert_offset,
+            None if live is None else live[i:i + step], sharded)
+            for i in range(0, T, step)]
+        out = jnp.concatenate([o for o, _, _ in parts])
+        sizes = sum(z for _, z, _ in parts)
+        n_here = jnp.sum(sizes)
     stats = jnp.stack([n_here, jnp.sum(sizes > 0), jnp.max(sizes)])
     return out.astype(x.dtype), stats.astype(jnp.int32), sel

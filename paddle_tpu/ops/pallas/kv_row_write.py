@@ -84,9 +84,9 @@ def _copy_kernel(at_ref, k_ref, v_ref, kc_ref, vc_ref, ko_ref, vo_ref):
 
 def _tile_kernel(at_ref, k_ref, v_ref, kc_ref, vc_ref, ko_ref, vo_ref, *, T):
     r = at_ref[pl.program_id(0)] % T
-    rows = jax.lax.broadcasted_iota(jnp.int32, ko_ref.shape[1:], 1)
-    ko_ref[0] = jnp.where(rows == r, k_ref[0], kc_ref[0])
-    vo_ref[0] = jnp.where(rows == r, v_ref[0], vc_ref[0])
+    for new, old, out in ((k_ref, kc_ref, ko_ref), (v_ref, vc_ref, vo_ref)):
+        rows = jax.lax.broadcasted_iota(jnp.int32, out.shape[1:], 1)
+        out[0] = jnp.where(rows == r, new[0], old[0])
 
 
 @functools.partial(jax.jit, static_argnames=("head_major",))
@@ -97,32 +97,40 @@ def kv_row_write(kc, vc, k, v, at, head_major: bool):
     buffers up. Head-major: caches (B, KV, L, D), rows (B, KV, 1, D);
     token-major: (B, L, KV, D) and (B, 1, KV, D)."""
     if not (supported(kc, k, head_major) and supported(vc, v, head_major)
-            and kc.shape == vc.shape and kc.dtype == vc.dtype):
+            and kc.shape[:-1] == vc.shape[:-1] and kc.dtype == vc.dtype):
         raise ValueError(f"kv_row_write: caches {kc.shape}/{vc.shape} "
                          f"{kc.dtype}/{vc.dtype} with rows {k.shape}/"
                          f"{v.shape} {k.dtype} are not served (see "
                          f"supported())")
-    B, KV, L, D = _dims(kc.shape, head_major)
+    B, KV, L, _ = _dims(kc.shape, head_major)
     at = jnp.clip(jnp.asarray(at, jnp.int32).reshape(B), 0, L - 1)
-    new = pl.BlockSpec(_row_shape(1, KV, D, head_major),
-                       lambda b, at_ref: (b, 0, 0, 0))
-    if head_major:
-        T = _TILE[kc.dtype.itemsize]
-        blk = pl.BlockSpec((1, KV, T, D),
-                           lambda b, at_ref: (b, 0, at_ref[b] // T, 0))
-        kernel, held = functools.partial(_tile_kernel, T=T), blk
-    else:
+
+    def specs(D):
+        """(new row, held block, written block) of a buffer of head
+        size ``D``: the two buffers may differ in it (a latent layer's
+        latent and rope key, models/axk1.py)."""
+        new = pl.BlockSpec(_row_shape(1, KV, D, head_major),
+                           lambda b, at_ref: (b, 0, 0, 0))
+        if head_major:
+            blk = pl.BlockSpec((1, KV, T, D),
+                               lambda b, at_ref: (b, 0, at_ref[b] // T, 0))
+            return new, blk, blk
         blk = pl.BlockSpec((1, 1, KV, D),
                            lambda b, at_ref: (b, at_ref[b], 0, 0))
         # written whole, never read: the aliased inputs stay in HBM
-        kernel, held = _copy_kernel, pl.BlockSpec(memory_space=pl.ANY)
-    like = jax.ShapeDtypeStruct(kc.shape, kc.dtype)
+        return new, pl.BlockSpec(memory_space=pl.ANY), blk
+
+    T = _TILE[kc.dtype.itemsize]
+    kernel = functools.partial(_tile_kernel, T=T) if head_major \
+        else _copy_kernel
+    (nk, hk, bk), (nv, hv, bv) = specs(kc.shape[-1]), specs(vc.shape[-1])
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(B,),
-            in_specs=[new, new, held, held], out_specs=[blk, blk]),
-        out_shape=[like, like],
+            in_specs=[nk, nv, hk, hv], out_specs=[bk, bv]),
+        out_shape=[jax.ShapeDtypeStruct(kc.shape, kc.dtype),
+                   jax.ShapeDtypeStruct(vc.shape, vc.dtype)],
         input_output_aliases={3: 0, 4: 1},
         interpret=_routing.use_interpret(),
         name="kv_row_write",

@@ -209,6 +209,11 @@ class _DecoderBackend:
         # the window's rows and the positions a summary stands for
         self.eva = ((dec.cfg.cache_len(0, dec.max_len), dec.cfg.chunk_size)
                     if dec.cfg.eva else None)
+        # a latent-attention model's cache layers (models/axk1.py): each
+        # holds a compressed latent and a rope key, no keys and values
+        # per head
+        self.latent_layers = (dec.cfg.num_cache_layers if dec.cfg.latent
+                              else 0)
         self.moe_counts = []    # state.moe of every chunk dispatch since
         #                         the engine last took them (harvest)
         # routed layers whose feed-forward the chunk program runs through
@@ -509,6 +514,7 @@ class _BundleBackend:
 
     has_windows = False    # (an exported program is full attention)
     eva = None
+    latent_layers = 0      # (a bundle of a latent model is refused)
     moe_counts = ()
     moe_ffn_kernel_layers = 0
     has_ring = False       # bundles carry no ring-staging entries: the
@@ -893,6 +899,13 @@ class ServingEngine:
         self.batch_admission = bool(batch_admission)
         self.prefix_cache = self._resolve_prefix_cache(
             prefix_cache, prefix_cache_bytes, prefix_block_tokens)
+        if self._b.latent_layers and self.prefix_cache is not None:
+            from paddle_tpu.inference.generate import LatentCacheError
+            raise LatentCacheError(
+                "the prefix cache keeps a slab of keys and values per head "
+                "by position; a model with latent attention caches a "
+                "latent and a rope key instead — serve it with "
+                "prefix_cache=False")
         if self._b.has_windows and self.prefix_cache is not None:
             from paddle_tpu.inference.generate import WindowedModelError
             raise WindowedModelError(
@@ -1070,6 +1083,16 @@ class ServingEngine:
             "sum over the occupied rows of the summaries visible at the "
             "chunk's start ((kv_pos // window) x (window / chunk)), per "
             "chunk dispatch")
+        # a latent model's decode attention (models/axk1.py) reads, a
+        # step, each occupied row's live positions in every latent layer
+        self._c_live_latent = r.counter(
+            "serving.chunk.live_latent_positions",
+            "latent positions the chunks' decode steps read: over the "
+            "occupied rows at the chunk's start, the chunk's steps (a "
+            "step at position n reads n + 1) and the latent cache layers "
+            "(0 for a model without latent attention)")
+        # bucket -> what its admission prefills needed (_count_prefill)
+        self._prefill_need: Dict[int, Dict[str, int]] = {}
         self._c_eva_chunks = r.counter(
             "serving.eva.chunks_summarised",
             "chunks whose summary a decode step completed, over the "
@@ -1669,6 +1692,11 @@ class ServingEngine:
         elif self._cache_bytes_window:
             self._c_live_win.inc(sum(min(slot.kv_pos, self._window_len)
                                      for _, slot in occupied))
+        if self._b.latent_layers:
+            L = self._b.max_len
+            self._c_live_latent.inc(self._b.latent_layers * sum(
+                min(slot.kv_pos + t + 1, L) for _, slot in occupied
+                for t in range(self.chunk_size)))
         toks = self._dispatch_chunk(occupied)
         nv = self._last_nv
         t_chunk_done = time.monotonic()
@@ -2532,6 +2560,12 @@ class ServingEngine:
         per-pool accounting the cluster bench asserts on."""
         import jax
 
+        if self._b.latent_layers:
+            from paddle_tpu.inference.generate import LatentCacheError
+            raise LatentCacheError(
+                "a prefix slab is a prompt's keys and values per head; a "
+                "model with latent attention caches a latent and a rope "
+                "key: no slab can be cut from its caches")
         if self._b.has_windows:
             from paddle_tpu.inference.generate import WindowedModelError
             raise WindowedModelError(
@@ -2743,6 +2777,20 @@ class ServingEngine:
                                 for req, _ in items], np.int32)
         return ids, true_len, pos0, aidxN
 
+    def _count_prefill(self, w: int, true_len) -> None:
+        """What an admission prefill of bucket ``w`` NEEDED, from its
+        rows' true lengths, by bucket: the rows, their positions and the
+        (query, key) pairs of causal attention over them — the padded
+        tail of the bucket is none of them (and EVA's own counts,
+        ``_count_eva_prefill``)."""
+        acc = self._prefill_need.setdefault(int(w), dict.fromkeys(
+            ("rows", "positions", "causal_pairs"), 0))
+        for n in map(int, true_len):
+            acc["rows"] += 1
+            acc["positions"] += n
+            acc["causal_pairs"] += n * (n + 1) // 2
+        self._count_eva_prefill(w, true_len)
+
     def _count_eva_prefill(self, w: int, true_len) -> None:
         """What an EVA admission prefill of bucket ``w`` NEEDED, from its
         rows' true lengths: the aligned windows they reach, and by bucket
@@ -2778,7 +2826,7 @@ class ServingEngine:
             self._b.ring_admit(ids, true_len, pos0, rows, aidx=aidxN)
             self._timeline.fed("prefill")
             self._c_prefill.inc()
-            self._count_eva_prefill(w, true_len)
+            self._count_prefill(w, true_len)
             if self._spec_active:
                 self._b.ring_admit_draft(ids, rows)
                 self._c_draft_prefill.inc()
@@ -2893,7 +2941,7 @@ class ServingEngine:
                 ids, true_len, pos0, kcN, vcN, aidx=aidxN)
             self._timeline.fed("prefill")
         self._c_prefill.inc()
-        self._count_eva_prefill(w, true_len)
+        self._count_prefill(w, true_len)
         if N > 1:
             self._c_batched_groups.inc()
             self._c_disp_saved.inc(N - 1)
@@ -3554,7 +3602,11 @@ class ServingEngine:
         ``cache_bytes_per_position_window`` / ``_summary`` a row's bytes of
         each leaf, and ``eva_*`` the chunks summarised, the window ends
         crossed, the windows prefilled and, by admission bucket, the rows,
-        positions and attended pairs the prefills needed), ``moe_*`` what
+        positions and attended pairs the prefills needed),
+        ``live_latent_positions_total`` the positions a latent model's
+        decode steps read over its latent layers, ``prefill_by_bucket``
+        the admission prefills' rows, positions and causal (query, key)
+        pairs by bucket (any model), ``moe_*`` what
         the routing of a model with routed feed-forwards did
         (``serving.moe.*``; zeros for any other) and
         ``moe_ffn_kernel_layers`` how many of its routed layers the chunk
@@ -3599,6 +3651,9 @@ class ServingEngine:
             "eva_prefill_windows_total": int(self._c_eva_prefill.value),
             "eva_prefill_by_bucket": {b: dict(v) for b, v
                                       in self._eva_prefill.items()},
+            "live_latent_positions_total": int(self._c_live_latent.value),
+            "prefill_by_bucket": {b: dict(v) for b, v
+                                  in self._prefill_need.items()},
             "moe_pairs_held_total": int(self._c_moe_pairs.value),
             "moe_experts_touched_total": int(self._c_moe_touched.value),
             "moe_load_max": int(self._g_moe_load.value),

@@ -547,3 +547,59 @@ def test_grouped_ffn_compiles_for_a_chip_of_half_the_vmem(tpu, monkeypatch):
             _s(v5p, (rows, 3072)), gu, dn,
             _s(v5p, (32,), jnp.int32)).compile()
         assert program_census(c)["kernels"] == {"grouped_ffn": 1}
+
+
+def test_latent_serving_programs_compile_at_the_published_widths(
+        tpu, decoder_kernels_routed, monkeypatch):
+    """A.X-K1's serving programs (models/axk1.py) at the published widths
+    — hidden 7168, 64 heads, latent 512, rope 64, one dense layer of 18432
+    and one routed layer of 12 held experts of 2048 over a router of 192,
+    the vocabulary slice of 20480 — compiled for a v5e from shapes alone
+    (no weight is made). The chunk program runs the latent decode kernel
+    and the row write once a layer and holds no copy of a layer's latent
+    (its temporaries stay under one latent leaf); a bucket-2048 admission
+    prefill runs the flash forward once a layer and its temporaries stay
+    under 1 GiB (the dense layer's 2048 x 36864 gate|up, the expanded
+    keys and values of 64 heads, the routed pairs)."""
+    from types import SimpleNamespace
+
+    from paddle_tpu.inference import generate as g
+    from paddle_tpu.models.axk1 import AxK1Config, AxK1ForCausalLM
+    from paddle_tpu.obs.cost import program_census
+
+    cfg = AxK1Config(num_hidden_layers=2, n_routed_experts=192,
+                     experts_held=12, vocab_size=20480, dtype="bfloat16")
+    slots, max_len, bucket = 8, 4096, 2048
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: _s(tpu, a.shape, a.dtype), tree)
+
+    shapes = on_chip(jax.eval_shape(
+        lambda: g._build_params(AxK1ForCausalLM(cfg), max_len, None)))
+    monkeypatch.setattr(g, "_build_params", lambda *a: shapes)
+    dec = g.LlamaDecoder(SimpleNamespace(config=cfg), max_len=max_len)
+    kc, vc = on_chip(jax.eval_shape(lambda: dec._empty_cache(slots)))
+    assert kc[0].shape == (slots, 1, max_len, 512)
+    assert vc[0].shape == (slots, 1, max_len, 128)
+    logits = _s(tpu, (slots, 20480), jnp.float32)
+    ri, rf = _s(tpu, (slots,), jnp.int32), _s(tpu, (slots,), jnp.float32)
+    keys, done = _s(tpu, (slots, 2), jnp.uint32), _s(tpu, (slots,), jnp.bool_)
+    chunk = dec._ring_chunk_decode._jitted.lower(
+        shapes, logits, kc, vc, ri, keys, done, ri, rf, None,
+        logits, kc, vc, ri, ri, keys, ri, rf, None,
+        steps=16, do_sample=False, top_k=None, top_p=None).compile()
+    assert program_census(chunk)["kernels"] == {
+        "mla_decode_attention": 2, "kv_row_write": 2, "grouped_ffn": 1}
+    # nothing outside the kernels writes a whole latent or rope leaf, and
+    # the temporaries are the compiler's re-layouts of weights (q_b's 36
+    # MB and kv_b's 16 MB a layer, the rope tables), whatever the cache
+    assert _whole_cache_writers(chunk.as_text(), {
+        f"{slots},1,{max_len},512", f"{slots},1,{max_len},128"}) == []
+    assert chunk.memory_analysis().temp_size_in_bytes < 128 << 20
+    one = on_chip(jax.eval_shape(lambda: dec._empty_cache(1)))
+    r1 = _s(tpu, (1,), jnp.int32)
+    prefill = dec._ring_admit_prefill._jitted.lower(
+        shapes, _s(tpu, (1, bucket), jnp.int32), *one, r1, r1, logits, kc,
+        vc, r1, None).compile()
+    assert program_census(prefill)["kernels"]["flash_fwd"] == 2
+    assert prefill.memory_analysis().temp_size_in_bytes < 1 << 30
